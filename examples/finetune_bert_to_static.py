@@ -10,10 +10,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("FORCE_CPU", "1") == "1":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 import paddle_tpu as paddle

@@ -12,11 +12,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np
 
-if os.environ.get("FORCE_CPU", "1") == "1":
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 import paddle_tpu as paddle
 from paddle_tpu.inference import ContinuousServingEngine
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny

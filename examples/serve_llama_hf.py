@@ -4,11 +4,10 @@ concurrent generate() calls.
 
     python examples/serve_llama_hf.py --model-dir /path/to/hf_llama
     python examples/serve_llama_hf.py            # tiny random demo model
-    FORCE_CPU=0 python examples/serve_llama_hf.py   # use the accelerator
+    JAX_PLATFORMS=cpu python examples/serve_llama_hf.py   # no chip
 
-Defaults to the CPU backend (FORCE_CPU=1) so the demo runs anywhere; with
-FORCE_CPU=0 on a TPU host the decode path runs jax's production
-paged-attention Pallas kernel — same API either way.
+Runs on whatever backend jax finds; on a TPU host the decode path runs the
+in-repo paged-attention Pallas kernel — same API either way.
 """
 import argparse
 import os
@@ -16,10 +15,6 @@ import sys
 import threading
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-if os.environ.get("FORCE_CPU", "1") == "1":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np                                        # noqa: E402
 

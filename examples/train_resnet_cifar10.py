@@ -10,10 +10,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-if os.environ.get("FORCE_CPU", "1") == "1":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 
 import paddle_tpu as paddle
@@ -30,8 +26,8 @@ def main():
     args = ap.parse_args()
 
     paddle.seed(0)
-    paddle.set_device("cpu" if os.environ.get("FORCE_CPU", "1") == "1"
-                      else "tpu")
+    import jax
+    paddle.set_device("tpu" if jax.default_backend() == "tpu" else "cpu")
     model = resnet18(num_classes=10)
     model.train()
     sched = paddle.optimizer.lr.CosineAnnealingDecay(

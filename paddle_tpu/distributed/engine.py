@@ -30,24 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
-def _shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-               check_vma=False):
-    """Version portability for shard_map: ``jax.shard_map`` (new API,
-    ``axis_names``/``check_vma``) when present, else the experimental
-    module's (``auto``/``check_rep``) with the argument translation —
-    ``axis_names`` lists the MANUAL axes, ``auto`` its complement."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=axis_names, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as esm
-    manual = frozenset(axis_names) if axis_names else \
-        frozenset(mesh.axis_names)
-    auto = frozenset(mesh.axis_names) - manual
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, auto=auto)
-
-
 def _chunk_key(base_key, micro_idx, chunk_id):
     """Deterministic per-(microbatch, chunk) PRNG key — the reference's
     ``RNGStatesTracker`` contract (``fleet/layers/mpu/random.py``): each
@@ -356,7 +338,7 @@ def _forward_1f1b(stage_fn, mesh, n_stages, n_micro, axis_name, with_keys,
 
     @jax.custom_vjp
     def call(stacked_params, micro_inputs, rng_key):
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             fwd_run, mesh=mesh,
             in_specs=(_p_specs(stacked_params), P(), P()), out_specs=P(),
             axis_names={axis_name}, check_vma=False)
@@ -369,7 +351,7 @@ def _forward_1f1b(stage_fn, mesh, n_stages, n_micro, axis_name, with_keys,
     def bwd(res, d_out):
         stacked_params, micro_inputs, rng_key = res
         specs = _p_specs(stacked_params)
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             bwd_run, mesh=mesh, in_specs=(specs, P(), P(), P()),
             out_specs=(specs, P()), axis_names={axis_name}, check_vma=False)
         dstacked, dmicro = jax.jit(mapped)(stacked_params, micro_inputs,
@@ -956,7 +938,7 @@ def pipeline_forward(stage_fn, stacked_params, micro_inputs, *, mesh=None,
     p_specs = jax.tree.map(lambda a: P(axis_name), stacked_params)
     # bare P() is a pytree-prefix spec: replicates every activation leaf
     in_specs = (p_specs, P()) + ((P(),) if with_keys else ())
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         run, mesh=mesh, in_specs=in_specs, out_specs=P(),
         axis_names={axis_name}, check_vma=False)
     args = (stacked_params, micro_inputs) + ((rng_key,) if with_keys else ())

@@ -13,6 +13,8 @@ outermost.
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -142,6 +144,34 @@ def batch_spec(ndim: int = 3):
         parts.append(sep)
     parts += [None] * (ndim - len(parts))
     return PartitionSpec(*parts)
+
+
+def shard_attention_kernel(kernel, q, k, v):
+    """Run ``kernel(q, k, v)`` ([b, s, heads, d] in and out) on a traced
+    call under a multi-device mesh. GSPMD cannot partition a Mosaic kernel
+    ("wrap the call in a shard_map"), so the call becomes a fully-manual
+    ``shard_map``: batch over the data axes, heads over 'mp' — the layout
+    the column-parallel q/k/v projections already produce. A dim an axis
+    does not divide stays whole there (each device of that axis computes
+    all of it); the sequence stays whole (context parallelism over 'sep'
+    is ring attention's job). Eager or mesh-less calls run ``kernel``
+    as is."""
+    if not (has_mesh() and isinstance(q, jax.core.Tracer)):
+        return kernel(q, k, v)
+    m = get_mesh()
+    if len(m.devices.flat) <= 1:
+        return kernel(q, k, v)
+
+    data = tuple(ax for ax in ("dp", "sharding")
+                 if int(m.shape.get(ax, 1)) > 1)
+    if q.shape[0] % math.prod(int(m.shape[ax]) for ax in data):
+        data = ()
+    n_mp = int(m.shape.get("mp", 1))
+    mp = "mp" if n_mp > 1 and not (q.shape[2] % n_mp
+                                   or k.shape[2] % n_mp) else None
+    spec = PartitionSpec(data or None, None, mp, None)
+    return jax.shard_map(kernel, mesh=m, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def strip_axis(spec: PartitionSpec, axis: str) -> PartitionSpec:

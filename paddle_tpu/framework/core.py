@@ -13,6 +13,8 @@ scheme upstream uses (``python/paddle/tensor/__init__.py`` monkey_patch).
 """
 from __future__ import annotations
 
+import os
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -38,9 +40,22 @@ class Place:
         return isinstance(other, Place) and (self.kind, self.index) == (other.kind, other.index)
 
     def jax_device(self):
-        plat = {"cpu": "cpu", "tpu": None, "gpu": None}[self.kind]
-        devs = jax.devices(plat) if plat else jax.devices()
-        return devs[self.index % len(devs)]
+        """The jax device behind this place. An accelerator place needs a
+        TPU backend and an index inside its device count: a missing chip
+        is an error here, never a quiet CPU (or device 0) stand-in."""
+        if self.kind == "cpu":
+            devs = jax.devices("cpu")
+        else:
+            devs = jax.devices()
+            if devs[0].platform != "tpu":
+                raise RuntimeError(
+                    f"{self!r} needs a TPU backend, but jax's default "
+                    f"backend is {devs[0].platform!r} "
+                    f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})")
+        if not 0 <= self.index < len(devs):
+            raise ValueError(f"{self!r}: device index out of range, the "
+                             f"backend has {len(devs)} device(s)")
+        return devs[self.index]
 
 
 class CPUPlace(Place):
@@ -60,16 +75,16 @@ _current_place: Place | None = None
 
 
 def set_device(device: str) -> Place:
-    """paddle.set_device('tpu'|'cpu'|'gpu:0'). 'gpu' aliases the accelerator."""
+    """paddle.set_device('tpu'|'cpu'|'gpu:0'). 'gpu'/'xpu' alias the
+    accelerator; raises when that device does not exist."""
     global _current_place
     kind, _, idx = device.partition(":")
     kind = {"gpu": "tpu", "xpu": "tpu"}.get(kind, kind)
+    if kind not in ("cpu", "tpu"):
+        raise ValueError(f"unknown device {device!r}")
     place = Place(kind, int(idx) if idx else 0)
+    jax.config.update("jax_default_device", place.jax_device())
     _current_place = place
-    try:
-        jax.config.update("jax_default_device", place.jax_device())
-    except RuntimeError:
-        pass
     return place
 
 

@@ -75,12 +75,11 @@ class Config:
 
     def set_optim_cache_dir(self, path):
         """Persistent compilation cache (reference: the optimization
-        cache dir) — compiled executables survive process restarts."""
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # default min-compile-time threshold (1s) silently skips small
-        # models — the knob must persist everything it is asked to
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        cache dir) — compiled executables survive process restarts.
+        ``JAX_COMPILATION_CACHE_DIR``, where set, wins over ``path``
+        (see ``paddle.jit.enable_persistent_cache``)."""
+        from ..jit.api import enable_persistent_cache
+        enable_persistent_cache(path)
 
     def enable_tensorrt_engine(self, *a, **kw):
         raise NotImplementedError(

@@ -94,11 +94,18 @@ def _install_disk_hit_listener():
 def enable_persistent_cache(path=None):
     """Wire jax's persistent compilation cache so repeated runs skip XLA
     recompiles entirely (the training/serving cold-start lever): compiled
-    executables are keyed on HLO+flags and restored from ``path`` across
-    processes. ``path`` defaults to ``PADDLE_JIT_CACHE_DIR``; returns True
-    when active. Restores are counted as
-    ``paddle_jit_cache_total{event="disk_hit"}``."""
-    if path is None:
+    executables are keyed on HLO+flags and restored across processes.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache lives THERE and no
+    directory is ever set in code (whoever runs the program places the
+    cache; the path is part of the cache key, so a second opinion in code
+    would only make it miss). Otherwise ``path`` (default
+    ``PADDLE_JIT_CACHE_DIR``) is used. Returns True when active. Restores
+    are counted as ``paddle_jit_cache_total{event="disk_hit"}``."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        path = env_dir
+    elif path is None:
         path = os.environ.get("PADDLE_JIT_CACHE_DIR")
     if not path:
         _PERSISTENT_CACHE[0] = False
@@ -106,29 +113,19 @@ def enable_persistent_cache(path=None):
     path = str(path)
     if _PERSISTENT_CACHE[0] == path:
         return True
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        # default thresholds skip tiny/fast programs — a framework whose
-        # eager tier jits small regions wants everything cached
-        for knob, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                          ("jax_persistent_cache_min_compile_time_secs", 0.0)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:
-                pass
+    if not env_dir:
         os.makedirs(path, exist_ok=True)
-        # the cache latches DISABLED at the first compile of the process
-        # (lazy _initialize_cache); a reset re-reads the (now set) dir so
-        # late wiring — after paddle's import-time jits — still engages
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _jax_cc)
-            _jax_cc.reset_cache()
-        except Exception:
-            pass
-    except Exception:
-        _PERSISTENT_CACHE[0] = False
-        return False
+        jax.config.update("jax_compilation_cache_dir", path)
+    # default thresholds skip tiny/fast programs — a framework whose
+    # eager tier jits small regions wants everything cached
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # the cache latches DISABLED at the first compile of the process
+    # (lazy _initialize_cache); a reset re-reads the (now set) dir so
+    # late wiring — after paddle's import-time jits — still engages
+    from jax.experimental.compilation_cache import (
+        compilation_cache as _jax_cc)
+    _jax_cc.reset_cache()
     _install_disk_hit_listener()
     _PERSISTENT_CACHE[0] = path
     return True

@@ -86,12 +86,15 @@ class LlamaConfig:
 
 
 def llama3_8b(**kw):
-    """Llama-3-8B (north star, BASELINE.json configs[4])."""
-    return LlamaConfig(vocab_size=128256, hidden_size=4096,
-                       intermediate_size=14336, num_hidden_layers=32,
-                       num_attention_heads=32, num_key_value_heads=8,
-                       max_position_embeddings=8192, rms_norm_eps=1e-5,
-                       rope_theta=500000.0, **kw)
+    """Llama-3-8B (north star, BASELINE.json configs[4]); keyword
+    overrides cut it to size (e.g. ``num_hidden_layers=8`` for one chip)."""
+    for k, v in dict(vocab_size=128256, hidden_size=4096,
+                     intermediate_size=14336, num_hidden_layers=32,
+                     num_attention_heads=32, num_key_value_heads=8,
+                     max_position_embeddings=8192, rms_norm_eps=1e-5,
+                     rope_theta=500000.0).items():
+        kw.setdefault(k, v)
+    return LlamaConfig(**kw)
 
 
 def llama_tiny(**kw):

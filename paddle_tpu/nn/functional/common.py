@@ -3,6 +3,8 @@ interpolate (reference: ``python/paddle/nn/functional/{common,conv,pooling,
 input}.py`` — SURVEY.md §2.2). All map to lax/XLA; conv/matmul hit the MXU."""
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -592,15 +594,17 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         q_off = key.shape[1] - query.shape[1]
 
         def flash_fn(q, k, v):
-            return _fa(q, k, v, causal=is_causal, q_offset=q_off,
-                       interpret=False)
+            from ...distributed.mesh import shard_attention_kernel
+            kernel = functools.partial(_fa, causal=is_causal,
+                                       q_offset=q_off, interpret=False)
+            return shard_attention_kernel(kernel, q, k, v)
 
         return apply(flash_fn, query, key, value, op_name="flash_attn")
 
     dk = prandom.next_key() if (dropout_p > 0.0 and training) else None
 
-    # long-sequence memory safety: with flash unavailable (quarantined
-    # kernel, disabled flag, CPU) a no-mask/no-dropout attention at
+    # long-sequence memory safety: with flash unavailable (disabled
+    # flag, CPU, short/odd head_dim) a no-mask/no-dropout attention at
     # seq >= 4096 would materialize an S×S fp32 logits tensor — route it
     # through the pure-XLA tier dispatcher instead (flash-like memory:
     # per-chunk remat + causal kv-prefix trim, or the scan tiers per

@@ -22,9 +22,11 @@ class HookRemoveHelper:
 
 
 class Layer:
-    def __init__(self, name_scope=None, dtype="float32"):
+    def __init__(self, name_scope=None, dtype=None):
         self.training = True
-        self._dtype = dtype
+        # parameters are created in paddle.get_default_dtype() (float32
+        # unless set_default_dtype changed it), as the reference's layers do
+        self._dtype = dtype or dtypes.get_default_dtype()
         self._full_name = name_scope or _auto_name(type(self).__name__.lower())
         self._parameters: dict[str, Parameter] = collections.OrderedDict()
         self._sub_layers: dict[str, Layer] = collections.OrderedDict()

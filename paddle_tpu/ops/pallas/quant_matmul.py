@@ -15,51 +15,14 @@ persists across k steps, output written at the last k step.
 from __future__ import annotations
 
 import functools
-import weakref
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either name so
-# the kernels (and their CPU interpret-mode tests) work across versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
-
 def _cdiv(a, b):
     return (a + b - 1) // b
-
-
-#: (id(w), id(scale)) -> (weakref(w), weakref(scale), dequant array).
-#: The guarded-off fallback below used to dequantize the FULL weight on
-#: every call — per decode step, per layer — which regressed eager
-#: serving whenever the canary said no. Weights are long-lived (a model
-#:  holds them for the process lifetime), so one dequant per weight
-#: identity amortizes to zero; the weakrefs guard against id() reuse
-#: after garbage collection.
-_DEQUANT_CACHE: dict = {}
-_DEQUANT_CACHE_MAX = 64
-
-
-def _dequant_weight(w_int8, scale):
-    key = (id(w_int8), id(scale))
-    hit = _DEQUANT_CACHE.get(key)
-    if hit is not None:
-        w_ref, s_ref, dq = hit
-        if w_ref() is w_int8 and s_ref() is scale:
-            return dq
-        del _DEQUANT_CACHE[key]
-    dq = w_int8.astype(jnp.float32) * scale[None, :]
-    try:
-        entry = (weakref.ref(w_int8), weakref.ref(scale), dq)
-    except TypeError:                       # non-weakrefable operands
-        entry = ((lambda o=w_int8: o), (lambda o=scale: o), dq)
-    if len(_DEQUANT_CACHE) >= _DEQUANT_CACHE_MAX:
-        _DEQUANT_CACHE.clear()
-    _DEQUANT_CACHE[key] = entry
-    return dq
 
 
 def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, k_steps):
@@ -86,13 +49,6 @@ def int8_matmul(x, w_int8, scale, block_m=128, block_n=128, block_k=128,
     dequant = int8 * scale). Returns x @ (w_int8 * scale) [M, N]."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if not interpret and jax.default_backend() == "tpu":
-        from ...utils.guarded_compile import kernel_allowed
-        if not kernel_allowed("quant_matmul", "int8 matmul kernel"):
-            # XLA fallback: dequantize + plain matmul (safe, more HBM);
-            # dequant cached per weight identity — see _dequant_weight
-            w = _dequant_weight(w_int8, scale)
-            return (x.astype(jnp.float32) @ w).astype(out_dtype or x.dtype)
     m, kdim = x.shape
     _, n = w_int8.shape
     out_dtype = out_dtype or x.dtype
@@ -120,7 +76,7 @@ def int8_matmul(x, w_int8, scale, block_m=128, block_n=128, block_k=128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_int8, scale[None, :].astype(jnp.float32))

@@ -22,17 +22,17 @@ Tokens outside every span (bucket padding) attend one garbage key
 (page 0 slot 0, the pool's scratch page) and their output is discarded
 by the caller — identical to the decode kernel's inactive-slot story.
 
-Three tiers, mirroring ``ops/pallas/paged_attention.py``:
+Tiers (``PADDLE_TPU_RAGGED_IMPL``), mirroring
+``ops/pallas/paged_attention.py``:
 
-* on real TPU an in-repo kernel is the default once its canary has
-  been proven in a disposable subprocess (``utils.guarded_compile``);
-* ``PADDLE_TPU_RAGGED_IMPL=xla`` (or an unproven kernel) delegates to a
-  plain-XLA gather+softmax fallback — zero Mosaic, wedge-free;
-* CPU tests / ``interpret=True`` run the in-repo kernels in interpret
-  mode: block-table-steered dynamic BlockSpec index maps (scalar
-  prefetch in SMEM), online-softmax scratch accumulation — the decode
-  kernel's streaming recurrence with per-TOKEN (not per-row) context
-  bounds and table rows.
+* ``auto`` / ``inrepo`` / ``qblock`` / ``token``: an in-repo kernel —
+  block-table-steered dynamic BlockSpec index maps (scalar prefetch in
+  SMEM), online-softmax scratch accumulation, the decode kernel's
+  streaming recurrence with per-TOKEN (not per-row) context bounds and
+  table rows. Compiled by Mosaic on a TPU backend (a compiler error
+  propagates), run in interpret mode by the CPU tests;
+* ``xla``: a plain-XLA gather+softmax, no kernel at all — only ever
+  reached through this explicit switch.
 
 Two in-repo grids. The default **q-block** grid ``(q_blocks, kv_head,
 jobs)`` tiles the flat batch into fixed ``PADDLE_TPU_RAGGED_QBLOCK``-row
@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .paged_attention import _CompilerParams, NEG_INF
+from .paged_attention import NEG_INF, _device_call, _scale_rows
 
 #: finite cross-span mask for the q-block kernel. The causal bound keeps
 #: NEG_INF (= -inf, matching the per-token kernel bit for bit on a row's
@@ -258,8 +258,9 @@ def _qblock_kernel(jp_ref, js_ref, jk_ref, rs_ref, rc_ref, q_ref, k_ref,
 def _qblock_kernel_quant(jp_ref, js_ref, jk_ref, rs_ref, rc_ref, q_ref,
                          k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref,
                          l_ref, acc_ref, *, sm_scale, num_jobs):
-    """int8-KV q-block variant: same job walk, pages dequantized from
-    int8 rows + per-row fp32 scales right before the MXU dots."""
+    """int8-KV q-block variant: same job walk; the per-row fp32 scales
+    arrive as a ``[1, page_size]`` lane vector and scale the scores /
+    weights around the int8 dots (see ``_decode_kernel_quant``)."""
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -274,11 +275,11 @@ def _qblock_kernel_quant(jp_ref, js_ref, jk_ref, rs_ref, rc_ref, q_ref,
     row_slot = rs_ref[0][:, :1]
     row_ctx = rc_ref[0][:, :1]
     q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
+        preferred_element_type=jnp.float32) * (ks_ref[0, 0] * sm_scale)
     s = _qblock_masked_scores(s, jkv, jslot, row_slot, row_ctx)
 
     m_prev = m_ref[...][:, :1]
@@ -288,7 +289,7 @@ def _qblock_kernel_quant(jp_ref, js_ref, jk_ref, rs_ref, rc_ref, q_ref,
     corr = jnp.exp(m_prev - m_new)
     l_new = l_ref[...][:, :1] * corr + jnp.sum(w, -1, keepdims=True)
     pv = jax.lax.dot_general(
-        w, v, (((1,), (0,)), ((), ())),
+        w * vs_ref[0, 0], v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * corr + pv
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -320,21 +321,38 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
     row_slot, row_ctx, job_page, job_slot, job_kv = qblock_schedule(
         tokens, seq_slots, q_starts, q_lens, context_lens, block_tables,
         qb, page_size)
+    nblocks = job_page.shape[0]
+    # per-ROW metadata rides as [B, Qg, 128] VMEM lanes so the kernel
+    # can slice [:, :1] — the same layout trick the softmax scratch uses
+    # (broadcast host-side: one transfer, no extra eager device ops)
+    rows = np.repeat(row_slot.reshape(nblocks, qb), group, axis=1)
+    rowc = np.repeat(row_ctx.reshape(nblocks, qb), group, axis=1)
+    rs = np.broadcast_to(rows[:, :, None], (nblocks, qb * group, 128))
+    rc = np.broadcast_to(rowc[:, :, None], (nblocks, qb * group, 128))
+    return _qblock_device(job_page, job_slot, job_kv, rs, rc, q, k_pages,
+                          v_pages, k_scales, v_scales, sm_scale=sm_scale,
+                          interpret=interpret)
+
+
+@_device_call
+def _qblock_device(job_page, job_slot, job_kv, rs, rc, q, k_pages, v_pages,
+                   k_scales, v_scales, *, sm_scale, interpret):
+    """Device half of the q-block tier: the schedule arrives as arrays
+    (``job_*`` [B, J] scalar-prefetched, ``rs``/``rc`` [B, Qg, 128] row
+    slot and context bound), so one compiled program serves every tick of
+    a (tokens, blocks, jobs) shape."""
+    tokens, heads, d = q.shape
+    kv_heads, _, page_size, _ = k_pages.shape
+    group = heads // kv_heads
     nblocks, num_jobs = job_page.shape
+    qg_rows = rs.shape[1]
+    qb = qg_rows // group
     t_pad = nblocks * qb
-    qg_rows = qb * group
 
     qp = jnp.pad(q, ((0, t_pad - tokens), (0, 0), (0, 0)))
     qg = qp.reshape(nblocks, qb, kv_heads, group, d).transpose(
         0, 2, 1, 3, 4).reshape(nblocks, kv_heads, qg_rows, d)
-    # per-ROW metadata rides as [B, Qg, 128] VMEM lanes so the kernel
-    # can slice [:, :1] — the same layout trick the softmax scratch uses
-    rows = np.repeat(row_slot.reshape(nblocks, qb), group, axis=1)
-    rowc = np.repeat(row_ctx.reshape(nblocks, qb), group, axis=1)
-    rs = jnp.asarray(np.broadcast_to(rows[:, :, None],
-                                     (nblocks, qg_rows, 128)))
-    rc = jnp.asarray(np.broadcast_to(rowc[:, :, None],
-                                     (nblocks, qg_rows, 128)))
+    rs, rc = jnp.asarray(rs), jnp.asarray(rc)
 
     quant = k_scales is not None
     kernel = functools.partial(
@@ -343,9 +361,9 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
     page_spec = pl.BlockSpec((1, 1, page_size, d),
                              lambda b, h, j, jp, js, jk:
                              (h, jp[b, j], 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, page_size),
+    scale_spec = pl.BlockSpec((1, 1, 1, page_size),
                               lambda b, h, j, jp, js, jk:
-                              (h, jp[b, j], 0))
+                              (h, jp[b, j], 0, 0))
     row_spec = pl.BlockSpec((1, qg_rows, 128),
                             lambda b, h, j, jp, js, jk: (b, 0, 0))
     in_specs = [
@@ -357,8 +375,7 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
     operands = [rs, rc, qg, k_pages, v_pages]
     if quant:
         in_specs += [scale_spec, scale_spec]
-        operands += [jnp.asarray(k_scales, jnp.float32),
-                     jnp.asarray(v_scales, jnp.float32)]
+        operands += [_scale_rows(k_scales), _scale_rows(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nblocks, kv_heads, num_jobs),
@@ -376,7 +393,7 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nblocks, kv_heads, qg_rows, d),
                                        q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(job_page), jnp.asarray(job_slot), jnp.asarray(job_kv),
@@ -433,8 +450,10 @@ def _ragged_kernel_quant(slots_ref, ctx_ref, tables_ref, q_ref, k_ref,
                          acc_ref, *, sm_scale, page_size, pages_per_seq,
                          group):
     """int8-KV variant of :func:`_ragged_kernel`: page blocks arrive as
-    int8 rows plus one fp32 scale per (page, slot) row, dequantized in
-    VMEM right before the MXU dots — fp32 pages never exist in HBM."""
+    int8 rows plus one fp32 scale per (page, slot) row (a
+    ``[1, page_size]`` lane vector scaling the scores / weights around
+    the dots, see ``_decode_kernel_quant``) — fp32 pages never exist in
+    HBM."""
     t = pl.program_id(0)
     p = pl.program_id(2)
 
@@ -446,11 +465,11 @@ def _ragged_kernel_quant(slots_ref, ctx_ref, tables_ref, q_ref, k_ref,
 
     ctx = ctx_ref[t]
     q = q_ref[0, 0].astype(jnp.float32)            # [group, d]
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+    k = k_ref[0, 0].astype(jnp.float32)            # [page_size, d]
+    v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
+        preferred_element_type=jnp.float32) * (ks_ref[0, 0] * sm_scale)
     pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos < ctx, s, NEG_INF)
 
@@ -461,7 +480,7 @@ def _ragged_kernel_quant(slots_ref, ctx_ref, tables_ref, q_ref, k_ref,
     corr = jnp.exp(m_prev - m_new)
     l_new = l_ref[...][:, :1] * corr + jnp.sum(w, -1, keepdims=True)
     pv = jax.lax.dot_general(                      # [g, d]
-        w, v, (((1,), (0,)), ((), ())),
+        w * vs_ref[0, 0], v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * corr + pv
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -473,6 +492,7 @@ def _ragged_kernel_quant(slots_ref, ctx_ref, tables_ref, q_ref, k_ref,
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+@_device_call
 def _ragged_paged_attention_pallas_quant(q, k_pages, v_pages, k_scales,
                                          v_scales, block_tables, tok_slot,
                                          tok_ctx, *, sm_scale, interpret):
@@ -488,9 +508,9 @@ def _ragged_paged_attention_pallas_quant(q, k_pages, v_pages, k_scales,
     page_spec = pl.BlockSpec((1, 1, page_size, d),
                              lambda t, h, p, slot, ctx, tbl:
                              (h, tbl[slot[t], p], 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, page_size),
+    scale_spec = pl.BlockSpec((1, 1, 1, page_size),
                               lambda t, h, p, slot, ctx, tbl:
-                              (h, tbl[slot[t], p], 0))
+                              (h, tbl[slot[t], p], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(tokens, kv_heads, pages_per_seq),
@@ -512,15 +532,16 @@ def _ragged_paged_attention_pallas_quant(q, k_pages, v_pages, k_scales,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tokens, kv_heads, group, d),
                                        q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(tok_slot, jnp.int32), jnp.asarray(tok_ctx, jnp.int32),
       jnp.asarray(block_tables, jnp.int32), qg, k_pages, v_pages,
-      jnp.asarray(k_scales, jnp.float32), jnp.asarray(v_scales, jnp.float32))
+      _scale_rows(k_scales), _scale_rows(v_scales))
     return out.reshape(tokens, heads, d)
 
 
+@_device_call
 def _ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
                                    tok_slot, tok_ctx, *, sm_scale,
                                    interpret):
@@ -559,7 +580,7 @@ def _ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tokens, kv_heads, group, d),
                                        q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(tok_slot, jnp.int32), jnp.asarray(tok_ctx, jnp.int32),
@@ -586,8 +607,8 @@ def _ragged_paged_attention_xla(q, k_pages, v_pages, block_tables,
                                 k_scales=None, v_scales=None):
     """Vectorized jittable XLA tier: gather each token's sequence pages
     as dense KV (dequantized when int8 row scales are given), then
-    masked softmax-attention. O(tokens * S_max) HBM — trades the
-    kernel's memory win for wedge-free compiles."""
+    masked softmax-attention. O(tokens * S_max) HBM — the explicit
+    ``PADDLE_TPU_RAGGED_IMPL=xla`` tier."""
     kv_heads, _, page_size, d = k_pages.shape
     tokens, heads, _ = q.shape
     group = heads // kv_heads
@@ -634,94 +655,22 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_slots,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     impl = _ragged_impl()
-    qblock_ok = _qblock_eligible(impl, seq_slots, q_starts, q_lens,
-                                 context_lens, block_tables)
-    if k_scales is not None:
-        # int8 KV pages: same wedge-proof ladder, own canaries — the
-        # quant kernels' Mosaic lowerings (int8 loads + row-scale
-        # multiplies) are distinct from the native kernels' proven ones.
-        if not interpret and jax.default_backend() == "tpu":
-            if impl != "xla":
-                from ...utils.guarded_compile import kernel_allowed
-                if qblock_ok and (impl == "inrepo" or kernel_allowed(
-                        "ragged_paged_attention_qblock_int8",
-                        "int8-KV q-block ragged attention kernel",
-                        fallback="the per-token ragged kernel")):
-                    return _ragged_paged_attention_pallas_qblock(
-                        q, k_pages, v_pages, block_tables, seq_slots,
-                        q_starts, q_lens, context_lens,
-                        sm_scale=sm_scale, interpret=False,
-                        k_scales=k_scales, v_scales=v_scales)
-                if impl == "inrepo" or kernel_allowed(
-                        "ragged_paged_attention_int8",
-                        "int8-KV ragged paged attention kernel",
-                        fallback="the XLA dequant-gather tier"):
-                    tok_slot, tok_ctx = _token_descriptors(
-                        tokens, seq_slots, q_starts, q_lens, context_lens)
-                    return _ragged_paged_attention_pallas_quant(
-                        q, k_pages, v_pages, k_scales, v_scales,
-                        block_tables, tok_slot, tok_ctx,
-                        sm_scale=sm_scale, interpret=False)
-            tok_slot, tok_ctx = _token_descriptors(
-                tokens, seq_slots, q_starts, q_lens, context_lens)
-            return _ragged_paged_attention_xla(
-                q, k_pages, v_pages, block_tables, tok_slot, tok_ctx,
-                sm_scale=sm_scale, k_scales=k_scales, v_scales=v_scales)
-        if qblock_ok:
-            return _ragged_paged_attention_pallas_qblock(
-                q, k_pages, v_pages, block_tables, seq_slots, q_starts,
-                q_lens, context_lens, sm_scale=sm_scale,
-                interpret=interpret, k_scales=k_scales, v_scales=v_scales)
-        tok_slot, tok_ctx = _token_descriptors(tokens, seq_slots,
-                                               q_starts, q_lens,
-                                               context_lens)
-        if impl == "xla":
-            return _ragged_paged_attention_xla(
-                q, k_pages, v_pages, block_tables, tok_slot, tok_ctx,
-                sm_scale=sm_scale, k_scales=k_scales, v_scales=v_scales)
-        return _ragged_paged_attention_pallas_quant(
-            q, k_pages, v_pages, k_scales, v_scales, block_tables,
-            tok_slot, tok_ctx, sm_scale=sm_scale, interpret=interpret)
-    if not interpret and jax.default_backend() == "tpu":
-        # Impl choice on real TPU: same wedge-proof ladder as
-        # paged_attention — an in-repo kernel only after its canary is
-        # proven in a disposable subprocess; the q-block grid first
-        # (fewer, fatter steps), the per-token grid as escape hatch
-        # (PADDLE_TPU_RAGGED_IMPL=token), zero-Mosaic XLA at the bottom.
-        if impl != "xla":
-            from ...utils.guarded_compile import kernel_allowed
-            if qblock_ok and (impl == "inrepo" or kernel_allowed(
-                    "ragged_paged_attention_qblock",
-                    "q-block ragged paged attention kernel",
-                    fallback="the per-token ragged kernel")):
-                return _ragged_paged_attention_pallas_qblock(
-                    q, k_pages, v_pages, block_tables, seq_slots,
-                    q_starts, q_lens, context_lens, sm_scale=sm_scale,
-                    interpret=False)
-            if impl == "inrepo" or kernel_allowed(
-                    "ragged_paged_attention", "ragged paged attention kernel",
-                    fallback="the XLA gather-attention tier"):
-                tok_slot, tok_ctx = _token_descriptors(
-                    tokens, seq_slots, q_starts, q_lens, context_lens)
-                return _ragged_paged_attention_pallas(
-                    q, k_pages, v_pages, block_tables, tok_slot, tok_ctx,
-                    sm_scale=sm_scale, interpret=False)
-        tok_slot, tok_ctx = _token_descriptors(tokens, seq_slots,
-                                               q_starts, q_lens,
-                                               context_lens)
-        return _ragged_paged_attention_xla(
-            q, k_pages, v_pages, block_tables, tok_slot, tok_ctx,
-            sm_scale=sm_scale)
-    if qblock_ok:
+    if _qblock_eligible(impl, seq_slots, q_starts, q_lens, context_lens,
+                        block_tables):
         return _ragged_paged_attention_pallas_qblock(
-            q, k_pages, v_pages, block_tables, seq_slots, q_starts,
-            q_lens, context_lens, sm_scale=sm_scale, interpret=interpret)
+            q, k_pages, v_pages, block_tables, seq_slots, q_starts, q_lens,
+            context_lens, sm_scale=sm_scale, interpret=interpret,
+            k_scales=k_scales, v_scales=v_scales)
     tok_slot, tok_ctx = _token_descriptors(tokens, seq_slots, q_starts,
                                            q_lens, context_lens)
     if impl == "xla":
         return _ragged_paged_attention_xla(
             q, k_pages, v_pages, block_tables, tok_slot, tok_ctx,
-            sm_scale=sm_scale)
+            sm_scale=sm_scale, k_scales=k_scales, v_scales=v_scales)
+    if k_scales is not None:
+        return _ragged_paged_attention_pallas_quant(
+            q, k_pages, v_pages, k_scales, v_scales, block_tables,
+            tok_slot, tok_ctx, sm_scale=sm_scale, interpret=interpret)
     return _ragged_paged_attention_pallas(
         q, k_pages, v_pages, block_tables, tok_slot, tok_ctx,
         sm_scale=sm_scale, interpret=interpret)

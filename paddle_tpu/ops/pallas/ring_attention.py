@@ -33,9 +33,8 @@ import jax.numpy as jnp
 from .flash_attention import flash_attention_with_lse, mha_reference, NEG_INF
 
 #: PADDLE_SEP_RING_IMPL values (mirrors PADDLE_TPU_RAGGED_IMPL): "auto"
-#: picks the kernel tier — interpret-pallas off-TPU, guarded Mosaic on a
-#: real TPU (flash_attention_with_lse's canary falls back to XLA when the
-#: subprocess proof is missing) — and "xla" forces the pure reference.
+#: and "kernel" run the Pallas flash kernel (Mosaic on a TPU backend,
+#: interpret mode off it); "xla" forces the pure reference.
 SEP_RING_IMPLS = ("auto", "kernel", "xla")
 
 
@@ -61,9 +60,8 @@ def ring_partial(q, k, v, q_offset, kv_offset, sm_scale, impl=None,
     [b, h, sq, d], global position ``q_offset``) against one KV block at
     global position ``kv_offset``, causal. Tiering matches
     ragged_paged_attention: ``auto``/``kernel`` route through
-    ``flash_attention_with_lse`` (interpret-pallas off-TPU, Mosaic behind
-    the guarded-compile canary with its own XLA fallback on TPU);
-    ``xla`` is the zero-Pallas reference."""
+    ``flash_attention_with_lse`` (Mosaic on a TPU backend,
+    interpret-pallas off it); ``xla`` is the zero-Pallas reference."""
     if impl is None:
         impl = sep_ring_impl()
     if impl == "xla":

@@ -11,8 +11,8 @@ off-by-one showed up only as mysterious p99s. The observatory makes
 every jit/compile boundary a first-class observed event:
 
 * each instrumented call site (ragged tick, legacy prefill chunk,
-  fixed-shape decode, batched draft forward, guarded-kernel proofs,
-  donated training steps) reports its **program family** plus a full
+  fixed-shape decode, batched draft forward, donated training steps)
+  reports its **program family** plus a full
   **argument signature** (array shapes/dtypes and static args) via
   :func:`observe`;
 * a signature seen before for its family is a cache **hit**; an unseen

@@ -38,8 +38,7 @@ watchdog unless ``PADDLE_FLIGHT_WATCHDOG=0``);
 ``PADDLE_FLIGHT_DEADLINE_S`` (default 300), ``PADDLE_FLIGHT_CAPACITY``
 (default 2048), ``PADDLE_FLIGHT_DIR`` (dump directory, default
 ``./flight_recorder``), ``PADDLE_METRICS_TEXT_PATH`` (the watchdog
-periodically rewrites ``metrics_text()`` there for
-``tools/tpu_watch.sh metrics`` to tail).
+periodically rewrites ``metrics_text()`` there for a scraper to tail).
 """
 from __future__ import annotations
 
@@ -257,7 +256,7 @@ class Watchdog:
     """Heartbeat monitor: when any tracked rank goes quiet past
     ``deadline_s``, dump the recorder once (latched; re-arms when every
     rank is fresh again). Optionally rewrites ``metrics_text()`` to a
-    file on each poll so ``tools/tpu_watch.sh metrics`` can tail it."""
+    file on each poll so a scraper can tail it."""
 
     def __init__(self, recorder: FlightRecorder, deadline_s: float = 300.0,
                  poll_s=None, dump_dir=None, metrics_text_path=None):
@@ -295,9 +294,9 @@ class Watchdog:
             # write-tmp-then-replace with a WRITER-UNIQUE tmp name: two
             # watchdogs (or a watchdog racing a manual rewrite) must
             # never interleave writes into one tmp file and publish the
-            # torn result — a scraper tailing the path (tools/
-            # tpu_watch.sh metrics) may read a complete exposition or
-            # the previous one, never a truncated body
+            # torn result — a scraper tailing the path may read a
+            # complete exposition or the previous one, never a truncated
+            # body
             tmp = (f"{self.metrics_text_path}.tmp."
                    f"{os.getpid()}.{threading.get_ident()}")
             with open(tmp, "w") as f:

@@ -20,12 +20,14 @@ PEAK_FLOPS = {
 
 
 def chip_kind(device=None):
-    """Map a jax device to a PEAK_FLOPS key (e.g. 'TPU v5 lite' -> 'v5e')."""
+    """Map a jax device to a PEAK_FLOPS key (e.g. 'TPU v5 lite' -> 'v5e').
+    An accelerator that is not in the table is an error, not a default."""
     if device is None:
         import jax
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "") or ""
-    k = kind.lower()
+    if device.platform == "cpu":
+        return "cpu"
+    k = (getattr(device, "device_kind", "") or "").lower()
     if "v5 lite" in k or "v5e" in k or "v5litepod" in k:
         return "v5e"
     if "v5p" in k or "v5" in k:
@@ -34,7 +36,8 @@ def chip_kind(device=None):
         return "v6e"
     if "v4" in k:
         return "v4"
-    return "cpu" if device.platform == "cpu" else "v5p"
+    raise ValueError(f"no peak FLOP/s known for device kind "
+                     f"{device.device_kind!r} ({device.platform})")
 
 
 def transformer_train_flops(num_params, tokens, num_layers=None,

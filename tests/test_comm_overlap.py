@@ -386,12 +386,14 @@ class TestOverlapTimeout:
 
 
 class TestPersistentJitCache:
-    def test_disk_hit_counted(self, tmp_path):
+    def test_disk_hit_counted(self, tmp_path, monkeypatch):
         import jax
         import jax.numpy as jnp
 
         from paddle_tpu.jit import api as jit_api
 
+        # an outside placement would (rightly) win over cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         cache_dir = str(tmp_path / "jitcache")
         prev = jit_api._PERSISTENT_CACHE[0]
         assert jit_api.enable_persistent_cache(cache_dir)
@@ -419,6 +421,7 @@ class TestPersistentJitCache:
     def test_disabled_without_env(self, monkeypatch):
         from paddle_tpu.jit import api as jit_api
         monkeypatch.delenv("PADDLE_JIT_CACHE_DIR", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         jit_api._PERSISTENT_CACHE[0] = None
         assert jit_api.enable_persistent_cache() is False
         jit_api._PERSISTENT_CACHE[0] = None

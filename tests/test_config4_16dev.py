@@ -18,12 +18,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_config4_four_axis_mesh_parity():
     sys.path.insert(0, REPO)
-    from __graft_entry__ import _sanitized_cpu_env
+    from __graft_entry__ import _cpu_env
 
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "__graft_entry__.py"),
          "--config4"],
-        env=_sanitized_cpu_env(16), cwd=REPO, text=True,
+        env=_cpu_env(16), cwd=REPO, text=True,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, timeout=420)
     assert proc.returncode == 0, proc.stdout[-2000:]
     assert "dryrun config4 OK: mesh=(dp=2, pp=2, sharding=2, mp=2)" \

@@ -13,11 +13,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_config5_five_axis_mesh_parity():
     sys.path.insert(0, REPO)
-    from __graft_entry__ import _sanitized_cpu_env
+    from __graft_entry__ import _cpu_env
 
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "_config5_child.py")],
-        env=_sanitized_cpu_env(32), cwd=REPO, text=True,
+        env=_cpu_env(32), cwd=REPO, text=True,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, timeout=560)
     assert proc.returncode == 0, proc.stdout[-2000:]
     assert "config5 OK: mesh=(dp=2, pp=2, sharding=2, sep=2, mp=2)" \

@@ -63,8 +63,10 @@ def _data(step):
 
 def _run_world(ckpt_dir, nprocs, total_steps, plan=None, ckpt_interval=3,
                job_id="job", restore_step=None, sharded=False,
-               rejoin_after=None, ttl=1.0):
-    """Spawn an elastic dp-N run; returns per-rank result dicts."""
+               rejoin_after=None, ttl=3.0):
+    """Spawn an elastic dp-N run; returns per-rank result dicts. A killed
+    rank marks itself dead, so ``ttl`` only bounds FALSE deaths: 3 s keeps a
+    heartbeat thread starved by a loaded test host from faking one."""
     store = MemKVStore()
     if plan:
         fault.install(plan)

@@ -611,7 +611,7 @@ class TestServingAttestation:
             # kill only once some first attempt has DELIVERED tokens —
             # attestation needs a non-empty attempt-1 stream to check
             # the regenerated attempt-2 stream against
-            deadline = time.monotonic() + 10
+            deadline = time.monotonic() + 60    # cold engines, loaded host
             victim = None
             while victim is None and time.monotonic() < deadline:
                 for tid in store_rt.trace_ids():

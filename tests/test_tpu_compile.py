@@ -1,0 +1,237 @@
+"""Ask the TPU v5e compiler, without a chip, whether the Pallas kernels of
+the serving and training main paths compile at the widths
+``chip_smoke.py`` runs them (Llama-3-8B head geometry: 32 q heads, 8 kv
+heads, head_dim 128, bf16, the serving engine's default page pool).
+
+Interpret mode cannot see what Mosaic refuses (block shapes off the
+(8, 128) tiling, too much VMEM/SMEM), so these compiles are the guard
+between the CPU tests and the first chip run. A compile that passes is
+not a chip run: nothing here executes.
+
+Everything that touches the TPU compiler happens inside fixtures/tests of
+THIS file (never at import, never in conftest): only one process may
+hold libtpu, and pytest-xdist workers all import every test module.
+"""
+import functools
+import importlib
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+# Llama-3-8B attention geometry + ContinuousServingEngine defaults
+HEADS, KV_HEADS, HEAD_DIM = 32, 8, 128
+PAGE_SIZE, MAX_BATCH, MAX_LEN = 16, 8, 2048
+PAGES_PER_SEQ = MAX_LEN // PAGE_SIZE              # 128
+NUM_PAGES = MAX_BATCH * PAGES_PER_SEQ + 1         # 1025 (page 0 = scratch)
+TOKEN_BUDGET = 256                                # ragged tick bucket
+TRAIN_SEQ = 2048
+SM_SCALE = HEAD_DIM ** -0.5
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_on_chip(one_chip):
+    """compile(fn, *(shape, dtype)) -> compiled text for one v5e chip."""
+    def _compile(fn, *specs):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        return jax.jit(fn).lower(*args).compile().as_text()
+    return _compile
+
+
+def _pool(dtype):
+    return ((KV_HEADS, NUM_PAGES, PAGE_SIZE, HEAD_DIM), dtype)
+
+
+_SCALES = ((KV_HEADS, NUM_PAGES, PAGE_SIZE), jnp.float32)
+
+
+def _mixed_tick():
+    """A ragged tick as the default scheduler packs it: 6 decode tokens +
+    one 250-token prefill chunk of a sequence with 300 tokens of context
+    already paged in, padded to the 256-token bucket."""
+    rng = np.random.default_rng(0)
+    tables = rng.integers(1, NUM_PAGES, (MAX_BATCH, PAGES_PER_SEQ)).astype(
+        np.int32)
+    n_dec = 6
+    seq_slots = np.arange(n_dec + 1, dtype=np.int32)
+    q_starts = np.arange(n_dec + 1, dtype=np.int32)
+    q_lens = np.array([1] * n_dec + [TOKEN_BUDGET - n_dec], np.int32)
+    ctx = np.array([700, 650, 400, 333, 128, 17, 300 + TOKEN_BUDGET - n_dec],
+                   np.int32)
+    return tables, seq_slots, q_starts, q_lens, ctx
+
+
+def _has_kernel(text):
+    return "tpu_custom_call" in text
+
+
+def test_flash_fwd_compiles(compile_on_chip):
+    from paddle_tpu.ops.pallas import flash_attention
+    fn = functools.partial(flash_attention, causal=True, interpret=False)
+    q = ((1, TRAIN_SEQ, HEADS, HEAD_DIM), jnp.bfloat16)
+    kv = ((1, TRAIN_SEQ, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+    assert _has_kernel(compile_on_chip(fn, q, kv, kv))
+
+
+def test_flash_fwd_bwd_compiles(compile_on_chip):
+    from paddle_tpu.ops.pallas import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    q = ((1, TRAIN_SEQ, HEADS, HEAD_DIM), jnp.bfloat16)
+    kv = ((1, TRAIN_SEQ, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+    text = compile_on_chip(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3      # fwd + dq + dkv
+
+
+def test_ring_partial_with_lse_compiles(compile_on_chip):
+    """The blockwise/ring partial (sep long-context prefill): one
+    512-token stripe of queries against one stripe of keys, returning
+    (out, lse) for the online-softmax merge."""
+    from paddle_tpu.ops.pallas.ring_attention import ring_partial
+    stripe = 512
+    fn = functools.partial(ring_partial, q_offset=stripe, kv_offset=0,
+                           sm_scale=SM_SCALE, impl="kernel",
+                           interpret=False)
+    q = ((1, HEADS, stripe, HEAD_DIM), jnp.bfloat16)
+    kv = ((1, KV_HEADS, stripe, HEAD_DIM), jnp.bfloat16)
+    assert _has_kernel(compile_on_chip(fn, q, kv, kv))
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode_compiles(compile_on_chip, kv_dtype):
+    pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+    q = ((MAX_BATCH, HEADS, HEAD_DIM), jnp.bfloat16)
+    tables = ((MAX_BATCH, PAGES_PER_SEQ), jnp.int32)   # SMEM-prefetched
+    lens = ((MAX_BATCH,), jnp.int32)
+    if kv_dtype == "int8":
+        def fn(q, kp, vp, ks, vs, tbl, ln):
+            return pa._paged_attention_pallas_quant(
+                q, kp, vp, ks, vs, tbl, ln, sm_scale=SM_SCALE,
+                interpret=False)
+        text = compile_on_chip(fn, q, _pool(jnp.int8), _pool(jnp.int8),
+                               _SCALES, _SCALES, tables, lens)
+    else:
+        def fn(q, kp, vp, tbl, ln):
+            return pa._paged_attention_pallas(
+                q, kp, vp, tbl, ln, sm_scale=SM_SCALE, interpret=False)
+        text = compile_on_chip(fn, q, _pool(jnp.bfloat16),
+                               _pool(jnp.bfloat16), tables, lens)
+    assert _has_kernel(text)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_ragged_per_token_compiles(compile_on_chip, kv_dtype):
+    rpa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    q = ((TOKEN_BUDGET, HEADS, HEAD_DIM), jnp.bfloat16)
+    tables = ((MAX_BATCH, PAGES_PER_SEQ), jnp.int32)   # SMEM-prefetched
+    tok = ((TOKEN_BUDGET,), jnp.int32)
+    if kv_dtype == "int8":
+        def fn(q, kp, vp, ks, vs, tbl, slot, ctx):
+            return rpa._ragged_paged_attention_pallas_quant(
+                q, kp, vp, ks, vs, tbl, slot, ctx, sm_scale=SM_SCALE,
+                interpret=False)
+        text = compile_on_chip(fn, q, _pool(jnp.int8), _pool(jnp.int8),
+                               _SCALES, _SCALES, tables, tok, tok)
+    else:
+        def fn(q, kp, vp, tbl, slot, ctx):
+            return rpa._ragged_paged_attention_pallas(
+                q, kp, vp, tbl, slot, ctx, sm_scale=SM_SCALE,
+                interpret=False)
+        text = compile_on_chip(fn, q, _pool(jnp.bfloat16),
+                               _pool(jnp.bfloat16), tables, tok, tok)
+    assert _has_kernel(text)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_ragged_qblock_compiles(compile_on_chip, kv_dtype):
+    """The q-block grid builds its job schedule host-side, so the
+    descriptors are concrete (as in the eager serving tick) and only the
+    tensors are described."""
+    rpa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    tables, seq_slots, q_starts, q_lens, ctx = _mixed_tick()
+    q = ((TOKEN_BUDGET, HEADS, HEAD_DIM), jnp.bfloat16)
+    if kv_dtype == "int8":
+        def fn(q, kp, vp, ks, vs):
+            return rpa._ragged_paged_attention_pallas_qblock(
+                q, kp, vp, tables, seq_slots, q_starts, q_lens, ctx,
+                sm_scale=SM_SCALE, interpret=False, k_scales=ks,
+                v_scales=vs)
+        text = compile_on_chip(fn, q, _pool(jnp.int8), _pool(jnp.int8),
+                               _SCALES, _SCALES)
+    else:
+        def fn(q, kp, vp):
+            return rpa._ragged_paged_attention_pallas_qblock(
+                q, kp, vp, tables, seq_slots, q_starts, q_lens, ctx,
+                sm_scale=SM_SCALE, interpret=False)
+        text = compile_on_chip(fn, q, _pool(jnp.bfloat16),
+                               _pool(jnp.bfloat16))
+    assert _has_kernel(text)
+
+
+def test_int8_matmul_compiles(compile_on_chip):
+    """Weight-only int8 GEMM at the 8B MLP up-projection: a 256-token
+    tick against [hidden 4096, intermediate 14336]."""
+    from paddle_tpu.ops.pallas.quant_matmul import int8_matmul
+    fn = functools.partial(int8_matmul, interpret=False)
+    text = compile_on_chip(fn, ((TOKEN_BUDGET, 4096), jnp.bfloat16),
+                           ((4096, 14336), jnp.int8),
+                           ((14336,), jnp.float32))
+    assert _has_kernel(text)
+
+
+def test_sdpa_flash_under_a_mesh_compiles(topo, monkeypatch):
+    """GSPMD refuses to partition a Mosaic kernel ("wrap the call in a
+    shard_map"): under a sharding x mp mesh SDPA must hand the flash kernel
+    per-device shards. The CPU suite never reaches this branch with a real
+    kernel, so the four-device compile is its guard."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.framework.core import Tensor
+    from paddle_tpu.nn import functional as F
+
+    # SDPA picks flash by platform; the compiler is described, not attached
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = mesh_mod.init_mesh({"sharding": 2, "mp": 2}, devices=topo.devices)
+    try:
+        sh = NamedSharding(mesh, P(("dp", "sharding"), None, "mp", None))
+        q = jax.ShapeDtypeStruct((2, 1024, HEADS, HEAD_DIM), jnp.bfloat16,
+                                 sharding=sh)
+        kv = jax.ShapeDtypeStruct((2, 1024, KV_HEADS, HEAD_DIM),
+                                  jnp.bfloat16, sharding=sh)
+
+        def fn(q, k, v):
+            return F.scaled_dot_product_attention(
+                Tensor(q), Tensor(k), Tensor(v), is_causal=True,
+                training=False)._data
+
+        with mesh:
+            text = jax.jit(fn).lower(q, kv, kv).compile().as_text()
+    finally:
+        mesh_mod.reset_mesh()
+    assert _has_kernel(text)

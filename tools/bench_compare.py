@@ -1,7 +1,7 @@
-"""Bench record comparator: diff two ``BENCH_*.json`` records with
+"""Bench record comparator: diff two ``bench.py`` records with
 per-metric direction + threshold rules and exit 1 on regression.
 
-The bench trajectory (``BENCH_r*.json``, ``bench.py``'s one-line JSON)
+The bench trajectory (``bench.py``'s one-line JSON, saved per run)
 is only useful if a regression between two records is *mechanically*
 detectable — a human eyeballing "26.1 vs 24.9 images/sec" does not
 scale to the aux-metric surface (phase fractions, peak bytes, p95s,

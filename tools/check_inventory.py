@@ -173,8 +173,6 @@ INVENTORY = [
     ("Text datasets (cache-gated)", "paddle_tpu.text",
      ["UCIHousing", "Imdb", "Imikolov"]),
     # -- round 3 additions ---------------------------------------------------
-    ("Kernel compile guard (wedge-proof)", "paddle_tpu.utils.guarded_compile",
-     ["prove", "kernel_allowed", "CANARIES"]),
     ("Ulysses all-to-all context parallel", "paddle_tpu.distributed.fleet.utils",
      ["ulysses_attention", "UlyssesAttention"]),
     ("Continuous-batching serving", "paddle_tpu.inference",
