@@ -1,0 +1,3 @@
+"""Device: share of the traced window in which no operation ran on the
+device, in the cells that report ``train_tok_s``."""
+from benchmark.trace_reduce import idle_pct as read  # noqa: F401
