@@ -23,6 +23,7 @@ from ..framework.core import Tensor
 from ..profiler import request_trace as _rt
 from ..profiler import ledger as _ledger
 from ..profiler import compile_observatory as _co
+from ..profiler import spans as _spans
 
 #: default token budget of one chunked-prefill step (overridable per
 #: engine via ``prefill_chunk_tokens=`` or PADDLE_SERVING_CHUNK_TOKENS)
@@ -1218,6 +1219,12 @@ class ContinuousServingEngine:
         loop, interleaved with decode steps). Prompts past the sep
         threshold route to the sep-parallel ring-prefill queue instead
         of the paged prefix path."""
+        n0 = self.prefills
+        with _spans.span("kv/admit") as sp:
+            self._admit_rows(cache, free, active, pending, prefill_q, sep_q)
+            sp.set(admitted=self.prefills - n0)
+
+    def _admit_rows(self, cache, free, active, pending, prefill_q, sep_q):
         tele = _telemetry()
         while free and pending:
             row = pending.popleft()
@@ -1460,6 +1467,7 @@ class ContinuousServingEngine:
 
         was_training = self.model.training
         self.model.eval()
+        tick = phase = _spans.NULL
         try:
             cache = self._new_cache()
             free: deque = deque(range(self.max_batch))
@@ -1511,6 +1519,12 @@ class ContinuousServingEngine:
                     if not enqueue(self._q.get()):
                         self._running = False
                         continue     # drain in-flight rows before exit
+                # the tick's spans (docs/OBSERVABILITY.md): schedule,
+                # kv/begin_ragged, forward, sync and emit partition
+                # serve/tick; ``phase`` is whichever of them is open
+                tracing = _spans.latch()
+                tick = _spans.span("serve/tick").begin()
+                phase = _spans.span("serve/schedule").begin()
                 if not draining:
                     try:
                         while True:
@@ -1651,6 +1665,8 @@ class ContinuousServingEngine:
                     self._mirror_kv_tier(tele, cache)
                     self._sep_tick(cache, free, active, sep_q)
                     if not spans:
+                        phase.discard()
+                        tick.discard()
                         continue
                     total = off
                     padded = _token_bucket(total, self.token_budget)
@@ -1668,15 +1684,22 @@ class ContinuousServingEngine:
                         else:
                             flat[qs:qs + n] = row.prompt[start:start + n]
                             pos[qs:qs + n] = np.arange(start, start + n)
+                    ragged = [(slot, qs, n) for slot, qs, _, n, _ in spans]
                     t_step = time.perf_counter()
-                    cache.begin_ragged(
-                        [(slot, qs, n) for slot, qs, _, n, _ in spans])
+                    phase.end()
+                    cache.begin_ragged(ragged)      # its own span
+                    phase = _spans.span("serve/forward").begin()
                     logits = self.model.forward(Tensor(flat[None]),
                                                 cache=cache,
                                                 position_ids=pos)
+                    phase.end()
+                    # the tick's one sync: the host waits for the device
+                    phase = _spans.span("serve/sync").begin()
                     lg = logits._data[0].astype(jnp.float32)  # [padded, V]
                     greedy = np.asarray(jnp.argmax(lg, axis=-1))
                     step_dt = time.perf_counter() - t_step
+                    phase.end()
+                    phase = _spans.span("serve/emit").begin()
                     self.ragged_steps += 1
                     self.ragged_buckets_used.add(padded)
                     # compile observatory: one program-boundary record
@@ -1742,6 +1765,7 @@ class ContinuousServingEngine:
 
                     # prefill spans: advance, register finished prompts,
                     # hand completed rows to the decode path
+                    first_tokens = emitted = 0
                     for slot, qs, start, n, kind in spans:
                         if kind != "prefill":
                             continue
@@ -1756,6 +1780,7 @@ class ContinuousServingEngine:
                         row.state = "decode"
                         self._push_token(cache, free, active, slot,
                                          sample(qs + n - 1, row))
+                        first_tokens += 1
                     # decode spans: verify drafted tokens against the
                     # target model's own choices — the target token at
                     # span offset j is valid iff every draft before it
@@ -1766,7 +1791,6 @@ class ContinuousServingEngine:
                         self.decode_steps += 1
                         self.events.append(("decode", len(decode_slots)))
                         tele["decode_step"].observe(step_dt)
-                        emitted = 0
                         for slot, qs, start, n, kind in spans:
                             if kind != "decode":
                                 continue
@@ -1801,7 +1825,16 @@ class ContinuousServingEngine:
                         for _ in range(emitted):
                             tele["token"].observe(
                                 step_dt / max(emitted, 1))
+                    phase.end(emitted=first_tokens + emitted)
+                    if tracing:
+                        tick.end(tick=self.ragged_steps, useful=total,
+                                 padded=padded, n_decode=n_decode,
+                                 n_prefill=n_prefill,
+                                 spans=[[n, start + n]
+                                        for _, _, start, n, _ in spans])
                 except Exception as e:      # fail everything in flight
+                    phase.end()
+                    tick.end(error=type(e).__name__)
                     reqs = {r.req for r in pending}
                     reqs |= {r.req for r in active if r is not None}
                     for req in reqs:
@@ -1814,6 +1847,8 @@ class ContinuousServingEngine:
                     free = deque(range(self.max_batch))
                     cache = self._new_cache()
         finally:
+            phase.discard()       # only what an escaping error left open
+            tick.discard()
             if was_training:
                 self.model.train()
 

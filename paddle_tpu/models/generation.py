@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from ..framework.core import Tensor
 from ..autograd.tape import no_grad
 from ..framework import random as prandom
+from ..profiler import spans as _spans
 
 __all__ = ["KVCache", "PagedKVCache", "SlotPagedKVCache", "HostKVPool",
            "GenerationMixin", "block_hash_chain", "quantize_kv_rows",
@@ -756,18 +757,19 @@ class SlotPagedKVCache:
         bucket padding — their K/V scatters to the scratch page and
         their output is discarded. Pages are allocated and
         copy-on-write-resolved here, once per step, for every span."""
-        spans = [(int(s), int(qs), int(n)) for s, qs, n in spans]
-        for slot, _, n_new in spans:
-            start = int(self.lens[slot])
-            if start + n_new > self.max_len:
-                raise ValueError(f"slot overflow: {start}+{n_new} > "
-                                 f"{self.max_len}")
-            self._ensure_blocks(slot, start + n_new)
-            for blk in range(start // self.page_size,
-                             -(-(start + n_new) // self.page_size)):
-                self._make_writable(slot, blk)
-        self._mode = ("ragged", spans)
-        self._idx = None
+        with _spans.span("kv/begin_ragged"):
+            spans = [(int(s), int(qs), int(n)) for s, qs, n in spans]
+            for slot, _, n_new in spans:
+                start = int(self.lens[slot])
+                if start + n_new > self.max_len:
+                    raise ValueError(f"slot overflow: {start}+{n_new} > "
+                                     f"{self.max_len}")
+                self._ensure_blocks(slot, start + n_new)
+                for blk in range(start // self.page_size,
+                                 -(-(start + n_new) // self.page_size)):
+                    self._make_writable(slot, blk)
+            self._mode = ("ragged", spans)
+            self._idx = None
 
     def free(self, slot):
         slot = int(slot)

@@ -23,6 +23,7 @@ from ..nn.initializer import Normal
 from ..ops import fused as fused_ops
 from ..ops import math as pmath
 from ..autograd.tape import apply
+from ..profiler import spans as _spans
 from .generation import GenerationMixin
 
 
@@ -219,11 +220,12 @@ class LlamaModel(Layer):
             # per-layer remat (reference recompute_granularity='full'):
             # under jit this wraps each decoder layer in jax.checkpoint
             from ..distributed.fleet.utils import recompute as remat
-        for layer in self.layers:
+        for i, layer in enumerate(self.layers):
             if recompute:
                 hidden = remat(layer, hidden, attn_mask, position_ids)
             else:
-                hidden = layer(hidden, attn_mask, position_ids, cache)
+                with _spans.span("model/layer", i=i):
+                    hidden = layer(hidden, attn_mask, position_ids, cache)
             hidden = shard_activation(hidden)
         hidden = self.norm(hidden)
         if cache is not None:

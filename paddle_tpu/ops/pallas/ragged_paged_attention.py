@@ -67,6 +67,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...profiler import spans as _spans
 from .paged_attention import NEG_INF, _device_call, _scale_rows
 
 #: finite cross-span mask for the q-block kernel. The causal bound keeps
@@ -318,20 +319,26 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
     kv_heads, _, page_size, _ = k_pages.shape
     group = heads // kv_heads
     qb = q_block or _qblock_rows()
-    row_slot, row_ctx, job_page, job_slot, job_kv = qblock_schedule(
-        tokens, seq_slots, q_starts, q_lens, context_lens, block_tables,
-        qb, page_size)
-    nblocks = job_page.shape[0]
-    # per-ROW metadata rides as [B, Qg, 128] VMEM lanes so the kernel
-    # can slice [:, :1] — the same layout trick the softmax scratch uses
-    # (broadcast host-side: one transfer, no extra eager device ops)
-    rows = np.repeat(row_slot.reshape(nblocks, qb), group, axis=1)
-    rowc = np.repeat(row_ctx.reshape(nblocks, qb), group, axis=1)
-    rs = np.broadcast_to(rows[:, :, None], (nblocks, qb * group, 128))
-    rc = np.broadcast_to(rowc[:, :, None], (nblocks, qb * group, 128))
-    return _qblock_device(job_page, job_slot, job_kv, rs, rc, q, k_pages,
-                          v_pages, k_scales, v_scales, sm_scale=sm_scale,
-                          interpret=interpret)
+    with _spans.span("attn/qblock") as sp:
+        with _spans.span("attn/qblock_schedule"):
+            row_slot, row_ctx, job_page, job_slot, job_kv = qblock_schedule(
+                tokens, seq_slots, q_starts, q_lens, context_lens,
+                block_tables, qb, page_size)
+            nblocks = job_page.shape[0]
+            # per-ROW metadata rides as [B, Qg, 128] VMEM lanes so the
+            # kernel can slice [:, :1] — the same layout trick the softmax
+            # scratch uses (broadcast host-side: one transfer, no extra
+            # eager device ops)
+            rows = np.repeat(row_slot.reshape(nblocks, qb), group, axis=1)
+            rowc = np.repeat(row_ctx.reshape(nblocks, qb), group, axis=1)
+            rs = np.broadcast_to(rows[:, :, None],
+                                 (nblocks, qb * group, 128))
+            rc = np.broadcast_to(rowc[:, :, None],
+                                 (nblocks, qb * group, 128))
+        sp.set(jobs=job_page.shape[1], blocks=nblocks)
+        return _qblock_device(job_page, job_slot, job_kv, rs, rc, q,
+                              k_pages, v_pages, k_scales, v_scales,
+                              sm_scale=sm_scale, interpret=interpret)
 
 
 @_device_call

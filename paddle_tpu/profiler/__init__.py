@@ -25,6 +25,8 @@ from .telemetry import (  # noqa: F401  (re-exported facade)
     metrics, metrics_text, enable_op_telemetry, disable_op_telemetry,
     op_telemetry, spans_to_chrome,
 )
+from . import spans as _spans
+from .spans import span, tracing_active  # noqa: F401
 from . import flight_recorder  # noqa: F401
 from .flight_recorder import (  # noqa: F401  (re-exported facade)
     FlightRecorder, Watchdog, get_flight_recorder, gather_metrics,
@@ -84,7 +86,7 @@ from .compile_observatory import (  # noqa: F401  (re-exported facade)
 __all__ = [
     "Profiler", "ProfilerTarget", "ProfilerState", "make_scheduler",
     "export_chrome_tracing", "export_protobuf", "RecordEvent", "load_profiler_result",
-    "benchmark", "comm_stats",
+    "benchmark", "comm_stats", "span", "tracing_active",
     "MetricRegistry", "SpanTracer", "get_registry", "get_tracer",
     "metrics", "metrics_text", "enable_op_telemetry", "disable_op_telemetry",
     "FlightRecorder", "Watchdog", "get_flight_recorder", "gather_metrics",
@@ -201,33 +203,23 @@ def export_protobuf(dir_name, worker_name=None):
 
 
 class RecordEvent:
-    """User annotation: a real nested span in the host trace (span tracer:
-    wall-clock begin/duration, thread id, parent linkage) plus a
-    TraceAnnotation in the device trace. Usable as context manager or
+    """User annotation: ``profiler.span`` under the user's own name (a
+    ``paddle_tpu:<name>`` annotation in the device trace plus a nested span
+    in the span tracer: wall-clock begin/duration, thread id, parent
+    linkage), on whenever a profiler records. Usable as context manager or
     begin()/end()."""
 
     def __init__(self, name, event_type=None):
         self.name = name
-        self._ann = None
         self._t0 = None
-        self._span = None
+        self._span = _spans.NULL
 
     def begin(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
         self._t0 = time.perf_counter()
-        self._span = get_tracer().begin(self.name, kind="user")
-        prof = Profiler._current
-        if prof is not None and prof._recording:
-            prof._open_events.append(self)
+        self._span = _spans.open_span(self.name, kind="user")
 
     def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-        if self._span is not None:
-            get_tracer().end(self._span)
-            self._span = None
+        self._span.end()
         prof = Profiler._current
         if prof is not None and prof._recording and self._t0 is not None:
             dt = time.perf_counter() - self._t0
@@ -274,7 +266,6 @@ class Profiler:
         self._state = ProfilerState.CLOSED
         self._recording = False
         self._op_stats = defaultdict(lambda: (0, 0.0))
-        self._open_events = []
         self._step_times = []
         self._t_step = None
         self._jax_tracing = False

@@ -480,6 +480,12 @@ class SpanTracer:
     def enabled(self) -> bool:
         return self._enabled > 0
 
+    @property
+    def origin(self) -> float:
+        """``time.perf_counter()`` at ``ts`` 0: ``origin + span.ts`` is a
+        span's start on the clock of everybody else's stamps."""
+        return self._t0
+
     def _tid(self):
         ident = threading.get_ident()
         tid = self._tids.get(ident)
@@ -509,26 +515,35 @@ class SpanTracer:
         Must be closed with :meth:`end` on the same thread."""
         if not self.enabled:
             return None
-        sp = self._new_span(name, time.perf_counter() - self._t0,
-                            args or None)
+        return self.open(name, args or None)
+
+    def open(self, name, args=None):
+        """``begin`` whether enabled or not: for ``profiler.span``, which
+        has its own switch (a ``jax.profiler`` session enables nothing
+        here)."""
+        sp = self._new_span(name, time.perf_counter() - self._t0, args)
         self._stack().append(sp)
         return sp
 
-    def end(self, span=None):
+    def end(self, span=None, record=True):
         """Close the innermost open span of this thread (or the given
-        span and anything opened after it)."""
+        span and anything opened after it). ``record=False`` drops the
+        closed spans instead of queueing them."""
         if span is None and not self.enabled:
             return None
         stack = self._stack()
         if not stack:
             return None
+        if span is not None and span not in stack:
+            return None                  # closed already, with its parent
         now = time.perf_counter() - self._t0
-        target = span if span in stack else stack[-1]
+        target = span if span is not None else stack[-1]
         while stack:
             sp = stack.pop()
             sp.dur = max(now - sp.ts, 0.0)
-            with self._lock:
-                self._done.append(sp)
+            if record:
+                with self._lock:
+                    self._done.append(sp)
             if sp is target:
                 return sp
         return None
@@ -570,6 +585,11 @@ class SpanTracer:
             out = list(self._done)
             self._done.clear()
         return out
+
+    def completed(self):
+        """Every completed span, left in place (``drain`` clears)."""
+        with self._lock:
+            return list(self._done)
 
     def __len__(self):
         return len(self._done)

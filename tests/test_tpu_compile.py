@@ -15,6 +15,7 @@ hold libtpu, and pytest-xdist workers all import every test module.
 import functools
 import importlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -192,6 +193,65 @@ def test_ragged_qblock_compiles(compile_on_chip, kv_dtype):
         text = compile_on_chip(fn, q, _pool(jnp.bfloat16),
                                _pool(jnp.bfloat16))
     assert _has_kernel(text)
+
+
+def _roofline_patterns(metric):
+    """The module of a roofline reader, for the regexes it tells its
+    kernels' device events by (a benchmark file no program PR may edit)."""
+    return importlib.import_module("benchmark.layer_metrics." + metric)
+
+
+def _custom_calls(text):
+    """A device event's name in a trace is its whole HLO instruction: the
+    compiled text's custom-call lines are those names."""
+    return [ln.strip() for ln in text.splitlines()
+            if "tpu_custom_call" in ln and " = " in ln]
+
+
+def test_qblock_device_event_name_is_the_one_its_roofline_matches(
+        compile_on_chip):
+    """``qblock_roofline`` finds the kernel's device time by the name the
+    jitted wrapper ``_qblock_device`` gives its Mosaic call. A rename (a
+    ``named_scope``, a ``pallas_call(name=)``, another wrapper name)
+    silences the metric, and a traced run that lacks it is refused: it
+    fails here first, on the CPU."""
+    rpa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    tables, seq_slots, q_starts, q_lens, ctx = _mixed_tick()
+
+    def fn(q, kp, vp):
+        return rpa._ragged_paged_attention_pallas_qblock(
+            q, kp, vp, tables, seq_slots, q_starts, q_lens, ctx,
+            sm_scale=SM_SCALE, interpret=False)
+
+    text = compile_on_chip(fn, ((TOKEN_BUDGET, HEADS, HEAD_DIM),
+                                jnp.bfloat16),
+                           _pool(jnp.bfloat16), _pool(jnp.bfloat16))
+    calls = _custom_calls(text)
+    kernel = re.compile(_roofline_patterns("qblock_roofline").KERNEL)
+    assert calls and all(kernel.search(c) for c in calls), calls
+
+
+def test_flash_device_event_names_are_the_ones_their_roofline_matches(
+        compile_on_chip):
+    """``flash_roofline`` tells the forward kernel and the backward's two
+    (dq, dkv) by the names the jitted wrappers ``_fwd`` / ``_bwd`` give
+    them under ``jax.grad``."""
+    from paddle_tpu.ops.pallas import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    q = ((1, TRAIN_SEQ, HEADS, HEAD_DIM), jnp.bfloat16)
+    kv = ((1, TRAIN_SEQ, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+    calls = _custom_calls(
+        compile_on_chip(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv))
+    mod = _roofline_patterns("flash_roofline")
+    fwd = [c for c in calls if re.search(mod.FWD, c)]
+    bwd = [c for c in calls if re.search(mod.BWD, c)]
+    assert len(fwd) == 1 and len(bwd) == 2, calls
+    assert len(calls) == 3, calls
 
 
 def test_int8_matmul_compiles(compile_on_chip):
