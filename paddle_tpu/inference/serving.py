@@ -178,7 +178,7 @@ def _engine_state(engine) -> dict:
              "queue_depth": engine._q.qsize()}
     for attr in ("batches_run", "decode_steps", "prefills", "max_batch",
                  "prefill_chunks", "cancelled_rows", "ragged_steps",
-                 "token_budget", "ragged_prefill_tokens",
+                 "compiled_layer_calls", "token_budget", "ragged_prefill_tokens",
                  "ragged_decode_tokens", "padded_tokens_total",
                  "useful_tokens_total", "spec_drafted_tokens",
                  "spec_accepted_tokens", "spec_rounds", "spec_k",
@@ -825,6 +825,10 @@ class ContinuousServingEngine:
         self.prefill_chunks = 0        # chunk forwards run
         self.cancelled_rows = 0
         self.ragged_steps = 0          # ragged packed forwards run
+        # decoder layers of those forwards that ran as compiled programs
+        # around the kernel entry (models/llama.py); 0 on a model that
+        # runs its layers eagerly
+        self.compiled_layer_calls = 0
         self.ragged_prefill_tokens = 0
         self.ragged_decode_tokens = 0
         # padded-vs-useful accounting for BOTH schedulers (the bench's
@@ -1688,11 +1692,14 @@ class ContinuousServingEngine:
                     t_step = time.perf_counter()
                     phase.end()
                     cache.begin_ragged(ragged)      # its own span
+                    compiled0 = cache.compiled_layer_calls
                     phase = _spans.span("serve/forward").begin()
                     logits = self.model.forward(Tensor(flat[None]),
                                                 cache=cache,
                                                 position_ids=pos)
                     phase.end()
+                    compiled = cache.compiled_layer_calls - compiled0
+                    self.compiled_layer_calls += compiled
                     # the tick's one sync: the host waits for the device
                     phase = _spans.span("serve/sync").begin()
                     lg = logits._data[0].astype(jnp.float32)  # [padded, V]
@@ -1830,6 +1837,7 @@ class ContinuousServingEngine:
                         tick.end(tick=self.ragged_steps, useful=total,
                                  padded=padded, n_decode=n_decode,
                                  n_prefill=n_prefill,
+                                 compiled_layers=compiled,
                                  spans=[[n, start + n]
                                         for _, _, start, n, _ in spans])
                 except Exception as e:      # fail everything in flight

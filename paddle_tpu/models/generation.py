@@ -12,6 +12,7 @@ from __future__ import annotations
 import os
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..framework.core import Tensor
@@ -21,7 +22,7 @@ from ..profiler import spans as _spans
 
 __all__ = ["KVCache", "PagedKVCache", "SlotPagedKVCache", "HostKVPool",
            "GenerationMixin", "block_hash_chain", "quantize_kv_rows",
-           "dequantize_kv_rows", "kv_page_nbytes"]
+           "dequantize_kv_rows", "scatter_kv_rows", "kv_page_nbytes"]
 
 #: kv_dtype values SlotPagedKVCache understands (PADDLE_KV_DTYPE)
 KV_DTYPES = ("auto", "int8", "native")
@@ -45,6 +46,45 @@ def dequantize_kv_rows(q, scale, dtype=jnp.float32):
     ``scale / 2 = max|row| / 254``)."""
     return (jnp.asarray(q).astype(jnp.float32)
             * jnp.asarray(scale)[..., None]).astype(dtype)
+
+
+def _scatter_rows(pool, rows, page_ids, slot_ids):
+    """``pool[:, page_ids, slot_ids] = rows`` for ``pool`` [kv, pages,
+    page_size, ...] and ``rows`` [kv, *page_ids.shape, ...], written as a
+    scatter of whole trailing rows into the pool flattened over its three
+    leading axes: each update is then contiguous in the pool's own layout.
+    (The three-axis form makes the TPU compiler re-lay the whole pool out
+    around the scatter, two full copies a pool, once the step holds more
+    than a few tokens.)"""
+    kv, pages, page_size = pool.shape[:3]
+    tail = pool.shape[3:]
+    head = jnp.arange(kv, dtype=jnp.int32).reshape((kv,) + (1,) * page_ids.ndim)
+    flat = (head * pages + page_ids[None]) * page_size + slot_ids[None]
+    out = pool.reshape((-1,) + tail).at[flat.reshape(-1)].set(
+        rows.reshape((-1,) + tail).astype(pool.dtype))
+    return out.reshape(pool.shape)
+
+
+def scatter_kv_rows(pools, kt, vt, page_ids, slot_ids):
+    """Write one forward's K/V rows into a layer's page pools and return
+    the updated pools: ``(k_pages, v_pages)``, or ``(k_pages, v_pages,
+    k_scales, v_scales)`` for int8 pools, which quantize on scatter (each
+    ``[..., d]`` row gets its own fp32 scale, stored beside the pool). The
+    leading shape of ``kt``/``vt`` past the kv axis must match
+    ``page_ids``/``slot_ids``. Pure jnp: the eager cache calls it op by
+    op, a compiled layer program traces it over donated pools."""
+    page_ids = jnp.asarray(page_ids, jnp.int32)
+    slot_ids = jnp.asarray(slot_ids, jnp.int32)
+    if len(pools) == 4:
+        k_pages, v_pages, ks, vs = pools
+        kt, ks_new = quantize_kv_rows(kt)
+        vt, vs_new = quantize_kv_rows(vt)
+        scales = (_scatter_rows(ks, ks_new, page_ids, slot_ids),
+                  _scatter_rows(vs, vs_new, page_ids, slot_ids))
+    else:
+        (k_pages, v_pages), scales = pools, ()
+    return (_scatter_rows(k_pages, kt, page_ids, slot_ids),
+            _scatter_rows(v_pages, vt, page_ids, slot_ids)) + scales
 
 
 def kv_page_nbytes(kv_heads, head_dim, page_size=16, kv_dtype="native",
@@ -442,6 +482,10 @@ class SlotPagedKVCache:
         # speculative-decode rejection accounting (rollback())
         self.rollbacks = 0
         self.tokens_rolled_back = 0
+        # decoder layers of ragged steps that a model ran as compiled
+        # programs around the kernel entry (the model counts them here;
+        # the serving engine mirrors the tick's delta)
+        self.compiled_layer_calls = 0
         # tiered KV: host-RAM second level under the prefix index.
         # ``host_pool=None`` builds a private pool from the env knob
         # (PADDLE_KV_HOST_POOL_MB=0 keeps the tier off); the serving
@@ -1146,25 +1190,30 @@ class SlotPagedKVCache:
                 self._scales[key] = (ks, vs)
         return self._pools[key]
 
-    def _scatter(self, layer, k_pages, v_pages, kt, vt, page_ids, slot_ids):
-        """Write this forward's K/V rows into the pages — quantizing on
-        scatter when the pool is int8 (each ``[..., d]`` row gets its
-        own fp32 scale, stored beside the pool) — and return the updated
-        pools. The leading shape of ``kt``/``vt`` past the kv axis must
-        match ``page_ids``/``slot_ids``."""
+    def layer_pools(self, layer, kv_spec):
+        """This layer's pools as :func:`scatter_kv_rows` takes them.
+        ``kv_spec()`` -> ``(kv_heads, head_dim, dtype)`` is asked only on
+        the layer's first forward, which creates them."""
         key = id(layer)
+        if key not in self._pools:
+            self._pool(layer, *kv_spec())
+        return self._pools[key] + (self._scales[key] if self.kv_quant
+                                   else ())
+
+    def set_layer_pools(self, layer, pools):
+        key = id(layer)
+        self._pools[key] = tuple(pools[:2])
         if self.kv_quant:
-            kq, ks_new = quantize_kv_rows(kt)
-            vq, vs_new = quantize_kv_rows(vt)
-            ks, vs = self._scales[key]
-            self._scales[key] = (
-                ks.at[:, page_ids, slot_ids].set(ks_new),
-                vs.at[:, page_ids, slot_ids].set(vs_new))
-            kt, vt = kq, vq
-        new_kp = k_pages.at[:, page_ids, slot_ids].set(kt)
-        new_vp = v_pages.at[:, page_ids, slot_ids].set(vt)
-        self._pools[key] = (new_kp, new_vp)
-        return new_kp, new_vp
+            self._scales[key] = tuple(pools[2:])
+
+    def _scatter(self, layer, k_pages, v_pages, kt, vt, page_ids, slot_ids):
+        """Write this forward's K/V rows into the pages
+        (:func:`scatter_kv_rows`) and return the updated pools."""
+        pools = (k_pages, v_pages) + (self._scales[id(layer)]
+                                      if self.kv_quant else ())
+        pools = scatter_kv_rows(pools, kt, vt, page_ids, slot_ids)
+        self.set_layer_pools(layer, pools)
+        return pools[0], pools[1]
 
     def _layer_scales(self, layer):
         """(k_scales, v_scales) for the paged kernels' dequant-gather
@@ -1172,6 +1221,59 @@ class SlotPagedKVCache:
         if not self.kv_quant:
             return None, None
         return self._scales[id(layer)]
+
+    # -- the ragged step's halves, shared by ``attend`` and by a model that
+    # runs its layers as compiled programs around the kernel (llama.py) ----
+    @property
+    def ragged_armed(self):
+        """True between :meth:`begin_ragged` and the next ``begin_*``."""
+        return self._mode is not None and self._mode[0] == "ragged"
+
+    def _ragged_index(self, s):
+        """The armed step's indices over a flat batch of ``s`` tokens,
+        built once a forward and shared by every layer: the scatter's
+        ``page_ids`` / ``slot_ids`` on the device, and the kernel's
+        descriptors as HOST arrays (block tables, then slot, q_start,
+        q_len and context length a span) — the q-block schedule is built
+        from them on the host, so a device copy would only be read back."""
+        if self._idx is None:
+            spans = self._mode[1]
+            page_ids = np.zeros(s, np.int64)     # default: scratch
+            slot_ids = np.zeros(s, np.int64)
+            for slot, qs, n_new in spans:
+                pos = np.arange(self.lens[slot], self.lens[slot] + n_new)
+                page_ids[qs:qs + n_new] = \
+                    self._tables[slot, pos // self.page_size]
+                slot_ids[qs:qs + n_new] = pos % self.page_size
+            self._idx = (
+                jnp.asarray(page_ids), jnp.asarray(slot_ids),
+                self._tables.copy(),
+                np.asarray([sl for sl, _, _ in spans], np.int32),
+                np.asarray([qs for _, qs, _ in spans], np.int32),
+                np.asarray([n for _, _, n in spans], np.int32),
+                np.asarray([int(self.lens[sl]) + n for sl, _, n in spans],
+                           np.int32))
+        return self._idx
+
+    def ragged_scatter_ids(self, s):
+        """``(page_ids, slot_ids)`` [s]: where the armed step's tokens
+        land in the pools; bucket padding lands in the scratch page."""
+        return self._ragged_index(s)[:2]
+
+    def ragged_attention(self, layer, qa):
+        """The armed step's attention for ``qa`` [tokens, heads, d] over
+        this layer's pools as they stand (the step's K/V already
+        scattered): the eager kernel entry, once a layer."""
+        from ..ops.pallas.ragged_paged_attention import (
+            ragged_paged_attention)
+        tables, seq_slots, q_starts, q_lens, ctx_lens = \
+            self._ragged_index(qa.shape[0])[2:]
+        k_pages, v_pages = self._pools[id(layer)]
+        ksc, vsc = self._layer_scales(layer)
+        return ragged_paged_attention(
+            qa, k_pages, v_pages, tables, seq_slots, q_starts, q_lens,
+            ctx_lens, k_scales=ksc, v_scales=vsc,
+            interpret=jax.default_backend() != "tpu")
 
     # -- attention ----------------------------------------------------------
     def attend(self, layer, q, k, v, training=False, dropout_p=0.0):
@@ -1362,43 +1464,14 @@ class SlotPagedKVCache:
             # from the pages — causal masking inside each span comes
             # from the kernel's per-token context bound.
             assert b == 1, "ragged step packs one flat token batch"
-            spans = arg
-            if self._idx is None:       # indices shared by every layer
-                page_ids = np.zeros(s, np.int64)     # default: scratch
-                slot_ids = np.zeros(s, np.int64)
-                for slot, qs, n_new in spans:
-                    pos = np.arange(self.lens[slot],
-                                    self.lens[slot] + n_new)
-                    page_ids[qs:qs + n_new] = \
-                        self._tables[slot, pos // self.page_size]
-                    slot_ids[qs:qs + n_new] = pos % self.page_size
-                self._idx = (
-                    jnp.asarray(page_ids), jnp.asarray(slot_ids),
-                    jnp.asarray(self._tables),
-                    jnp.asarray([sl for sl, _, _ in spans], jnp.int32),
-                    jnp.asarray([qs for _, qs, _ in spans], jnp.int32),
-                    jnp.asarray([n for _, _, n in spans], jnp.int32),
-                    jnp.asarray([int(self.lens[sl]) + n
-                                 for sl, _, n in spans], jnp.int32))
-            (page_ids, slot_ids, tables, seq_slots, q_starts, q_lens,
-             ctx_lens) = self._idx
+            page_ids, slot_ids = self.ragged_scatter_ids(s)
             kt = jnp.moveaxis(ka[0], 1, 0)          # [kv, s, d]
             vt = jnp.moveaxis(va[0], 1, 0)
-            new_kp, new_vp = self._scatter(layer, k_pages, v_pages, kt, vt,
-                                           page_ids, slot_ids)
-            ksc, vsc = self._layer_scales(layer)
-
-            from ..ops.pallas.ragged_paged_attention import (
-                ragged_paged_attention)
-            import jax as _jax
-            interpret = _jax.default_backend() != "tpu"
+            self._scatter(layer, k_pages, v_pages, kt, vt, page_ids,
+                          slot_ids)
 
             def fn(qa):
-                out = ragged_paged_attention(
-                    qa[0], new_kp, new_vp, tables, seq_slots, q_starts,
-                    q_lens, ctx_lens, k_scales=ksc, v_scales=vsc,
-                    interpret=interpret)
-                return out[None]         # [1, tokens, heads, d]
+                return self.ragged_attention(layer, qa[0])[None]
             return apply(fn, q, op_name="ragged_paged_attention")
 
         # decode: one token for EVERY slot (fixed shape), per-slot ctx

@@ -11,10 +11,15 @@ implements by hand in ``fleet/layers/mpu/mp_layers.py`` (SURVEY.md §2.3).
 """
 from __future__ import annotations
 
+import copy
 import math
+import threading
 
+import jax
 import jax.numpy as jnp
 
+from ..framework.core import Tensor
+from ..framework.functional import FunctionalModule
 from ..nn.layer import Layer, LayerList
 from ..nn.layers.common import Linear, Embedding
 from ..nn.layers.norm import RMSNorm
@@ -24,7 +29,11 @@ from ..ops import fused as fused_ops
 from ..ops import math as pmath
 from ..autograd.tape import apply
 from ..profiler import spans as _spans
-from .generation import GenerationMixin
+from .generation import GenerationMixin, scatter_kv_rows
+
+
+def _raw(x):
+    return x._data if isinstance(x, Tensor) else x
 
 
 def shard_activation(x):
@@ -34,7 +43,6 @@ def shard_activation(x):
     into the residual stream and fall back to replicate-repartition
     ("Involuntary full rematerialization") — the maxtext-style activation
     annotation recipe. No-op in eager / single-device."""
-    import jax
     from ..distributed import mesh as mesh_mod
 
     spec = mesh_mod.batch_spec(3)
@@ -150,8 +158,11 @@ class LlamaAttention(Layer):
         from ..distributed import mesh as mesh_mod
         return mesh_mod.has_mesh() and mesh_mod.axis_size("sep") > 1
 
-    def forward(self, hidden, attn_mask=None, position_ids=None, cache=None):
-        from ..ops import manipulation as manip
+    def qkv(self, hidden, position_ids=None, cache=None, rope=None):
+        """Projections, head reshapes and rope -> ``(q, k, v)``, each
+        ``[b, s, heads, head_dim]``. ``rope`` = ``(cos, sin)`` replaces
+        the layer's own tables (a compiled program passes them as
+        arguments)."""
         b, s, _ = hidden.shape
         q = self.q_proj(hidden).reshape([b, s, self.num_heads, self.head_dim])
         k = self.k_proj(hidden).reshape([b, s, self.num_kv_heads, self.head_dim])
@@ -160,28 +171,38 @@ class LlamaAttention(Layer):
             # raw jnp: consumed as a closure constant by the rope op
             position_ids = jnp.arange(cache.pos, cache.pos + s,
                                       dtype=jnp.int32)
+        cos, sin = (self._cos, self._sin) if rope is None else map(_raw, rope)
         q, k, _ = fused_ops.fused_rotary_position_embedding(
-            q, k, sin=self._sin, cos=self._cos, position_ids=position_ids)
+            q, k, sin=sin, cos=cos, position_ids=_raw(position_ids))
+        return q, k, v
+
+    def attend(self, q, k, v, attn_mask=None, cache=None):
         if cache is not None:
             # decode: the cache owns its layout (concat or paged) and the
             # cache-aware attention over the filled prefix
-            out = cache.attend(self, q, k, v, training=self.training)
-        elif self._use_ring_attention():
+            return cache.attend(self, q, k, v, training=self.training)
+        if self._use_ring_attention():
             # context parallelism: seq dim sharded over 'sep'. cp_mode
             # picks the mechanism (SURVEY.md §5.7): "ring" rotates KV
             # blocks with ppermute (3); "ulysses" swaps seq<->head with
             # one all-to-all each way (2)
             if getattr(self.config, "cp_mode", "ring") == "ulysses":
                 from ..distributed.fleet.utils import ulysses_attention
-                out = ulysses_attention(q, k, v, causal=True)
-            else:
-                from ..distributed.fleet.utils import ring_attention
-                out = ring_attention(q, k, v, causal=True)
-        else:
-            out = F.scaled_dot_product_attention(
-                q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
-                training=self.training)
+                return ulysses_attention(q, k, v, causal=True)
+            from ..distributed.fleet.utils import ring_attention
+            return ring_attention(q, k, v, causal=True)
+        return F.scaled_dot_product_attention(
+            q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
+            training=self.training)
+
+    def project(self, out):
+        """``[b, s, heads, head_dim]`` attention output -> ``o_proj``."""
+        b, s = out.shape[:2]
         return self.o_proj(out.reshape([b, s, self.num_heads * self.head_dim]))
+
+    def forward(self, hidden, attn_mask=None, position_ids=None, cache=None):
+        q, k, v = self.qkv(hidden, position_ids, cache)
+        return self.project(self.attend(q, k, v, attn_mask, cache))
 
 
 class LlamaDecoderLayer(Layer):
@@ -193,10 +214,133 @@ class LlamaDecoderLayer(Layer):
         self.post_attention_layernorm = RMSNorm(config.hidden_size,
                                                 config.rms_norm_eps)
 
-    def forward(self, hidden, attn_mask=None, position_ids=None, cache=None):
-        hidden = hidden + self.self_attn(self.input_layernorm(hidden),
-                                         attn_mask, position_ids, cache)
+    # The layer's mathematics, in two pieces around the attention: every
+    # caller runs these, eagerly in order (``forward``) or as two compiled
+    # programs around the eager kernel entry (``RaggedLayerPrograms``).
+    def pre_attention(self, hidden, position_ids=None, cache=None, rope=None):
+        return self.self_attn.qkv(self.input_layernorm(hidden), position_ids,
+                                  cache, rope)
+
+    def post_attention(self, hidden, attn_out):
+        hidden = hidden + self.self_attn.project(attn_out)
         return hidden + self.mlp(self.post_attention_layernorm(hidden))
+
+    def forward(self, hidden, attn_mask=None, position_ids=None, cache=None):
+        q, k, v = self.pre_attention(hidden, position_ids, cache)
+        return self.post_attention(
+            hidden, self.self_attn.attend(q, k, v, attn_mask, cache))
+
+
+def _structural_twin(layer):
+    """A copy of ``layer``'s structure whose parameters and buffers hold no
+    data (no array is copied: the copy shares them until they are dropped)."""
+    shared = {id(t._data): t._data
+              for t in list(layer.parameters()) + list(layer.buffers())
+              if t is not None}
+    for sub in layer.sublayers(include_self=True):
+        shared.update((id(v), v) for v in vars(sub).values()
+                      if isinstance(v, jax.Array))
+    twin = copy.deepcopy(layer, shared)
+    for t in list(twin.parameters()) + list(twin.buffers()):
+        if t is not None:
+            t._data = None
+    return twin
+
+
+class RaggedLayerPrograms:
+    """A decoder layer of a ragged serving tick as two compiled programs
+    around the unchanged eager kernel entry:
+
+    1. pre-attention: ``pre_attention`` (norm, q/k/v, reshapes, rope at
+       the tick's positions) and the K/V scatter into the layer's page
+       pools, which are donated, so the scatter updates them in place;
+    2. ``cache.ragged_attention``: ``ragged_paged_attention`` eagerly, its
+       descriptors host values;
+    3. post-attention: ``post_attention`` (o_proj, residual, norm, MLP,
+       residual).
+
+    Weights, rope tables, positions, page and slot ids and pools are all
+    ARGUMENTS: the programs are traced once over a twin of ``layers[0]`` and keyed
+    by shapes and dtypes alone, so every layer, every tick and every cache
+    of one geometry share one executable a token bucket, weights swapped
+    after construction are followed, and nothing here keeps an array
+    alive. That sharing is sound only while a layer's state is its
+    parameters and buffers: ``usable()`` says whether it is."""
+
+    def __init__(self, layers):
+        self._layers = list(layers)
+        self._state = {id(l): ([p for p in l.parameters() if p is not None],
+                               [b for b in l.buffers() if b is not None])
+                       for l in self._layers}
+        # a trace swaps the traced layer's arrays for tracers and the
+        # global generator for the trace's key (``FunctionalModule``), and
+        # thread-tier replicas share one model: the programs are traced
+        # over a private twin of layer 0 that holds no data, one trace at
+        # a time, so no thread ever reads a tracer out of a served layer
+        twin = _structural_twin(self._layers[0])
+        self._tracing = threading.Lock()
+        pre = FunctionalModule(twin, method=twin.pre_attention,
+                               training=False)
+        post = FunctionalModule(twin, method=twin.post_attention,
+                                training=False)
+
+        def qkv(p, b, cos, sin, hidden, pos):
+            with self._tracing:       # this body runs only under a trace
+                # no op of the pieces draws from the key (inference)
+                (q, k, v), _ = pre(p, b, jax.random.key(0), hidden, pos,
+                                   rope=(cos, sin))
+            return q, k, v
+
+        def pre_fn(p, b, cos, sin, hidden, pos, page_ids, slot_ids, pools):
+            q, k, v = qkv(p, b, cos, sin, hidden, pos)
+            kt = jnp.moveaxis(k[0], 1, 0)           # [kv, s, d]
+            vt = jnp.moveaxis(v[0], 1, 0)
+            return q[0], scatter_kv_rows(pools, kt, vt, page_ids, slot_ids)
+
+        def post_fn(p, b, hidden, attn_out):
+            with self._tracing:
+                return post(p, b, jax.random.key(0), hidden,
+                            attn_out[None])[0]
+
+        self._qkv = qkv
+        self.pre = jax.jit(pre_fn, donate_argnums=(8,))
+        self.post = jax.jit(post_fn)
+        self._kv_dtype = {}          # hidden dtype -> k's dtype after rope
+
+    def usable(self):
+        """False where some layer carries state the programs would bake
+        in as layer 0's constants: int8 weight streams
+        (``quantization.quantize_linears``) live outside the parameters."""
+        return not any(getattr(sub, "_w_int8", None) is not None
+                       for l in self._layers
+                       for sub in l.sublayers(include_self=True))
+
+    def program_counts(self):
+        """Executables held, by piece: one a token bucket met so far."""
+        return {"pre": self.pre._cache_size(),
+                "post": self.post._cache_size()}
+
+    def run(self, layer, hidden, pos, cache):
+        """One layer over ``hidden`` [1, tokens, hidden] (raw array)."""
+        params, buffers = self._state[id(layer)]
+        p = [t._data for t in params]
+        b = [t._data for t in buffers]
+        attn = layer.self_attn
+        cos, sin = attn._cos, attn._sin
+
+        def kv_spec():
+            if hidden.dtype not in self._kv_dtype:
+                k = jax.eval_shape(self._qkv, p, b, cos, sin, hidden, pos)[1]
+                self._kv_dtype[hidden.dtype] = k.dtype
+            return (attn.num_kv_heads, attn.head_dim,
+                    self._kv_dtype[hidden.dtype])
+
+        page_ids, slot_ids = cache.ragged_scatter_ids(hidden.shape[1])
+        q, pools = self.pre(p, b, cos, sin, hidden, pos, page_ids, slot_ids,
+                            cache.layer_pools(layer, kv_spec))
+        cache.set_layer_pools(layer, pools)
+        cache.compiled_layer_calls += 1
+        return self.post(p, b, hidden, cache.ragged_attention(layer, q))
 
 
 class LlamaModel(Layer):
@@ -209,6 +353,19 @@ class LlamaModel(Layer):
         self.layers = LayerList(
             [LlamaDecoderLayer(config) for _ in range(config.num_hidden_layers)])
         self.norm = RMSNorm(config.hidden_size, config.rms_norm_eps)
+        self._programs = None        # RaggedLayerPrograms, on first use
+
+    def _ragged_programs(self, cache, hidden, position_ids):
+        """The compiled layer programs where this forward can run them:
+        a ragged step armed on the cache (``begin_ragged``), concrete
+        inputs (a caller that traces the model sees the eager pieces) and
+        explicit positions; else None."""
+        if not getattr(cache, "ragged_armed", False) or position_ids is None \
+                or isinstance(hidden._data, jax.core.Tracer):
+            return None
+        if self._programs is None:
+            self._programs = RaggedLayerPrograms(self.layers)
+        return self._programs if self._programs.usable() else None
 
     def forward(self, input_ids, attn_mask=None, position_ids=None,
                 cache=None):
@@ -220,11 +377,18 @@ class LlamaModel(Layer):
             # per-layer remat (reference recompute_granularity='full'):
             # under jit this wraps each decoder layer in jax.checkpoint
             from ..distributed.fleet.utils import recompute as remat
+        programs = self._ragged_programs(cache, hidden, position_ids)
+        if programs is not None:
+            pos = jnp.asarray(_raw(position_ids))     # one upload a tick
         for i, layer in enumerate(self.layers):
             if recompute:
                 hidden = remat(layer, hidden, attn_mask, position_ids)
+            elif programs is not None:
+                with _spans.span("model/layer", i=i, compiled=1):
+                    hidden = Tensor(programs.run(layer, hidden._data, pos,
+                                                 cache))
             else:
-                with _spans.span("model/layer", i=i):
+                with _spans.span("model/layer", i=i, compiled=0):
                     hidden = layer(hidden, attn_mask, position_ids, cache)
             hidden = shard_activation(hidden)
         hidden = self.norm(hidden)
@@ -246,7 +410,6 @@ class LlamaPretrainingCriterion(Layer):
         ign = self.ignore_index
 
         def fn(lg, lb):
-            import jax
             lg = lg.astype(jnp.float32)
             logp = lg - jax.nn.logsumexp(lg, axis=-1, keepdims=True)
             valid = lb != ign

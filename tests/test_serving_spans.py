@@ -105,6 +105,8 @@ def test_names_and_parents(traced):
         assert per_tick[n] == 1, n
     layers = [s.args["i"] for s in spans if s.name == "model/layer"]
     assert layers == list(range(LAYERS)) * len(ticks)
+    assert all(s.args["compiled"] == 1 for s in spans
+               if s.name == "model/layer")
     for s in spans:
         if s.name == "attn/qblock":
             assert s.args["jobs"] >= 1 and s.args["blocks"] >= 1
@@ -121,6 +123,8 @@ def test_tick_args_are_the_engines_counter_deltas(traced):
     assert sum(t.args["n_decode"] for t in ticks) == eng.ragged_decode_tokens
     assert sum(t.args["n_prefill"] for t in ticks) == \
         eng.ragged_prefill_tokens
+    assert [t.args["compiled_layers"] for t in ticks] == [LAYERS] * len(ticks)
+    assert eng.compiled_layer_calls == LAYERS * eng.ragged_steps
     for t in ticks:
         assert t.args["useful"] == t.args["n_decode"] + t.args["n_prefill"]
         assert sum(q for q, _ in t.args["spans"]) == t.args["useful"]

@@ -503,8 +503,9 @@ def check_serving_programs(verbose=True):
     (``PADDLE_TPU_RAGGED_IMPL=qblock``, the ISSUE-16 default decode
     path) keeps the identical bucket discipline: the q-block schedule
     re-tiles the flat token batch but the engine still pads the token
-    dimension to declared buckets. Returns a list of violation
-    strings."""
+    dimension to declared buckets. Last, the decoder layer's two
+    compiled programs hold exactly one executable a bucket met. Returns
+    a list of violation strings."""
     import threading
 
     import numpy as np
@@ -584,9 +585,27 @@ def check_serving_programs(verbose=True):
             f"{sorted(qb.declared_token_buckets())})")
     if not qb.ragged_steps:
         violations.append("q-block pass never reached the ragged scheduler")
+    # the decoder layer's two compiled programs (models/llama.py): one
+    # executable a token bucket met, shared by every layer, tick and
+    # engine of this geometry — a recompile a tick or a layer shows here
+    met = (eng.ragged_buckets_used | spec.ragged_buckets_used
+           | qb.ragged_buckets_used)
+    programs = model.llama._programs
+    pieces = programs.program_counts() if programs is not None else {}
+    for e, name in ((eng, "mixed"), (spec, "speculative"), (qb, "q-block")):
+        if e.compiled_layer_calls != e.ragged_steps:     # one layer
+            violations.append(
+                f"{name} pass ran {e.compiled_layer_calls} compiled layers "
+                f"in {e.ragged_steps} ticks of a one-layer model")
+    for piece, n in sorted(pieces.items()):
+        if n != len(met):
+            violations.append(
+                f"layer program `{piece}` holds {n} executables for "
+                f"{len(met)} token bucket(s) met {sorted(met)}")
     if verbose:
         for v in violations:
             print(f"FAIL {v}")
+        print(f"layer programs: {pieces} for buckets {sorted(met)}")
         print(f"serving programs: {len(eng.ragged_buckets_used)} bucket(s) "
               f"{sorted(eng.ragged_buckets_used)} within declared "
               f"{sorted(declared)}; prefill={eng.ragged_prefill_tokens} "
