@@ -1,0 +1,170 @@
+"""An expert layer that is told which experts it holds.
+
+Expert parallelism gives each chip a contiguous run of a layer's routed
+experts (``held = range(lo, lo + n)`` of ``num_experts``). The layer here
+is one chip's part of that: the router keeps its full width and picks its
+``top_k`` over ALL experts; the tokens routed to a held expert are sorted by
+expert and run through ONE grouped matrix product a projection
+(``jax.lax.ragged_dot``: on a TPU a native grouped matmul that visits only
+the row tiles a group really has); what an absent expert would add is left
+out. There is no capacity and no dropped token: the sorted buffer holds
+every (token, choice) pair. Nothing here stands in for the other chips or
+their exchange: a caller that runs every share adds the parts up
+(``tests/test_deepseek_v3.py`` does, against the uncut layer).
+
+The router is the sigmoid / group-limited one of the DeepSeek-V3 family
+(``topk_method = noaux_tc``): scores ``s = sigmoid(x W_r)`` in float32; the
+choice is made on ``s + b`` (``b`` a learned bias an expert that steers
+load and is NOT part of the weight): the experts stand in ``n_group``
+groups, a group's score is the sum of its two best, the best
+``topk_group`` groups are kept and the best ``top_k`` experts among them
+chosen; the weights are ``s_i / sum(s_chosen) * routed_scaling_factor``.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from .....autograd.tape import apply
+from .....framework.core import Tensor
+from .....nn.initializer import Normal
+from .....nn.layer import Layer
+
+__all__ = ["group_limited_topk", "sigmoid_group_route", "held_expert_sum",
+           "HeldExperts"]
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def group_limited_topk(choice_scores, n_group, topk_group, top_k):
+    """``choice_scores`` [S, E] float32 -> indices [S, top_k] of the best
+    ``top_k`` experts inside the best ``topk_group`` of ``n_group`` groups,
+    a group scored by the sum of its two best experts."""
+    s, e = choice_scores.shape
+    per = e // n_group
+    grouped = choice_scores.reshape(s, n_group, per)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, kept = jax.lax.top_k(group_score, topk_group)          # [S, kept]
+    keep = jnp.zeros((s, n_group), bool).at[
+        jnp.arange(s)[:, None], kept].set(True)
+    masked = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(s, e)
+    return jax.lax.top_k(masked, top_k)[1]
+
+
+def sigmoid_group_route(x, w_router, bias, *, n_group, topk_group, top_k,
+                        scale, norm_topk=True):
+    """``x`` [S, h] -> (chosen experts [S, k] int32, weights [S, k]
+    float32). The router's product and everything after it run in float32
+    at the highest matmul precision, whatever ``x``'s type: a bf16 router
+    flips choices."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), w_router.astype(jnp.float32), precision=_HI))
+    idx = group_limited_topk(scores + bias.astype(jnp.float32)[None],
+                             n_group, topk_group, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+def held_expert_sum(x, idx, weights, w_gate, w_up, w_down, lo, valid=None):
+    """The held experts' part of a routed SwiGLU layer.
+
+    ``x`` [S, h]; ``idx`` / ``weights`` [S, k] from the router (over ALL
+    experts); ``w_gate`` / ``w_up`` [n, h, m] and ``w_down`` [n, m, h] are
+    the held experts ``lo .. lo + n - 1``, stacked. Returns ``(out [S, h]
+    in ``x``'s type, tokens routed to each held expert [n] int32, tokens
+    none of whose choices is held [] int32)``; the two counts leave out
+    rows where ``valid`` [S] is False (a serving tick's bucket padding).
+    """
+    s, k = idx.shape
+    n = w_gate.shape[0]
+    local = idx - lo
+    held = (local >= 0) & (local < n)                         # [S, k]
+    key = jnp.where(held, local, n).reshape(-1)               # n: not here
+    order = jnp.argsort(key, stable=True)                     # [S*k]
+    sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+    rows = x[order // k]                                      # [S*k, h]
+    gate = jax.lax.ragged_dot(rows, w_gate, sizes)
+    up = jax.lax.ragged_dot(rows, w_up, sizes)
+    act = (jax.nn.silu(gate.astype(jnp.float32))
+           * up.astype(jnp.float32)).astype(x.dtype)
+    y = jax.lax.ragged_dot(act, w_down, sizes,
+                           preferred_element_type=jnp.float32)
+    # each pair's row of ``y`` by the inverse permutation: a gather, where
+    # the forward form would be a scatter-add; pairs of absent experts
+    # (sorted past the last group, whose rows no group computes) weigh 0
+    inv = jnp.zeros(s * k, jnp.int32).at[order].set(
+        jnp.arange(s * k, dtype=jnp.int32))
+    w = jnp.where(held, weights, 0.0).astype(jnp.float32)     # [S, k]
+    picked = jnp.where(held.reshape(-1)[:, None], y[inv], 0.0)
+    out = jnp.einsum("skh,sk->sh", picked.reshape(s, k, -1), w)
+    if valid is None:
+        valid = jnp.ones(s, bool)
+    counted = held & valid[:, None]
+    per_expert = jnp.bincount(jnp.where(counted, local, n).reshape(-1),
+                              length=n + 1)[:n].astype(jnp.int32)
+    unheld = jnp.sum(valid & ~jnp.any(held, axis=-1)).astype(jnp.int32)
+    return out.astype(x.dtype), per_expert, unheld
+
+
+class HeldExperts(Layer):
+    """Router over ``num_experts`` + the stacked SwiGLU weights of the
+    experts ``held = (lo, n)`` this chip holds (default: all of them).
+    ``forward(x [.., h], valid=None)`` returns ``(the held experts' sum,
+    {"moe_expert_tokens": [n], "moe_unheld_tokens": []})``."""
+
+    def __init__(self, hidden_size, expert_size, num_experts, top_k, *,
+                 n_group=1, topk_group=1, scale=1.0, norm_topk=True,
+                 held=None, initializer_range=0.02):
+        super().__init__()
+        lo, n = (0, num_experts) if held is None else map(int, held)
+        if not (0 <= lo and lo + n <= num_experts and n > 0):
+            raise ValueError(f"held experts {lo}..{lo + n - 1} are not "
+                             f"inside 0..{num_experts - 1}")
+        if num_experts % n_group:
+            raise ValueError(f"{num_experts} experts do not divide into "
+                             f"{n_group} groups")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.n_group, self.topk_group = n_group, topk_group
+        self.scale, self.norm_topk = float(scale), bool(norm_topk)
+        self.held = (lo, n)
+        init = Normal(0.0, initializer_range)
+        # the router stays float32 under a bf16 model (as the published
+        # checkpoints keep it): its scores decide which experts run
+        self.router = self.create_parameter(
+            [hidden_size, num_experts], dtype="float32",
+            default_initializer=init)
+        self.router_bias = self.create_parameter(
+            [num_experts], dtype="float32", is_bias=True)
+        self.w_gate = self.create_parameter(
+            [n, hidden_size, expert_size], default_initializer=init)
+        self.w_up = self.create_parameter(
+            [n, hidden_size, expert_size], default_initializer=init)
+        self.w_down = self.create_parameter(
+            [n, expert_size, hidden_size], default_initializer=init)
+
+    def forward(self, x, valid=None):
+        lo, _ = self.held
+        shape = x.shape
+        if isinstance(valid, Tensor):
+            valid = valid._data
+
+        def fn(xa, wr, br, wg, wu, wd):
+            tok = xa.reshape(-1, shape[-1])
+            with jax.named_scope("moe/route"):
+                idx, w = sigmoid_group_route(
+                    tok, wr, br, n_group=self.n_group,
+                    topk_group=self.topk_group, top_k=self.top_k,
+                    scale=self.scale, norm_topk=self.norm_topk)
+            with jax.named_scope("moe/experts"):
+                out, per_expert, unheld = held_expert_sum(
+                    tok, idx, w, wg, wu, wd, lo,
+                    None if valid is None else valid.reshape(-1))
+            return out.reshape(shape), per_expert, unheld
+
+        out, per_expert, unheld = apply(
+            fn, x, self.router, self.router_bias, self.w_gate, self.w_up,
+            self.w_down, op_name="held_experts")
+        return out, {"moe_expert_tokens": per_expert,
+                     "moe_unheld_tokens": unheld}

@@ -17,6 +17,7 @@ import time
 from collections import deque
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..framework.core import Tensor
@@ -829,6 +830,16 @@ class ContinuousServingEngine:
         # around the kernel entry (models/llama.py); 0 on a model that
         # runs its layers eagerly
         self.compiled_layer_calls = 0
+        # prompt tokens of admitted requests, and those of them that the
+        # prefix cache served (``cache.assign``): host counts, also on
+        # ``serve/tick`` as the tick's deltas
+        self.prompt_tokens_admitted = 0
+        self.prompt_tokens_cached = 0
+        # device counters a model left on the cache during a tick's
+        # forward (``cache.add_step_counters``; e.g. tokens routed to each
+        # held expert), summed over ticks and layers: name -> numpy array.
+        # They come back inside the tick's one sync
+        self.model_counters: dict = {}
         self.ragged_prefill_tokens = 0
         self.ragged_decode_tokens = 0
         # padded-vs-useful accounting for BOTH schedulers (the bench's
@@ -1272,6 +1283,8 @@ class ContinuousServingEngine:
             tele["prefix_hits"].inc(hits)
             tele["prefix_misses"].inc(misses)
             tele["prefix_cached"].inc(cached)
+            self.prompt_tokens_admitted += int(row.prompt.shape[0])
+            self.prompt_tokens_cached += int(cached)
             _rt.add_event(row.req.trace, "admit", slot=slot,
                           cached_tokens=int(cached), prefix_hits=int(hits),
                           prefix_misses=int(misses), engine=self._ENGINE)
@@ -1528,6 +1541,8 @@ class ContinuousServingEngine:
                 # serve/tick; ``phase`` is whichever of them is open
                 tracing = _spans.latch()
                 tick = _spans.span("serve/tick").begin()
+                admitted0 = (self.prompt_tokens_admitted,
+                             self.prompt_tokens_cached)
                 phase = _spans.span("serve/schedule").begin()
                 if not draining:
                     try:
@@ -1703,9 +1718,17 @@ class ContinuousServingEngine:
                     # the tick's one sync: the host waits for the device
                     phase = _spans.span("serve/sync").begin()
                     lg = logits._data[0].astype(jnp.float32)  # [padded, V]
-                    greedy = np.asarray(jnp.argmax(lg, axis=-1))
+                    # ... and what the model counted on the device during
+                    # the forward rides the same read-back
+                    greedy, found = jax.device_get(
+                        (jnp.argmax(lg, axis=-1),
+                         cache.take_step_counters()))
                     step_dt = time.perf_counter() - t_step
                     phase.end()
+                    found = {k: np.sum(v, axis=0) for k, v in found.items()}
+                    for k, v in found.items():
+                        self.model_counters[k] = \
+                            self.model_counters.get(k, 0) + v
                     phase = _spans.span("serve/emit").begin()
                     self.ragged_steps += 1
                     self.ragged_buckets_used.add(padded)
@@ -1838,8 +1861,14 @@ class ContinuousServingEngine:
                                  padded=padded, n_decode=n_decode,
                                  n_prefill=n_prefill,
                                  compiled_layers=compiled,
+                                 prompt_tokens=(self.prompt_tokens_admitted
+                                                - admitted0[0]),
+                                 cached_tokens=(self.prompt_tokens_cached
+                                                - admitted0[1]),
                                  spans=[[n, start + n]
-                                        for _, _, start, n, _ in spans])
+                                        for _, _, start, n, _ in spans],
+                                 **{k: v.tolist()
+                                    for k, v in found.items()})
                 except Exception as e:      # fail everything in flight
                     phase.end()
                     tick.end(error=type(e).__name__)

@@ -23,6 +23,8 @@ from .ppyoloe import (PPYOLOE, DetectionLoss, ppyoloe_lite, CSPBackbone,
                       FPNNeck, ETHead)
 from .mixtral import (MixtralConfig, MixtralModel, MixtralForCausalLM,
                       MixtralSparseMoeBlock, mixtral_8x7b, mixtral_tiny)
+from .deepseek_v3 import (DeepseekV3Config, DeepseekV3Model,
+                          DeepseekV3ForCausalLM, deepseek_v3_tiny)
 
 __all__ = [
     "LlamaConfig", "LlamaModel", "LlamaForCausalLM",
@@ -30,6 +32,8 @@ __all__ = [
     "build_llama_pipe", "llama3_8b", "llama_tiny",
     "MixtralConfig", "MixtralModel", "MixtralForCausalLM",
     "MixtralSparseMoeBlock", "mixtral_8x7b", "mixtral_tiny",
+    "DeepseekV3Config", "DeepseekV3Model", "DeepseekV3ForCausalLM",
+    "deepseek_v3_tiny",
     "T5Config", "T5ForConditionalGeneration", "t5_tiny",
     "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTForCausalLMPipe",
     "gpt3_1p3b", "gpt_tiny",

@@ -65,16 +65,40 @@ def _scatter_rows(pool, rows, page_ids, slot_ids):
     return out.reshape(pool.shape)
 
 
-def scatter_kv_rows(pools, kt, vt, page_ids, slot_ids):
+def _scatter_latent_rows(pool, rows, slot_ids, pages, row_page):
+    """Write a step's latent rows into a latent pool ``[1, num_pages, d,
+    page_size]`` (a page holds its tokens as COLUMNS, see
+    ``SlotPagedKVCache._pool``). ``rows`` [1, s, d]; ``pages`` [n]: the
+    distinct pages the step writes, padded with the scratch page 0;
+    ``row_page`` [s]: each row's index into ``pages``. The touched pages
+    are gathered whole (the page axis is the pool's major one), the rows
+    written into that small buffer, and the pages put back whole: a step
+    touches a few dozen pages, and nothing is scattered column by column
+    into the pool."""
+    got = jnp.swapaxes(pool[0, pages], 1, 2)            # [n, page, d]
+    got = got.at[row_page, slot_ids].set(rows[0].astype(pool.dtype))
+    return pool.at[0, pages].set(jnp.swapaxes(got, 1, 2))
+
+
+def scatter_kv_rows(pools, kt, vt=None, page_ids=None, slot_ids=None,
+                    touched=None):
     """Write one forward's K/V rows into a layer's page pools and return
     the updated pools: ``(k_pages, v_pages)``, or ``(k_pages, v_pages,
     k_scales, v_scales)`` for int8 pools, which quantize on scatter (each
-    ``[..., d]`` row gets its own fp32 scale, stored beside the pool). The
-    leading shape of ``kt``/``vt`` past the kv axis must match
+    ``[..., d]`` row gets its own fp32 scale, stored beside the pool), or
+    ``(kv_pages,)`` for a latent layer's single pool, whose one row a
+    token (``kt``; no ``vt``) holds keys and values alike (``touched``:
+    ``(pages, row_page)`` of :func:`_scatter_latent_rows`). The leading
+    shape of ``kt``/``vt`` past the kv axis must match
     ``page_ids``/``slot_ids``. Pure jnp: the eager cache calls it op by
     op, a compiled layer program traces it over donated pools."""
-    page_ids = jnp.asarray(page_ids, jnp.int32)
     slot_ids = jnp.asarray(slot_ids, jnp.int32)
+    if len(pools) == 1:
+        pages, row_page = touched
+        return (_scatter_latent_rows(pools[0], kt, slot_ids,
+                                     jnp.asarray(pages, jnp.int32),
+                                     jnp.asarray(row_page, jnp.int32)),)
+    page_ids = jnp.asarray(page_ids, jnp.int32)
     if len(pools) == 4:
         k_pages, v_pages, ks, vs = pools
         kt, ks_new = quantize_kv_rows(kt)
@@ -88,18 +112,19 @@ def scatter_kv_rows(pools, kt, vt, page_ids, slot_ids):
 
 
 def kv_page_nbytes(kv_heads, head_dim, page_size=16, kv_dtype="native",
-                   native_dtype="float32", num_layers=1):
+                   native_dtype="float32", num_layers=1, latent=False):
     """HBM bytes ONE page pins across K+V (plus int8 row scales) for
     ``num_layers`` attention layers — the int8-KV capacity math:
     ``sessions_per_pool = pool_bytes // (pages_per_seq * this)``. int8
     vs fp32 is ``4d/(d+4)`` (~3.8x at d=64), vs bf16 ``2d/(d+4)``
-    (~1.94x at d=128)."""
+    (~1.94x at d=128). ``latent``: one pool a layer (``head_dim`` is the
+    latent row, keys and values in one), so no factor of two."""
     elems = int(kv_heads) * int(page_size) * int(head_dim)
     if str(kv_dtype) == "int8":
         per = elems + int(kv_heads) * int(page_size) * 4   # + f32 scales
     else:
         per = elems * np.dtype(native_dtype).itemsize
-    return 2 * per * int(num_layers)                       # K and V
+    return (1 if latent else 2) * per * int(num_layers)    # K and V
 
 
 def block_hash_chain(tokens, page_size, parent=b""):
@@ -166,7 +191,7 @@ class HostKVPool:
 
     @staticmethod
     def entry_nbytes(entry):
-        total = sum(k.nbytes + v.nbytes for k, v in entry["layers"])
+        total = sum(a.nbytes for arrs in entry["layers"] for a in arrs)
         if entry.get("scales"):
             total += sum(ks.nbytes + vs.nbytes
                          for ks, vs in entry["scales"])
@@ -465,6 +490,7 @@ class SlotPagedKVCache:
         self.lens = np.zeros(self.max_batch, np.int32)   # filled ctx/slot
         self._mode = None            # ("prefill", slot) | ("decode", mask)
         self._idx = None             # per-forward index memo
+        self._touched = None         # ... and ragged_touched_pages' own
         self._prefill_valid = None   # real tokens in the current chunk
         # prefix-cache statistics (mirrored into the telemetry registry
         # by the serving engine)
@@ -486,6 +512,7 @@ class SlotPagedKVCache:
         # programs around the kernel entry (the model counts them here;
         # the serving engine mirrors the tick's delta)
         self.compiled_layer_calls = 0
+        self._step_counters = {}     # add_step_counters / take_step_counters
         # tiered KV: host-RAM second level under the prefix index.
         # ``host_pool=None`` builds a private pool from the env knob
         # (PADDLE_KV_HOST_POOL_MB=0 keeps the tier off); the serving
@@ -526,24 +553,28 @@ class SlotPagedKVCache:
         With the host tier enabled the page's bytes are demoted there
         before the device page frees — the prefix survives device churn
         and a later :meth:`assign` promotes it back."""
-        for digest in list(self._index):
-            page = self._index[digest]
-            if self._ref[page] == 1:
-                self._demote(digest, page)
-                del self._index[digest]
-                del self._page_digest[page]
-                self._ref[page] = 0
-                self._free.append(page)
-                self.prefix_evictions_device += 1
-                return True
-        return False
+        # the index is in LRU order: walk it from its old end, in place
+        # (a copy of it a freed page was the cost of a full pool)
+        victim = next(((d, p) for d, p in self._index.items()
+                       if self._ref[p] == 1), None)
+        if victim is None:
+            return False
+        digest, page = victim
+        self._demote(digest, page)
+        del self._index[digest]
+        del self._page_digest[page]
+        self._ref[page] = 0
+        self._free.append(page)
+        self.prefix_evictions_device += 1
+        return True
 
     def _page_entry(self, page):
         """Single-page host blob in the export_pages codec layout: one
-        ``[kv, page_size, d]`` K/V pair per layer (pool/forward order)
-        plus the int8 row scales — np copies, device-independent."""
-        layers = [(np.asarray(kp[:, page]), np.asarray(vp[:, page]))
-                  for kp, vp in self._pools.values()]
+        ``[kv, page_size, d]`` K/V pair per layer (pool/forward order; a
+        latent layer's single pool gives a 1-tuple) plus the int8 row
+        scales — np copies, device-independent."""
+        layers = [tuple(np.asarray(pool[:, page]) for pool in pools)
+                  for pools in self._pools.values()]
         scales = ([(np.asarray(ks[:, page]), np.asarray(vs[:, page]))
                    for ks, vs in self._scales.values()]
                   if self.kv_quant else None)
@@ -598,10 +629,9 @@ class SlotPagedKVCache:
         if self._pools:
             scales = entry["scales"]
             for li, key in enumerate(list(self._pools)):
-                kp, vp = self._pools[key]
-                kb, vb = entry["layers"][li]
-                self._pools[key] = (kp.at[:, page].set(kb),
-                                    vp.at[:, page].set(vb))
+                self._pools[key] = tuple(
+                    pool.at[:, page].set(blob) for pool, blob in
+                    zip(self._pools[key], entry["layers"][li]))
                 if self.kv_quant and scales is not None:
                     ks, vs = self._scales[key]
                     ksb, vsb = scales[li]
@@ -646,9 +676,9 @@ class SlotPagedKVCache:
         if self._ref[page] <= 1 and page not in self._page_digest:
             return
         new = self._alloc_page()
-        for key, (kp, vp) in self._pools.items():
-            self._pools[key] = (kp.at[:, new].set(kp[:, page]),
-                                vp.at[:, new].set(vp[:, page]))
+        for key, pools in self._pools.items():
+            self._pools[key] = tuple(pool.at[:, new].set(pool[:, page])
+                                     for pool in pools)
         for key, (ks, vs) in self._scales.items():
             self._scales[key] = (ks.at[:, new].set(ks[:, page]),
                                  vs.at[:, new].set(vs[:, page]))
@@ -670,8 +700,8 @@ class SlotPagedKVCache:
         pools (and int8 scale arrays) — 0 until the first forward
         materializes the pools."""
         total = 0
-        for kp, vp in self._pools.values():
-            total += kp.nbytes + vp.nbytes
+        for pools in self._pools.values():
+            total += sum(pool.nbytes for pool in pools)
         for ks, vs in self._scales.values():
             total += ks.nbytes + vs.nbytes
         return total // self.num_pages if total else 0
@@ -813,7 +843,7 @@ class SlotPagedKVCache:
                                  -(-(start + n_new) // self.page_size)):
                     self._make_writable(slot, blk)
             self._mode = ("ragged", spans)
-            self._idx = None
+            self._idx = self._touched = None
 
     def free(self, slot):
         slot = int(slot)
@@ -872,8 +902,9 @@ class SlotPagedKVCache:
         # layout; int8 pools ship their quantized ints AS-IS plus the
         # per-row scales — the handoff blob shrinks with the pages and
         # the receiver re-registers bit-exactly (no requantization step)
-        layers = [(np.stack([e["layers"][li][0] for e in entries], axis=1),
-                   np.stack([e["layers"][li][1] for e in entries], axis=1))
+        layers = [tuple(np.stack([e["layers"][li][a] for e in entries],
+                                 axis=1)
+                        for a in range(len(entries[0]["layers"][li])))
                   for li in range(n_layers)]
         scales = ([(np.stack([e["scales"][li][0] for e in entries], axis=1),
                     np.stack([e["scales"][li][1] for e in entries], axis=1))
@@ -930,7 +961,8 @@ class SlotPagedKVCache:
             if digest in self._index:
                 continue
             page = self._alloc_page()        # ref=1: the index's own ref
-            per_layer = [(k[:, j], v[:, j]) for k, v in blob["layers"]]
+            per_layer = [tuple(a[:, j] for a in arrs)
+                         for arrs in blob["layers"]]
             per_scales = ([(ks[:, j], vs[:, j]) for ks, vs in blob_scales]
                           if blob_scales is not None else None)
             if self._pools:
@@ -939,10 +971,9 @@ class SlotPagedKVCache:
                         f"layer count mismatch: exporter "
                         f"{len(per_layer)} vs importer {len(self._pools)}")
                 for li, key in enumerate(list(self._pools)):
-                    kp, vp = self._pools[key]
-                    kb, vb = per_layer[li]
-                    self._pools[key] = (kp.at[:, page].set(kb),
-                                        vp.at[:, page].set(vb))
+                    self._pools[key] = tuple(
+                        pool.at[:, page].set(blk) for pool, blk in
+                        zip(self._pools[key], per_layer[li]))
                     if per_scales is not None:
                         ks, vs = self._scales[key]
                         ksb, vsb = per_scales[li]
@@ -1158,14 +1189,29 @@ class SlotPagedKVCache:
         else:                   # "decode" mask or "sep_decode" slot
             self.lens[arg] += 1
 
-    def _pool(self, layer, kv_heads, d, dtype):
+    def _pool(self, layer, kv_heads, d, dtype, latent=False):
+        """The layer's pools, made on its first forward: ``(k_pages,
+        v_pages)`` of ``[kv_heads, pages, page_size, d]``, or for a
+        ``latent`` layer ONE pool ``(kv_pages,)`` of ``[1, pages, d,
+        page_size]``: ``d`` values a token, keys and values alike (the
+        kernel reads the values as a prefix of them), a page's tokens as
+        its columns. That order keeps the page size (a multiple of 128 on
+        the chip) as the minor axis: a 576-wide row as the minor axis is
+        no multiple of the TPU's 128 lanes, and the TPU then lays the pool
+        out in an order of its own choosing which the scatter and the
+        kernel each answer with a copy of the whole pool."""
         key = id(layer)
         if key not in self._pools:
             li = len(self._pools)       # this layer's forward-order index
-            shape = (kv_heads, self.num_pages, self.page_size, d)
+            shape = ((kv_heads, self.num_pages, d, self.page_size)
+                     if latent else
+                     (kv_heads, self.num_pages, self.page_size, d))
+            if latent and self.kv_quant:
+                raise NotImplementedError(
+                    "int8 pages for a latent pool are not built")
             pool_dtype = jnp.int8 if self.kv_quant else dtype
             kp = jnp.zeros(shape, pool_dtype)
-            vp = jnp.zeros(shape, pool_dtype)
+            vp = None if latent else jnp.zeros(shape, pool_dtype)
             if self.kv_quant:
                 # scale 1.0 everywhere: the scratch page (and any
                 # never-written slot) dequantizes to finite garbage that
@@ -1178,22 +1224,22 @@ class SlotPagedKVCache:
             # been evicted from the index are dead — skip them
             for page, per_layer, per_scales in self._import_backlog:
                 if li < len(per_layer) and page in self._page_digest:
-                    kb, vb = per_layer[li]
-                    kp = kp.at[:, page].set(kb)
-                    vp = vp.at[:, page].set(vb)
+                    kp = kp.at[:, page].set(per_layer[li][0])
+                    if not latent:
+                        vp = vp.at[:, page].set(per_layer[li][1])
                     if self.kv_quant and per_scales is not None:
                         ksb, vsb = per_scales[li]
                         ks = ks.at[:, page].set(ksb)
                         vs = vs.at[:, page].set(vsb)
-            self._pools[key] = (kp, vp)
+            self._pools[key] = (kp,) if latent else (kp, vp)
             if self.kv_quant:
                 self._scales[key] = (ks, vs)
         return self._pools[key]
 
     def layer_pools(self, layer, kv_spec):
         """This layer's pools as :func:`scatter_kv_rows` takes them.
-        ``kv_spec()`` -> ``(kv_heads, head_dim, dtype)`` is asked only on
-        the layer's first forward, which creates them."""
+        ``kv_spec()`` -> ``(kv_heads, head_dim, dtype[, latent])`` is asked
+        only on the layer's first forward, which creates them."""
         key = id(layer)
         if key not in self._pools:
             self._pool(layer, *kv_spec())
@@ -1202,7 +1248,7 @@ class SlotPagedKVCache:
 
     def set_layer_pools(self, layer, pools):
         key = id(layer)
-        self._pools[key] = tuple(pools[:2])
+        self._pools[key] = tuple(pools[:2])     # a latent layer's: one
         if self.kv_quant:
             self._scales[key] = tuple(pools[2:])
 
@@ -1260,20 +1306,80 @@ class SlotPagedKVCache:
         land in the pools; bucket padding lands in the scratch page."""
         return self._ragged_index(s)[:2]
 
-    def ragged_attention(self, layer, qa):
+    def ragged_touched_pages(self, s):
+        """``(pages [n], row_page [s])`` for a latent pool's scatter
+        (:func:`_scatter_latent_rows`): the distinct pages the armed step
+        writes, padded with the scratch page to a length that depends on
+        ``s`` alone (a span is contiguous, so a step writes at most a page
+        a span and ``s // page_size`` more), and each token's index into
+        them. Built once a forward, on the host."""
+        if self._touched is None:
+            page_ids = np.asarray(self._ragged_index(s)[0])
+            pages, row_page = np.unique(page_ids, return_inverse=True)
+            n = self.max_batch + s // self.page_size + 2
+            if len(pages) > n:
+                raise RuntimeError(f"a step of {s} tokens writes "
+                                   f"{len(pages)} pages, over {n}")
+            self._touched = (
+                jnp.asarray(np.pad(pages, (0, n - len(pages))), jnp.int32),
+                jnp.asarray(row_page.reshape(-1), jnp.int32))
+        return self._touched
+
+    def ragged_attention(self, layer, qa, sm_scale=None, value_dim=None):
         """The armed step's attention for ``qa`` [tokens, heads, d] over
         this layer's pools as they stand (the step's K/V already
-        scattered): the eager kernel entry, once a layer."""
+        scattered): the eager kernel entry, once a layer. A latent layer
+        (one pool) gives ``value_dim``: the values are that prefix of each
+        row, and the output is ``[tokens, heads, value_dim]``."""
         from ..ops.pallas.ragged_paged_attention import (
             ragged_paged_attention)
         tables, seq_slots, q_starts, q_lens, ctx_lens = \
             self._ragged_index(qa.shape[0])[2:]
-        k_pages, v_pages = self._pools[id(layer)]
+        pools = self._pools[id(layer)]
+        k_pages, v_pages = pools if len(pools) == 2 else (pools[0], None)
         ksc, vsc = self._layer_scales(layer)
         return ragged_paged_attention(
             qa, k_pages, v_pages, tables, seq_slots, q_starts, q_lens,
-            ctx_lens, k_scales=ksc, v_scales=vsc,
+            ctx_lens, sm_scale=sm_scale, value_dim=value_dim,
+            k_scales=ksc, v_scales=vsc,
             interpret=jax.default_backend() != "tpu")
+
+    def attend_latent(self, layer, q, row, sm_scale, value_dim):
+        """Eager attention of a latent layer: ``q`` [1, s, heads, d] (the
+        absorbed query) and ``row`` [1, s, 1, d] (this forward's cache
+        rows). Latent layers are served by the ragged scheduler alone:
+        scatter, then the ragged kernel over the layer's one pool."""
+        from ..autograd.tape import apply
+        if not self.ragged_armed:
+            raise NotImplementedError(
+                "a latent layer attends through the ragged step only "
+                f"(begin_ragged); the cache is armed for {self._mode}")
+        ra = row._data if isinstance(row, Tensor) else row
+        b, s, _, d = ra.shape
+        assert b == 1, "ragged step packs one flat token batch"
+        page_ids, slot_ids = self.ragged_scatter_ids(s)
+        pools = self.layer_pools(layer, lambda: (1, d, ra.dtype, True))
+        self.set_layer_pools(layer, scatter_kv_rows(
+            pools, jnp.moveaxis(ra[0], 1, 0), page_ids=page_ids,
+            slot_ids=slot_ids, touched=self.ragged_touched_pages(s)))
+
+        def fn(qa):
+            return self.ragged_attention(layer, qa[0], sm_scale,
+                                         value_dim)[None]
+        return apply(fn, q, op_name="ragged_paged_attention")
+
+    # -- device counters of a step, read with the tick's one sync ----------
+    def add_step_counters(self, found):
+        """A model leaves device values here during a forward (name ->
+        array; a name met again is stacked: one entry a layer)."""
+        for name, value in found.items():
+            self._step_counters.setdefault(name, []).append(value)
+
+    def take_step_counters(self):
+        """-> {name: [device arrays, one a layer that left one]}, emptied:
+        whoever syncs the step reads them in the same transfer."""
+        found, self._step_counters = self._step_counters, {}
+        return found
 
     # -- attention ----------------------------------------------------------
     def attend(self, layer, q, k, v, training=False, dropout_p=0.0):

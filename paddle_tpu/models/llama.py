@@ -12,6 +12,7 @@ implements by hand in ``fleet/layers/mpu/mp_layers.py`` (SURVEY.md §2.3).
 from __future__ import annotations
 
 import copy
+import inspect
 import math
 import threading
 
@@ -251,21 +252,27 @@ class RaggedLayerPrograms:
     """A decoder layer of a ragged serving tick as two compiled programs
     around the unchanged eager kernel entry:
 
-    1. pre-attention: ``pre_attention`` (norm, q/k/v, reshapes, rope at
-       the tick's positions) and the K/V scatter into the layer's page
-       pools, which are donated, so the scatter updates them in place;
+    1. pre-attention: ``pre_attention`` (norm, projections, reshapes, rope
+       at the tick's positions) -> ``(q, *rows)`` and the scatter of the
+       rows into the layer's page pools, which are donated, so the scatter
+       updates them in place (``rows`` is ``k, v`` for a K pool and a V
+       pool, one latent row for a latent layer's single pool);
     2. ``cache.ragged_attention``: ``ragged_paged_attention`` eagerly, its
        descriptors host values;
-    3. post-attention: ``post_attention`` (o_proj, residual, norm, MLP,
-       residual).
+    3. post-attention: ``post_attention`` (o_proj, residual, norm, MLP or
+       experts, residual). A layer whose ``post_attention`` takes ``valid``
+       returns ``(hidden, counters)``: device values the tick reads with
+       its one sync (``cache.add_step_counters``).
 
     Weights, rope tables, positions, page and slot ids and pools are all
-    ARGUMENTS: the programs are traced once over a twin of ``layers[0]`` and keyed
-    by shapes and dtypes alone, so every layer, every tick and every cache
-    of one geometry share one executable a token bucket, weights swapped
-    after construction are followed, and nothing here keeps an array
-    alive. That sharing is sound only while a layer's state is its
-    parameters and buffers: ``usable()`` says whether it is."""
+    ARGUMENTS: the programs are traced once a KIND of layer (``layer.kind``
+    where a model has several, e.g. dense and expert layers) over a twin of
+    the kind's first layer and keyed by shapes and dtypes alone, so every
+    layer of a kind, every tick and every cache of one geometry share one
+    executable a token bucket, weights swapped after construction are
+    followed, and nothing here keeps an array alive. That sharing is sound
+    only while a layer's state is its parameters and buffers: ``usable()``
+    says whether it is."""
 
     def __init__(self, layers):
         self._layers = list(layers)
@@ -275,72 +282,104 @@ class RaggedLayerPrograms:
         # a trace swaps the traced layer's arrays for tracers and the
         # global generator for the trace's key (``FunctionalModule``), and
         # thread-tier replicas share one model: the programs are traced
-        # over a private twin of layer 0 that holds no data, one trace at
-        # a time, so no thread ever reads a tracer out of a served layer
-        twin = _structural_twin(self._layers[0])
+        # over a private twin that holds no data, one trace at a time, so
+        # no thread ever reads a tracer out of a served layer
         self._tracing = threading.Lock()
-        pre = FunctionalModule(twin, method=twin.pre_attention,
-                               training=False)
-        post = FunctionalModule(twin, method=twin.post_attention,
-                                training=False)
+        self._kinds = {}             # kind -> (qkv, pre, post)
+        for layer in self._layers:
+            kind = self.kind_of(layer)
+            if kind not in self._kinds:
+                self._kinds[kind] = self._build(_structural_twin(layer))
+        self._kv_dtype = {}          # (kind, hidden dtype) -> rows' dtype
+
+    @staticmethod
+    def kind_of(layer):
+        return getattr(layer, "kind", "layer")
+
+    def _build(self, twin):
+        pre_mod = FunctionalModule(twin, method=twin.pre_attention,
+                                   training=False)
+        post_mod = FunctionalModule(twin, method=twin.post_attention,
+                                    training=False)
+        counts = "valid" in inspect.signature(twin.post_attention).parameters
 
         def qkv(p, b, cos, sin, hidden, pos):
             with self._tracing:       # this body runs only under a trace
                 # no op of the pieces draws from the key (inference)
-                (q, k, v), _ = pre(p, b, jax.random.key(0), hidden, pos,
-                                   rope=(cos, sin))
-            return q, k, v
+                out, _ = pre_mod(p, b, jax.random.key(0), hidden, pos,
+                                 rope=(cos, sin))
+            return out
 
-        def pre_fn(p, b, cos, sin, hidden, pos, page_ids, slot_ids, pools):
-            q, k, v = qkv(p, b, cos, sin, hidden, pos)
-            kt = jnp.moveaxis(k[0], 1, 0)           # [kv, s, d]
-            vt = jnp.moveaxis(v[0], 1, 0)
-            return q[0], scatter_kv_rows(pools, kt, vt, page_ids, slot_ids)
+        def pre_fn(p, b, cos, sin, hidden, pos, page_ids, slot_ids, pools,
+                   touched):
+            q, *rows = qkv(p, b, cos, sin, hidden, pos)
+            rows = [jnp.moveaxis(r[0], 1, 0) for r in rows]   # [kv, s, d]
+            return q[0], scatter_kv_rows(pools, *rows, page_ids=page_ids,
+                                         slot_ids=slot_ids, touched=touched)
 
-        def post_fn(p, b, hidden, attn_out):
+        def post_fn(p, b, hidden, attn_out, page_ids):
             with self._tracing:
-                return post(p, b, jax.random.key(0), hidden,
-                            attn_out[None])[0]
+                if not counts:
+                    return post_mod(p, b, jax.random.key(0), hidden,
+                                    attn_out[None])[0], {}
+                # bucket padding scatters to the scratch page 0
+                (out, found), _ = post_mod(
+                    p, b, jax.random.key(0), hidden, attn_out[None],
+                    valid=page_ids > 0)
+            return out, found
 
-        self._qkv = qkv
-        self.pre = jax.jit(pre_fn, donate_argnums=(8,))
-        self.post = jax.jit(post_fn)
-        self._kv_dtype = {}          # hidden dtype -> k's dtype after rope
+        return qkv, jax.jit(pre_fn, donate_argnums=(8,)), jax.jit(post_fn)
 
     def usable(self):
         """False where some layer carries state the programs would bake
-        in as layer 0's constants: int8 weight streams
+        in as the traced layer's constants: int8 weight streams
         (``quantization.quantize_linears``) live outside the parameters."""
         return not any(getattr(sub, "_w_int8", None) is not None
                        for l in self._layers
                        for sub in l.sublayers(include_self=True))
 
     def program_counts(self):
-        """Executables held, by piece: one a token bucket met so far."""
-        return {"pre": self.pre._cache_size(),
-                "post": self.post._cache_size()}
+        """Executables held, by piece: one a token bucket and kind met so
+        far."""
+        return {"pre": sum(k[1]._cache_size()
+                           for k in self._kinds.values()),
+                "post": sum(k[2]._cache_size()
+                            for k in self._kinds.values())}
 
     def run(self, layer, hidden, pos, cache):
         """One layer over ``hidden`` [1, tokens, hidden] (raw array)."""
         params, buffers = self._state[id(layer)]
         p = [t._data for t in params]
         b = [t._data for t in buffers]
+        kind = self.kind_of(layer)
+        qkv, pre, post = self._kinds[kind]
         attn = layer.self_attn
         cos, sin = attn._cos, attn._sin
 
         def kv_spec():
-            if hidden.dtype not in self._kv_dtype:
-                k = jax.eval_shape(self._qkv, p, b, cos, sin, hidden, pos)[1]
-                self._kv_dtype[hidden.dtype] = k.dtype
-            return (attn.num_kv_heads, attn.head_dim,
-                    self._kv_dtype[hidden.dtype])
+            key = (kind, hidden.dtype)
+            if key not in self._kv_dtype:
+                row = jax.eval_shape(qkv, p, b, cos, sin, hidden, pos)[1]
+                self._kv_dtype[key] = row.dtype
+            spec = getattr(attn, "kv_pool_spec", None)
+            if spec is not None:
+                return spec(self._kv_dtype[key])
+            return attn.num_kv_heads, attn.head_dim, self._kv_dtype[key]
 
         page_ids, slot_ids = cache.ragged_scatter_ids(hidden.shape[1])
-        q, pools = self.pre(p, b, cos, sin, hidden, pos, page_ids, slot_ids,
-                            cache.layer_pools(layer, kv_spec))
+        pools = cache.layer_pools(layer, kv_spec)
+        # a latent layer's one pool is written a touched page at a time
+        touched = (cache.ragged_touched_pages(hidden.shape[1])
+                   if len(pools) == 1 else ())
+        q, pools = pre(p, b, cos, sin, hidden, pos, page_ids, slot_ids,
+                       pools, touched)
         cache.set_layer_pools(layer, pools)
         cache.compiled_layer_calls += 1
-        return self.post(p, b, hidden, cache.ragged_attention(layer, q))
+        out, found = post(p, b, hidden, cache.ragged_attention(
+            layer, q, **getattr(attn, "ragged_kwargs", {})), page_ids)
+        if found:
+            cache.add_step_counters(found)
+        return out
 
 
 class LlamaModel(Layer):

@@ -81,6 +81,11 @@ from .paged_attention import NEG_INF, _device_call, _scale_rows
 #: exp(-1e30 - m_real) = 0.0, correction exp(0) = 1.0).
 BIG_NEG = -1e30
 
+#: the latent kernel's products take their operands as they are stored: a
+#: global ``jax_default_matmul_precision`` of "highest" (the test suite's)
+#: would ask Mosaic for a float32 product of bf16 operands, which it refuses
+_DEFAULT = jax.lax.Precision.DEFAULT
+
 #: default q-block rows (tokens per grid step); PADDLE_TPU_RAGGED_QBLOCK
 DEFAULT_QBLOCK = 8
 
@@ -307,18 +312,36 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
                                           q_starts, q_lens, context_lens,
                                           *, sm_scale, interpret,
                                           k_scales=None, v_scales=None,
-                                          q_block=None):
+                                          q_block=None, value_dim=None):
     """Q-block tier: grid ``(q_blocks, kv_heads, jobs)`` over the flat
     packed batch — one grid step covers ``q_block`` tokens against one
     KV page, so a mixed prefill+decode tick runs far fewer (and fatter)
     MXU steps than the per-token grid. Requires concrete descriptors
-    (the job schedule is built host-side)."""
+    (the job schedule is built host-side).
+
+    ``v_pages is None`` is the LATENT call: one pool of one KV head whose
+    row a token holds keys and values alike (``value_dim``: the values are
+    that prefix of the row). The host half (this entry, its spans, now
+    with ``latent=1``) is shared; the schedule is a flat job list
+    (:func:`latent_job_list`) and the device half a wrapper of its own name
+    (:func:`_latent_qblock_device`), so a device trace tells the two
+    kernels apart."""
     import numpy as np
 
     tokens, heads, d = q.shape
+    qb = q_block or _qblock_rows()
+    if v_pages is None:
+        page_size = k_pages.shape[3]            # [1, pages, d, page_size]
+        with _spans.span("attn/qblock", latent=1) as sp:
+            with _spans.span("attn/qblock_schedule", latent=1):
+                row_slot, row_ctx, jobs = latent_job_list(
+                    tokens, seq_slots, q_starts, q_lens, context_lens,
+                    block_tables, qb, page_size)
+            sp.set(jobs=jobs.shape[1], blocks=row_slot.shape[0] // qb)
+            return _latent_call(jobs, row_slot, row_ctx, q, k_pages,
+                                sm_scale, interpret, value_dim, qb)
     kv_heads, _, page_size, _ = k_pages.shape
     group = heads // kv_heads
-    qb = q_block or _qblock_rows()
     with _spans.span("attn/qblock") as sp:
         with _spans.span("attn/qblock_schedule"):
             row_slot, row_ctx, job_page, job_slot, job_kv = qblock_schedule(
@@ -408,6 +431,210 @@ def _qblock_device(job_page, job_slot, job_kv, rs, rc, q, k_pages, v_pages,
     out = out.reshape(nblocks, kv_heads, qb, group, d).transpose(
         0, 2, 1, 3, 4).reshape(t_pad, heads, d)
     return out[:tokens]
+
+
+#: smallest flat job list of the latent kernel; lists are padded to a
+#: power of two from here, so the family of compiled programs stays small
+LATENT_MIN_JOBS = 64
+
+
+def latent_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
+                    block_tables, q_block, page_size):
+    """Host-side schedule of the latent q-block kernel, vectorized: the
+    same jobs as :func:`qblock_schedule` (one a KV page a sequence of a
+    q-block needs, a sequence's pages ascending) as ONE flat list in block
+    order, so the grid walks the jobs that exist and not ``blocks x the
+    longest block's jobs``: with 64 query heads on one KV head a block of 8
+    decode rows at 8 k of context has 500 jobs and a block of prefill rows
+    64. Padding rows (outside every span) get slot -1 / ctx 0 and own no
+    job; a block without jobs gets one that matches nothing (slot -2), so
+    that its output is written; the list is padded to a power of two with
+    such jobs on the last block.
+
+    Returns ``(row_slot [B*q_block], row_ctx [B*q_block], jobs [4, J])``
+    int32 numpy; ``jobs`` rows are (q-block, physical page, owner slot,
+    kv offset)."""
+    import numpy as np
+
+    ss = np.asarray(seq_slots, np.int64).reshape(-1)
+    qs = np.asarray(q_starts, np.int64).reshape(-1)
+    ql = np.asarray(q_lens, np.int64).reshape(-1)
+    cl = np.asarray(context_lens, np.int64).reshape(-1)
+    tbl = np.asarray(block_tables, np.int32)
+    pages_per_seq = tbl.shape[1]
+    qb = max(int(q_block), 1)
+    nblocks = -(-int(num_tokens) // qb)
+    t_pad = nblocks * qb
+
+    row_slot = np.full(t_pad, -1, np.int32)
+    row_ctx = np.zeros(t_pad, np.int32)
+    live = ql > 0
+    ss, qs, ql, cl = ss[live], qs[live], ql[live], cl[live]
+    span_of = np.repeat(np.arange(len(qs)), ql)
+    off = np.arange(len(span_of)) - np.repeat(np.cumsum(ql) - ql, ql)
+    tok = qs[span_of] + off
+    row_slot[tok] = ss[span_of]
+    row_ctx[tok] = (cl - ql)[span_of] + off + 1
+
+    # one (block, sequence) pair a q-block a span overlaps; the pages it
+    # needs reach the context bound of the span's last row in the block
+    b0, b1 = qs // qb, (qs + ql - 1) // qb
+    nb = b1 - b0 + 1
+    pair_span = np.repeat(np.arange(len(qs)), nb)
+    pair_block = b0[pair_span] + (np.arange(len(pair_span))
+                                  - np.repeat(np.cumsum(nb) - nb, nb))
+    last_row = np.minimum(qs[pair_span] + ql[pair_span],
+                          (pair_block + 1) * qb) - 1
+    cmax = (cl - ql)[pair_span] + (last_row - qs[pair_span]) + 1
+    n_pages = np.clip(-(-cmax // page_size), 1, pages_per_seq)
+    pair_slot = ss[pair_span]
+    # a slot met twice in one block (two spans of one sequence) is walked
+    # once, as far as its longest bound
+    key = pair_block * (int(tbl.shape[0]) + 1) + pair_slot
+    uniq, inv = np.unique(key, return_inverse=True)
+    if len(uniq) != len(key):
+        pages = np.zeros(len(uniq), np.int64)
+        np.maximum.at(pages, inv, n_pages)
+        first = np.zeros(len(uniq), np.int64)
+        first[inv[::-1]] = np.arange(len(key))[::-1]
+        pair_block, pair_slot, n_pages = (pair_block[first],
+                                          pair_slot[first], pages)
+    empty = np.setdiff1d(np.arange(nblocks), pair_block)
+    pair_block = np.concatenate([pair_block, empty])
+    pair_slot = np.concatenate([pair_slot, np.full(len(empty), -2)])
+    n_pages = np.concatenate([n_pages, np.ones(len(empty), np.int64)])
+    order = np.argsort(pair_block, kind="stable")
+    pair_block, pair_slot, n_pages = (pair_block[order], pair_slot[order],
+                                      n_pages[order])
+
+    total = int(n_pages.sum())
+    num_jobs = max(1 << (total - 1).bit_length(), LATENT_MIN_JOBS)
+    jobs = np.zeros((4, num_jobs), np.int32)
+    jobs[0, total:] = nblocks - 1
+    jobs[2, total:] = -2
+    page_idx = np.arange(total) - np.repeat(np.cumsum(n_pages) - n_pages,
+                                            n_pages)
+    slot = np.repeat(pair_slot, n_pages)
+    jobs[0, :total] = np.repeat(pair_block, n_pages)
+    jobs[1, :total] = np.where(slot >= 0,
+                               tbl[np.maximum(slot, 0), page_idx], 0)
+    jobs[2, :total] = slot
+    jobs[3, :total] = page_idx * page_size
+    return row_slot, row_ctx, jobs
+
+
+def _latent_kernel(jobs_ref, rs_ref, rc_ref, q_ref, kv_ref, o_ref, m_ref,
+                   l_ref, acc_ref, *, sm_scale, num_jobs, value_dim):
+    """One grid step: a q-block's rows (``q_block`` tokens x all heads)
+    against one page of latent rows (``[d, page_size]``: a token a column,
+    as the pool keeps them), read once for keys and values. The
+    products take the pool's type as their operands' (bf16 x bf16 products
+    are exact in the float32 accumulator) and the softmax runs in
+    float32; the weights enter the second product in the pool's type."""
+    j = pl.program_id(0)
+    blk = jobs_ref[0, j]
+    first = (j == 0) | (jobs_ref[0, jnp.maximum(j - 1, 0)] != blk)
+    last = (j == num_jobs - 1) | (
+        jobs_ref[0, jnp.minimum(j + 1, num_jobs - 1)] != blk)
+
+    @pl.when(first)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    jslot = jobs_ref[2, j]
+
+    @pl.when(jslot >= 0)                            # -2: matches no row
+    def _step():
+        q = q_ref[0]                                # [rows, d]
+        kv = kv_ref[0, 0]                           # [d, page_size]
+        s = jax.lax.dot_general(
+            q, kv, (((1,), (0,)), ((), ())), precision=_DEFAULT,
+            preferred_element_type=jnp.float32) * sm_scale
+        s = _qblock_masked_scores(s, jobs_ref[3, j], jslot,
+                                  rs_ref[0][:, :1], rc_ref[0][:, :1])
+        m_prev = m_ref[...][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        w = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_ref[...][:, :1] * corr + jnp.sum(w, -1, keepdims=True)
+        pv = jax.lax.dot_general(
+            w.astype(kv.dtype), kv[:value_dim],
+            (((1,), (1,)), ((), ())), precision=_DEFAULT,
+            preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(last)
+    def _finalize():
+        l = jnp.maximum(l_ref[...][:, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _latent_qblock_device(jobs, row_slot, row_ctx, q, kv_pages, sm_scale,
+                          interpret, value_dim, q_block):
+    """Device half of the latent q-block tier: ``jobs`` [4, J] is scalar-
+    prefetched, the rows' slot and context bound arrive one a TOKEN and are
+    spread over the heads here, on the device (64 heads a token would make
+    them 16 MB a layer on the host's side of the link)."""
+    tokens, heads, d = q.shape
+    _, _, _, page_size = kv_pages.shape
+    num_jobs = jobs.shape[1]
+    t_pad = row_slot.shape[0]
+    nblocks, rows = t_pad // q_block, q_block * heads
+
+    qg = jnp.pad(q, ((0, t_pad - tokens), (0, 0), (0, 0))).reshape(
+        nblocks, rows, d)
+
+    def spread(a):
+        a = jnp.repeat(jnp.asarray(a, jnp.int32).reshape(nblocks, q_block),
+                       heads, axis=1)
+        return jnp.broadcast_to(a[:, :, None], (nblocks, rows, 128))
+
+    kernel = functools.partial(_latent_kernel, sm_scale=sm_scale,
+                               num_jobs=num_jobs, value_dim=value_dim)
+    row_spec = pl.BlockSpec((1, rows, 128), lambda j, jobs: (jobs[0, j], 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(num_jobs,),
+        in_specs=[
+            row_spec, row_spec,
+            pl.BlockSpec((1, rows, d), lambda j, jobs: (jobs[0, j], 0, 0)),
+            pl.BlockSpec((1, 1, d, page_size),
+                         lambda j, jobs: (0, jobs[1, j], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, rows, value_dim),
+                               lambda j, jobs: (jobs[0, j], 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, value_dim), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((nblocks, rows, value_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.asarray(jobs), spread(row_slot), spread(row_ctx), qg, kv_pages)
+    return out.reshape(t_pad, heads, value_dim)[:tokens]
+
+
+#: the jitted device half: ``_latent_qblock_device`` in a device trace
+_latent_qblock_jit = jax.jit(_latent_qblock_device,
+                             static_argnums=(5, 6, 7, 8))
+
+
+def _latent_call(jobs, row_slot, row_ctx, q, kv_pages, sm_scale, interpret,
+                 value_dim, q_block):
+    """Interpret mode eager (the CPU tests' numerics), else jitted."""
+    fn = _latent_qblock_device if interpret else _latent_qblock_jit
+    return fn(jobs, row_slot, row_ctx, q, kv_pages, sm_scale, interpret,
+              value_dim, q_block)
 
 
 def _ragged_kernel(slots_ref, ctx_ref, tables_ref, q_ref, k_ref, v_ref,
@@ -642,8 +869,15 @@ def _ragged_paged_attention_xla(q, k_pages, v_pages, block_tables,
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_slots,
                            q_starts, q_lens, context_lens, *,
                            sm_scale=None, k_scales=None, v_scales=None,
-                           interpret=False):
+                           value_dim=None, interpret=False):
     """Mixed prefill+decode attention over a shared paged KV cache.
+
+    A LATENT pool is ``k_pages`` [1, num_pages, d, page_size] (a page's
+    tokens are its columns) with ``v_pages=None``: every query head attends
+    the one row a token, whose first ``value_dim`` values are also the
+    values; the result is
+    ``[tokens, heads, value_dim]``. It runs on the q-block tier only
+    (concrete descriptors).
 
     q               [tokens, heads, head_dim] — the flat packed batch
     k_pages/v_pages [kv_heads, num_pages, page_size, head_dim]
@@ -662,8 +896,19 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_slots,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     impl = _ragged_impl()
-    if _qblock_eligible(impl, seq_slots, q_starts, q_lens, context_lens,
-                        block_tables):
+    eligible = _qblock_eligible(impl, seq_slots, q_starts, q_lens,
+                                context_lens, block_tables)
+    if v_pages is None:
+        if not eligible or value_dim is None or k_scales is not None:
+            raise NotImplementedError(
+                "a latent pool is read by the q-block kernel alone: "
+                "concrete descriptors, a value_dim, native pages "
+                f"(PADDLE_TPU_RAGGED_IMPL={impl!r})")
+        return _ragged_paged_attention_pallas_qblock(
+            q, k_pages, None, block_tables, seq_slots, q_starts, q_lens,
+            context_lens, sm_scale=sm_scale, interpret=interpret,
+            value_dim=int(value_dim))
+    if eligible:
         return _ragged_paged_attention_pallas_qblock(
             q, k_pages, v_pages, block_tables, seq_slots, q_starts, q_lens,
             context_lens, sm_scale=sm_scale, interpret=interpret,

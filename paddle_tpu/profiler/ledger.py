@@ -183,8 +183,8 @@ def blob_digest(blob: dict) -> str:
     h.update(str(blob.get("native_dtype")).encode())
     for d in blob.get("digests", ()):
         h.update(bytes(d))
-    for k, v in blob.get("layers", ()):
-        for part in (k, v):
+    for parts in blob.get("layers", ()):    # (k, v), or a latent (kv,)
+        for part in parts:
             a = np.ascontiguousarray(np.asarray(part))
             h.update(str(a.dtype).encode())
             h.update(repr(tuple(a.shape)).encode())

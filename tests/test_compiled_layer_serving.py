@@ -440,3 +440,38 @@ def test_a_traced_layer_takes_the_eager_pieces(model, monkeypatch):
     jaxpr = jax.make_jaxpr(traced)(whole.param_arrays())
     assert cache.compiled_layer_calls == 0
     assert not {"pre_fn", "post_fn"} & set(pjit_names(jaxpr.jaxpr))
+
+
+def test_two_kinds_of_layer_in_one_model_get_programs_by_kind(monkeypatch):
+    """A model whose layer 0 is dense and whose others are expert layers
+    (``models/deepseek_v3.py``): one pair of programs a KIND and token
+    bucket, every layer of every tick compiled, the eager pieces' tokens and
+    logits."""
+    from paddle_tpu.models import deepseek_v3 as ds
+    paddle.seed(0)
+    model = ds.DeepseekV3ForCausalLM(ds.deepseek_v3_tiny())
+    assert [l.kind for l in model.model.layers] == ["dense", "moe", "moe"]
+    waves = [prompts((5, 11, 23))]
+    toks, logits, eng, _ = serve(model, waves)
+    assert eng.compiled_layer_calls == 3 * eng.ragged_steps > 0
+    programs = model.model._programs
+    assert sorted(programs._kinds) == ["dense", "moe"]
+    buckets = len(eng.ragged_buckets_used)
+    assert programs.program_counts() == {"pre": 2 * buckets,
+                                         "post": 2 * buckets}
+    # the expert layers' device counters came back with the ticks' syncs
+    assert eng.model_counters["moe_expert_tokens"].sum() == \
+        2 * 4 * eng.useful_tokens_total
+    monkeypatch.setattr(ds.DeepseekV3Model, "_ragged_programs",
+                        lambda self, *a: None)
+    toks_e, logits_e, eng_e, _ = serve(model, waves)
+    assert eng_e.compiled_layer_calls == 0
+    for a, b in zip(toks, toks_e):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(logits, logits_e):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+def test_llama_layers_are_one_kind(model):
+    serve(model, [prompts((5,))])
+    assert list(model.llama._programs._kinds) == ["layer"]

@@ -190,6 +190,45 @@ def test_pool_eviction_reclaims_index_pages():
     assert cached == hits * 4
 
 
+def test_eviction_frees_the_oldest_entry_no_slot_maps_and_no_other():
+    """``_evict_lru`` walks the index from its old end, in place: a page a
+    live slot still maps is passed over (and keeps its entry), a prefix hit
+    makes its entries the newest, and one call frees exactly one page."""
+    layer = object()
+    cache = SlotPagedKVCache(2, page_size=4, max_len=16, num_pages=12,
+                             enable_prefix_cache=True)
+    chains = []
+    for i in range(3):                               # a, b, c: 2 blocks each
+        prompt = np.arange(8) + 1000 * i
+        chains.append(block_hash_chain(prompt, 4))
+        cache.assign(0, prompt)
+        _write_tokens(cache, 0, layer, prompt)
+        cache.commit_prefix(0)
+        cache.free(0)
+    a, b, c = chains
+    assert list(cache._index) == a + b + c           # oldest first
+    # a again, and live: its first block is a hit (the last token of a
+    # prompt is always computed, so its second block is not)
+    cached, hits, _ = cache.assign(1, np.arange(8))
+    assert (cached, hits) == (4, 1)
+    held = [d for d in a if cache._ref[cache._index[d]] > 1]
+    assert held == a[:1]                             # slot 1 maps it
+    # the hit made that entry the newest
+    assert list(cache._index) == a[1:] + b + c + a[:1]
+    before = dict(cache._index)
+    order = []
+    while cache._evict_lru():
+        gone = [d for d in before if d not in cache._index]
+        order += [d for d in gone if d not in order]
+        assert len(order) == cache.prefix_evictions_device   # one a call
+    # from the old end, the mapped entry passed over and kept
+    assert order == a[1:] + b + c
+    assert list(cache._index) == held
+    assert all(cache._ref[cache._index[d]] == 2 for d in held)
+    cache.free(1)
+    assert (cache._ref >= 0).all()
+
+
 def test_refcount_underflow_raises():
     cache = SlotPagedKVCache(1, page_size=4, max_len=16)
     page = cache._alloc_page()

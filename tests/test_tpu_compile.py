@@ -254,6 +254,111 @@ def test_flash_device_event_names_are_the_ones_their_roofline_matches(
     assert len(calls) == 3, calls
 
 
+# -- the latent (MLA) serving path at GigaChat3.1-702B-A36B widths ----------
+# (benchmark/configs/gigachat3.1-702b-serve-ep16-5l.json: 64 heads on one
+# 576-wide latent row a token, 128-token pages, a 512-token tick)
+LAT_HEADS, LAT_DIM, LAT_VALUE = 64, 576, 512
+LAT_PAGE, LAT_PAGES, LAT_LEN, LAT_TOKENS = 128, 5121, 17408, 512
+
+
+def _latent_tick(rpa):
+    """15 decode rows at 8 k and one 497-token chunk at 9 k of context."""
+    rng = np.random.default_rng(0)
+    tables = rng.integers(1, LAT_PAGES, (16, LAT_LEN // LAT_PAGE)).astype(
+        np.int32)
+    slots = np.arange(16, dtype=np.int32)
+    q_lens = np.array([1] * 15 + [LAT_TOKENS - 15], np.int32)
+    ctx = np.array([8000 + 100 * i for i in range(15)] + [9000], np.int32)
+    return rpa.latent_job_list(LAT_TOKENS, slots, slots, q_lens, ctx, tables,
+                               8, LAT_PAGE)
+
+
+#: the latent pool: a page's tokens are its columns (generation.py ``_pool``)
+LAT_POOL = ((1, LAT_PAGES, LAT_DIM, LAT_PAGE), jnp.bfloat16)
+
+
+def _pool_sized_copies(text):
+    """Instructions that copy or re-lay-out a whole latent pool."""
+    shape = r"bf16\[1,%d,%d,%d\]" % LAT_POOL[0][1:]
+    return [ln.strip()[:120] for ln in text.splitlines()
+            if re.search(r"= " + shape + r"\S* (copy|transpose)\(", ln)]
+
+
+def test_latent_qblock_compiles_in_place_and_under_its_rooflines_name(
+        compile_on_chip):
+    """``latent_attn_roofline`` finds the kernel by the name the jitted
+    wrapper ``_latent_qblock_device`` gives its Mosaic call, and
+    ``qblock_roofline`` must not count it. The program holds no copy of the
+    pool: with a 576-wide row as the pool's minor axis the TPU lays the
+    pool out in an order of its own and the call holds a 755 MB copy."""
+    rpa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    row_slot, row_ctx, jobs = _latent_tick(rpa)
+
+    def fn(jobs, row_slot, row_ctx, q, pool):
+        return rpa._latent_qblock_jit(jobs, row_slot, row_ctx, q, pool,
+                                      LAT_DIM ** -0.5, False, LAT_VALUE, 8)
+
+    text = compile_on_chip(
+        fn, (jobs.shape, jnp.int32), (row_slot.shape, jnp.int32),
+        (row_ctx.shape, jnp.int32),
+        ((LAT_TOKENS, LAT_HEADS, LAT_DIM), jnp.bfloat16), LAT_POOL)
+    calls = _custom_calls(text)
+    mine = re.compile(_roofline_patterns("latent_attn_roofline").KERNEL)
+    llamas = re.compile(_roofline_patterns("qblock_roofline").KERNEL)
+    assert calls and all(mine.search(c) for c in calls), calls
+    assert not any(llamas.search(c) for c in calls), calls
+    assert not _pool_sized_copies(text)
+
+
+def test_latent_scatter_updates_the_pool_in_place(one_chip):
+    """A step's rows go into the latent pool a touched page at a time:
+    no copy of the pool, temporaries of a few dozen pages."""
+    from paddle_tpu.models.generation import scatter_kv_rows
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    touched = 16 + LAT_TOKENS // LAT_PAGE + 2
+
+    def scatter(pools, rows, slot_ids, pages, row_page):
+        return scatter_kv_rows(pools, rows, slot_ids=slot_ids,
+                               touched=(pages, row_page))
+
+    compiled = jax.jit(scatter, donate_argnums=(0,)).lower(
+        (spec(*LAT_POOL),), spec((1, LAT_TOKENS, LAT_DIM), jnp.bfloat16),
+        spec((LAT_TOKENS,), jnp.int32), spec((touched,), jnp.int32),
+        spec((LAT_TOKENS,), jnp.int32)).compile()
+    assert not _pool_sized_copies(compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_expert_sum_device_events_are_the_ones_moe_device_pct_matches(
+        compile_on_chip):
+    """The grouped products of the expert sum (``jax.lax.ragged_dot``, three
+    a layer) lower to Mosaic calls named ``ragged-dot...`` whatever program
+    they are fused into: ``moe_device_pct`` tells them by that name."""
+    from paddle_tpu.incubate.distributed.models.moe.held import (
+        held_expert_sum, sigmoid_group_route)
+
+    def fn(x, wr, br, wg, wu, wd):
+        with jax.named_scope("moe/route"):
+            idx, w = sigmoid_group_route(x, wr, br, n_group=8, topk_group=4,
+                                         top_k=8, scale=2.5)
+        with jax.named_scope("moe/experts"):
+            return held_expert_sum(x, idx, w, wg, wu, wd, 0)
+
+    h, m = 7168, 2048
+    # the suite pins "highest" for its CPU parities; the chip runs the
+    # products at the backend's default, and Mosaic refuses the other
+    with jax.default_matmul_precision("default"):
+        text = compile_on_chip(
+            fn, ((LAT_TOKENS, h), jnp.bfloat16), ((h, 256), jnp.float32),
+            ((256,), jnp.float32), ((16, h, m), jnp.bfloat16),
+            ((16, h, m), jnp.bfloat16), ((16, m, h), jnp.bfloat16))
+    kernel = re.compile(_roofline_patterns("moe_device_pct").KERNEL)
+    products = [c for c in _custom_calls(text)
+                if kernel.search(c) and "metadata" not in c.split(" = ")[0]]
+    assert len(products) == 3, _custom_calls(text)
+
+
 def test_int8_matmul_compiles(compile_on_chip):
     """Weight-only int8 GEMM at the 8B MLP up-projection: a 256-token
     tick against [hidden 4096, intermediate 14336]."""
