@@ -1273,12 +1273,13 @@ def bench_serving():
     def qblock_step_probe():
         """Q-block vs per-token ragged grid at a representative mixed
         prefill+decode tick: the per-token kernel runs one grid step per
-        (token, kv_head, page); the q-block kernel runs one per
-        (q_block, kv_head, job). The step ratio is the device-tier
+        (token, kv_head, page); the q-block kernel runs one per job of
+        its flat (q_block, page) list, every KV head in it. The step
+        ratio is the device-tier
         speed lever (fewer, fatter MXU launches for the same math) and
         is exact from the schedules — no timing noise."""
         from paddle_tpu.ops.pallas.ragged_paged_attention import (
-            qblock_schedule, _qblock_rows)
+            qblock_job_list, _qblock_rows)
         page, pps = 16, 8
         # 3 decode slots mid-stream + a chunked-prefill tail + a fresh
         # prefill: 64 packed tokens, the run_mixed regime
@@ -1289,10 +1290,10 @@ def bench_serving():
         tbl = np.zeros((8, pps), np.int32)
         tokens = 64
         kv_heads = cfg.num_key_value_heads
-        _, _, job_page, _, _ = qblock_schedule(
+        _, _, jobs = qblock_job_list(
             tokens, seq_slots, q_starts, q_lens, ctx, tbl,
             _qblock_rows(), page)
-        q_steps = job_page.shape[0] * kv_heads * job_page.shape[1]
+        q_steps = jobs.shape[1]
         t_steps = tokens * kv_heads * pps
         return {"qblock_grid_steps": int(q_steps),
                 "token_grid_steps": int(t_steps),

@@ -869,6 +869,31 @@ class ContinuousServingEngine:
         out.add(self.token_budget)
         return out
 
+    def declared_kernel_buckets(self, latent=False):
+        """The q-block attention kernel's compiled-shape family: one
+        program a (token bucket, job bucket). A tick's flat job list, one
+        job a (q-block, KV page) pair
+        (``ragged_paged_attention.qblock_job_list``), reaches the device
+        in an array of a bucketed length (``job_bucket``: 1,024, 8,192,
+        ...), and how long the list is follows the contexts in flight,
+        not the tick's token count. Returns ``{token bucket: [job
+        buckets]}``: every bucket a tick within this engine's limits can
+        reach (``max_batch_size`` sequences of at most ``max_len`` tokens,
+        pages shared or not), which :meth:`warmup_programs` compiles.
+        ``latent``: the ladder of a latent (one-pool) layer's kernel,
+        whose lists are padded to powers of two from 64. Empty where the
+        per-token grid runs
+        (``PADDLE_TPU_RAGGED_IMPL=token`` / ``xla``): one program a token
+        bucket, which the warm-up forward meets."""
+        from ..ops.pallas.ragged_paged_attention import (
+            _qblock_eligible, _qblock_rows, _ragged_impl, job_buckets)
+        if not self.enable_ragged or not _qblock_eligible(_ragged_impl()):
+            return {}
+        pages_per_seq = -(-self.max_len // self.page_size)
+        return {b: job_buckets(b, _qblock_rows(), self.max_batch,
+                               pages_per_seq, latent=latent)
+                for b in sorted(self.declared_token_buckets())}
+
     def declared_chunk_buckets(self):
         """The legacy prefill path's compiled-shape family: every chunk
         pads to one of these widths (:func:`_chunk_bucket`, pow2 min 8
@@ -918,6 +943,12 @@ class ContinuousServingEngine:
 
     def _ragged_signature(self, padded):
         sig = {"tokens": _co.tensor_arg((int(padded),), "int64")}
+        sig.update(self._static_args())
+        return sig
+
+    def _kernel_signature(self, padded, jobs):
+        sig = {"tokens": _co.tensor_arg((int(padded),), "int64"),
+               "jobs": _co.tensor_arg((int(jobs),), "int32")}
         sig.update(self._static_args())
         return sig
 
@@ -985,6 +1016,18 @@ class ContinuousServingEngine:
                 "serving.ragged",
                 buckets={"tokens": sorted(self.declared_token_buckets())},
                 warmup=lambda: warm(("serving.ragged",)))
+            kernel = self.declared_kernel_buckets()
+            if kernel:
+                # both ladders: which kind of layer the model has shows
+                # only once a forward has built its pools
+                ladders = (kernel, self.declared_kernel_buckets(latent=True))
+                _co.declare_family(
+                    "serving.ragged_attention",
+                    buckets={"tokens": sorted(kernel),
+                             "jobs": sorted({j for fam in ladders
+                                             for js in fam.values()
+                                             for j in js})},
+                    warmup=lambda: warm(("serving.ragged_attention",)))
         else:
             _co.declare_family(
                 "serving.prefill_chunk",
@@ -1044,20 +1087,35 @@ class ContinuousServingEngine:
                     max_len=self.max_len, num_pages=self.num_pages,
                     enable_prefix_cache=False, kv_dtype=self.kv_dtype,
                     allow_page_overcommit=self.sep_prefill_enabled)
-                if self.enable_ragged and want("serving.ragged"):
+                kernel = bool(want("serving.ragged_attention")
+                              and self.declared_kernel_buckets())
+                if self.enable_ragged and (want("serving.ragged") or kernel):
                     t0 = time.perf_counter()
+                    t_kernel = 0.0
                     for b in sorted(self.declared_token_buckets()):
                         flat = np.full(b, self.pad_token_id, np.int64)
                         pos = np.zeros(b, np.int32)
                         cache.begin_ragged([(0, 0, 1)])
+                        cache.attention_calls = []
                         t_run = time.perf_counter()
                         self.model.forward(Tensor(flat[None]), cache=cache,
                                            position_ids=pos)
-                        _co.observe("serving.ragged",
-                                    self._ragged_signature(b),
-                                    seconds=time.perf_counter() - t_run)
+                        if want("serving.ragged"):
+                            _co.observe("serving.ragged",
+                                        self._ragged_signature(b),
+                                        seconds=time.perf_counter() - t_run)
+                        calls, cache.attention_calls = \
+                            cache.attention_calls, None
+                        t_run = time.perf_counter()
+                        if kernel:
+                            self._warm_attention(cache, b, calls)
+                        t_kernel += time.perf_counter() - t_run
                         cache.free(0)
-                    out["serving.ragged"] = time.perf_counter() - t0
+                    if want("serving.ragged"):
+                        out["serving.ragged"] = \
+                            time.perf_counter() - t0 - t_kernel
+                    if kernel:
+                        out["serving.ragged_attention"] = t_kernel
                 if not self.enable_ragged and want("serving.prefill_chunk"):
                     t0 = time.perf_counter()
                     for b in sorted(self.declared_chunk_buckets()):
@@ -1196,6 +1254,38 @@ class ContinuousServingEngine:
             if was_training:
                 self.model.train()
         return out
+
+    def _warm_attention(self, cache, tokens, calls):
+        """Compile the attention kernel of a ``tokens``-token tick for
+        every job bucket declared for it: the warm-up forward that has just
+        run on ``cache`` met the smallest one only. ``calls`` are that
+        forward's kernel calls (``cache.attention_calls``); one layer of
+        each distinct shape is called again, through the cache and the
+        public op, with descriptors that make a list of each length (a
+        layer of one pool is a latent one, with that kernel's ladder)."""
+        from ..ops.pallas.ragged_paged_attention import (
+            _qblock_rows, warm_descriptors)
+        pages_per_seq = -(-self.max_len // self.page_size)
+        seen = set()
+        for layer, shape, dtype, sm_scale, value_dim in calls:
+            pools = cache._pools[id(layer)]
+            key = (shape, str(dtype), sm_scale, value_dim,
+                   tuple((p.shape, str(p.dtype)) for p in pools))
+            if key in seen:
+                continue
+            seen.add(key)
+            q = jnp.zeros(shape, dtype)
+            ladder = self.declared_kernel_buckets(latent=len(pools) == 1)
+            for jobs in ladder[tokens]:
+                t_run = time.perf_counter()
+                cache.ragged_attention(
+                    layer, q, sm_scale, value_dim,
+                    descriptors=warm_descriptors(
+                        tokens, jobs, _qblock_rows(), self.page_size,
+                        pages_per_seq)).block_until_ready()
+                _co.observe("serving.ragged_attention",
+                            self._kernel_signature(tokens, jobs),
+                            seconds=time.perf_counter() - t_run)
 
     def generate(self, input_ids, max_new_tokens=32, max_length=None,
                  timeout=None, trace=None, **kwargs):

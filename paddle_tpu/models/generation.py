@@ -513,6 +513,9 @@ class SlotPagedKVCache:
         # the serving engine mirrors the tick's delta)
         self.compiled_layer_calls = 0
         self._step_counters = {}     # add_step_counters / take_step_counters
+        # a list while a warm-up wants to know which kernel calls a forward
+        # makes (``ragged_attention`` notes each one's arguments there)
+        self.attention_calls = None
         # tiered KV: host-RAM second level under the prefix index.
         # ``host_pool=None`` builds a private pool from the env knob
         # (PADDLE_KV_HOST_POOL_MB=0 keeps the tier off); the serving
@@ -1325,16 +1328,23 @@ class SlotPagedKVCache:
                 jnp.asarray(row_page.reshape(-1), jnp.int32))
         return self._touched
 
-    def ragged_attention(self, layer, qa, sm_scale=None, value_dim=None):
+    def ragged_attention(self, layer, qa, sm_scale=None, value_dim=None,
+                         descriptors=None):
         """The armed step's attention for ``qa`` [tokens, heads, d] over
         this layer's pools as they stand (the step's K/V already
         scattered): the eager kernel entry, once a layer. A latent layer
         (one pool) gives ``value_dim``: the values are that prefix of each
-        row, and the output is ``[tokens, heads, value_dim]``."""
+        row, and the output is ``[tokens, heads, value_dim]``.
+        ``descriptors`` (block tables, then slot, q_start, q_len and
+        context length a span) stand in for the armed step's: a warm-up
+        reaches the kernel's other job buckets with them."""
         from ..ops.pallas.ragged_paged_attention import (
             ragged_paged_attention)
-        tables, seq_slots, q_starts, q_lens, ctx_lens = \
-            self._ragged_index(qa.shape[0])[2:]
+        if self.attention_calls is not None:
+            self.attention_calls.append(
+                (layer, qa.shape, qa.dtype, sm_scale, value_dim))
+        tables, seq_slots, q_starts, q_lens, ctx_lens = (
+            descriptors or self._ragged_index(qa.shape[0])[2:])
         pools = self._pools[id(layer)]
         k_pages, v_pages = pools if len(pools) == 2 else (pools[0], None)
         ksc, vsc = self._layer_scales(layer)

@@ -34,16 +34,22 @@ Tiers (``PADDLE_TPU_RAGGED_IMPL``), mirroring
 * ``xla``: a plain-XLA gather+softmax, no kernel at all — only ever
   reached through this explicit switch.
 
-Two in-repo grids. The default **q-block** grid ``(q_blocks, kv_head,
-jobs)`` tiles the flat batch into fixed ``PADDLE_TPU_RAGGED_QBLOCK``-row
-blocks over the cumulative span offsets and walks a host-built job list
-(one (page, owner-slot, kv-offset) per KV page any sequence in the
-block needs) — one grid step covers a whole block of tokens against one
-page, so a mixed tick runs far fewer, fatter MXU steps. A block may
+Two in-repo grids. The default **q-block** grid ``(jobs,)`` tiles the
+flat batch into fixed ``PADDLE_TPU_RAGGED_QBLOCK``-row blocks over the
+cumulative span offsets and walks ONE flat host-built job list in block
+order (:func:`qblock_job_list`: one (q-block, page, owner-slot, kv-offset)
+per KV page any sequence in the block needs; the grid's bound is the
+list's own length, read on the device) —
+one grid step covers a whole block of tokens, every KV head of it,
+against one page, so a mixed tick runs far fewer, fatter MXU steps, and
+the grid holds the jobs that exist: a block's softmax state starts at
+its first job and its output is written at its last, and a job without
+an owner (a block of padding rows has one) skips the body. A block may
 straddle span boundaries: rows past a span's causal bound mask with
 -inf exactly like the per-token kernel, and cross-span keys are steered
 out with a finite ``BIG_NEG`` so alien jobs are bitwise no-ops (see
-``BIG_NEG``). The historical **per-token** grid ``(tokens, kv_head,
+``BIG_NEG``). The latent (one pool, one KV head) kernel walks the same
+list. The historical **per-token** grid ``(tokens, kv_head,
 pages)`` remains as the escape hatch (``PADDLE_TPU_RAGGED_IMPL=token``)
 and is used automatically under jit tracing, where the q-block
 schedule's host-side job build cannot run. The two grids run the SAME
@@ -64,6 +70,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -125,337 +132,92 @@ def _token_descriptors(num_tokens, seq_slots, q_starts, q_lens,
     return tok_slot, tok_ctx
 
 
-def qblock_schedule(num_tokens, seq_slots, q_starts, q_lens, context_lens,
-                    block_tables, q_block, page_size):
-    """Host-side (numpy, concrete-value) schedule for the q-block grid.
-
-    Tiles the flat packed batch into fixed ``q_block``-row blocks over
-    the cumulative span offsets and enumerates, per block, the "jobs"
-    its grid steps execute: one (physical page, owner slot, kv offset)
-    triple per KV page any sequence appearing in the block still needs.
-    Pages of one slot are listed ascending, slots in first-appearance
-    order, so each row sees its own pages in exactly the per-token
-    kernel's order. The job count is padded to a power of two so the
-    compiled-program family stays bounded (grid = (blocks, kv_heads, J)
-    with J from a small bucket set, vs (tokens, kv_heads, pages)).
-
-    Sentinels: rows past ``num_tokens`` (block padding) get slot -1 /
-    ctx 0; padding jobs get slot -2 / page 0. They can never match each
-    other, so every row's score matrix keeps at least one finite entry
-    (BIG_NEG) and the online softmax never sees an all--inf row.
-
-    Returns ``(row_slot [B*q_block], row_ctx [B*q_block],
-    job_page [B, J], job_slot [B, J], job_kv [B, J])`` int32 numpy.
-    """
-    import numpy as np
-
-    ss = np.asarray(seq_slots, np.int32).reshape(-1)
-    qs = np.asarray(q_starts, np.int32).reshape(-1)
-    ql = np.asarray(q_lens, np.int32).reshape(-1)
-    cl = np.asarray(context_lens, np.int32).reshape(-1)
-    tbl = np.asarray(block_tables, np.int32)
-    pages_per_seq = tbl.shape[1]
-    T = int(num_tokens)
-    q_block = max(int(q_block), 1)
-
-    tok = np.arange(T, dtype=np.int32)
-    nseq = qs.shape[0]
-    seq_of = np.clip(
-        np.searchsorted(qs, tok, side="right").astype(np.int32) - 1,
-        0, max(nseq - 1, 0))
-    off = tok - qs[seq_of]
-    valid = (off >= 0) & (off < ql[seq_of])
-    ts = np.where(valid, ss[seq_of], 0).astype(np.int32)
-    tc = np.where(valid, cl[seq_of] - ql[seq_of] + off + 1, 1).astype(
-        np.int32)
-
-    nblocks = -(-T // q_block)
-    t_pad = nblocks * q_block
-    row_slot = np.full(t_pad, -1, np.int32)
-    row_ctx = np.zeros(t_pad, np.int32)
-    row_slot[:T] = ts
-    row_ctx[:T] = tc
-    bs = row_slot.reshape(nblocks, q_block)
-    bc = row_ctx.reshape(nblocks, q_block)
-
-    jobs = []
-    max_jobs = 1
-    for b in range(nblocks):
-        block_jobs = []
-        seen = []
-        for r in range(q_block):
-            slot = int(bs[b, r])
-            if slot < 0 or slot in seen:
-                continue
-            seen.append(slot)
-            cmax = int(bc[b][bs[b] == slot].max())
-            n_pages = min(max(-(-cmax // page_size), 1), pages_per_seq)
-            for p in range(n_pages):
-                block_jobs.append((int(tbl[slot, p]), slot, p * page_size))
-        if not block_jobs:
-            block_jobs.append((0, -2, 0))
-        jobs.append(block_jobs)
-        max_jobs = max(max_jobs, len(block_jobs))
-
-    num_jobs = 1 << (max_jobs - 1).bit_length()
-    job_page = np.zeros((nblocks, num_jobs), np.int32)
-    job_slot = np.full((nblocks, num_jobs), -2, np.int32)
-    job_kv = np.zeros((nblocks, num_jobs), np.int32)
-    for b, block_jobs in enumerate(jobs):
-        for j, (page, slot, kv) in enumerate(block_jobs):
-            job_page[b, j] = page
-            job_slot[b, j] = slot
-            job_kv[b, j] = kv
-    return row_slot, row_ctx, job_page, job_slot, job_kv
-
-
-def _qblock_masked_scores(s, kv_start, jslot, row_slot, row_ctx):
-    """Causal bound with NEG_INF (bitwise the per-token kernel's mask on
-    a row's own pages), then the whole row to finite BIG_NEG wherever
-    the row's sequence does not own this job's page."""
-    pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < row_ctx, s, NEG_INF)
-    return jnp.where(row_slot == jslot, s, BIG_NEG)
-
-
-def _qblock_kernel(jp_ref, js_ref, jk_ref, rs_ref, rc_ref, q_ref, k_ref,
-                   v_ref, o_ref, m_ref, l_ref, acc_ref, *, sm_scale,
-                   num_jobs):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    jslot = js_ref[b, j]
-    jkv = jk_ref[b, j]
-    row_slot = rs_ref[0][:, :1]                    # [Qg, 1]
-    row_ctx = rc_ref[0][:, :1]
-    q = q_ref[0, 0].astype(jnp.float32)            # [Qg, d]
-    k = k_ref[0, 0].astype(jnp.float32)            # [page_size, d]
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    s = _qblock_masked_scores(s, jkv, jslot, row_slot, row_ctx)
-
-    m_prev = m_ref[...][:, :1]                     # [Qg, 1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    w = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_ref[...][:, :1] * corr + jnp.sum(w, -1, keepdims=True)
-    pv = jax.lax.dot_general(                      # [Qg, d]
-        w, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(j == num_jobs - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...][:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _qblock_kernel_quant(jp_ref, js_ref, jk_ref, rs_ref, rc_ref, q_ref,
-                         k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref,
-                         l_ref, acc_ref, *, sm_scale, num_jobs):
-    """int8-KV q-block variant: same job walk; the per-row fp32 scales
-    arrive as a ``[1, page_size]`` lane vector and scale the scores /
-    weights around the int8 dots (see ``_decode_kernel_quant``)."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    jslot = js_ref[b, j]
-    jkv = jk_ref[b, j]
-    row_slot = rs_ref[0][:, :1]
-    row_ctx = rc_ref[0][:, :1]
-    q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * (ks_ref[0, 0] * sm_scale)
-    s = _qblock_masked_scores(s, jkv, jslot, row_slot, row_ctx)
-
-    m_prev = m_ref[...][:, :1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    w = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_ref[...][:, :1] * corr + jnp.sum(w, -1, keepdims=True)
-    pv = jax.lax.dot_general(
-        w * vs_ref[0, 0], v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(j == num_jobs - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...][:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
-                                          block_tables, seq_slots,
-                                          q_starts, q_lens, context_lens,
-                                          *, sm_scale, interpret,
-                                          k_scales=None, v_scales=None,
-                                          q_block=None, value_dim=None):
-    """Q-block tier: grid ``(q_blocks, kv_heads, jobs)`` over the flat
-    packed batch — one grid step covers ``q_block`` tokens against one
-    KV page, so a mixed prefill+decode tick runs far fewer (and fatter)
-    MXU steps than the per-token grid. Requires concrete descriptors
-    (the job schedule is built host-side).
-
-    ``v_pages is None`` is the LATENT call: one pool of one KV head whose
-    row a token holds keys and values alike (``value_dim``: the values are
-    that prefix of the row). The host half (this entry, its spans, now
-    with ``latent=1``) is shared; the schedule is a flat job list
-    (:func:`latent_job_list`) and the device half a wrapper of its own name
-    (:func:`_latent_qblock_device`), so a device trace tells the two
-    kernels apart."""
-    import numpy as np
-
-    tokens, heads, d = q.shape
-    qb = q_block or _qblock_rows()
-    if v_pages is None:
-        page_size = k_pages.shape[3]            # [1, pages, d, page_size]
-        with _spans.span("attn/qblock", latent=1) as sp:
-            with _spans.span("attn/qblock_schedule", latent=1):
-                row_slot, row_ctx, jobs = latent_job_list(
-                    tokens, seq_slots, q_starts, q_lens, context_lens,
-                    block_tables, qb, page_size)
-            sp.set(jobs=jobs.shape[1], blocks=row_slot.shape[0] // qb)
-            return _latent_call(jobs, row_slot, row_ctx, q, k_pages,
-                                sm_scale, interpret, value_dim, qb)
-    kv_heads, _, page_size, _ = k_pages.shape
-    group = heads // kv_heads
-    with _spans.span("attn/qblock") as sp:
-        with _spans.span("attn/qblock_schedule"):
-            row_slot, row_ctx, job_page, job_slot, job_kv = qblock_schedule(
-                tokens, seq_slots, q_starts, q_lens, context_lens,
-                block_tables, qb, page_size)
-            nblocks = job_page.shape[0]
-            # per-ROW metadata rides as [B, Qg, 128] VMEM lanes so the
-            # kernel can slice [:, :1] — the same layout trick the softmax
-            # scratch uses (broadcast host-side: one transfer, no extra
-            # eager device ops)
-            rows = np.repeat(row_slot.reshape(nblocks, qb), group, axis=1)
-            rowc = np.repeat(row_ctx.reshape(nblocks, qb), group, axis=1)
-            rs = np.broadcast_to(rows[:, :, None],
-                                 (nblocks, qb * group, 128))
-            rc = np.broadcast_to(rowc[:, :, None],
-                                 (nblocks, qb * group, 128))
-        sp.set(jobs=job_page.shape[1], blocks=nblocks)
-        return _qblock_device(job_page, job_slot, job_kv, rs, rc, q,
-                              k_pages, v_pages, k_scales, v_scales,
-                              sm_scale=sm_scale, interpret=interpret)
-
-
-@_device_call
-def _qblock_device(job_page, job_slot, job_kv, rs, rc, q, k_pages, v_pages,
-                   k_scales, v_scales, *, sm_scale, interpret):
-    """Device half of the q-block tier: the schedule arrives as arrays
-    (``job_*`` [B, J] scalar-prefetched, ``rs``/``rc`` [B, Qg, 128] row
-    slot and context bound), so one compiled program serves every tick of
-    a (tokens, blocks, jobs) shape."""
-    tokens, heads, d = q.shape
-    kv_heads, _, page_size, _ = k_pages.shape
-    group = heads // kv_heads
-    nblocks, num_jobs = job_page.shape
-    qg_rows = rs.shape[1]
-    qb = qg_rows // group
-    t_pad = nblocks * qb
-
-    qp = jnp.pad(q, ((0, t_pad - tokens), (0, 0), (0, 0)))
-    qg = qp.reshape(nblocks, qb, kv_heads, group, d).transpose(
-        0, 2, 1, 3, 4).reshape(nblocks, kv_heads, qg_rows, d)
-    rs, rc = jnp.asarray(rs), jnp.asarray(rc)
-
-    quant = k_scales is not None
-    kernel = functools.partial(
-        _qblock_kernel_quant if quant else _qblock_kernel,
-        sm_scale=sm_scale, num_jobs=num_jobs)
-    page_spec = pl.BlockSpec((1, 1, page_size, d),
-                             lambda b, h, j, jp, js, jk:
-                             (h, jp[b, j], 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, 1, page_size),
-                              lambda b, h, j, jp, js, jk:
-                              (h, jp[b, j], 0, 0))
-    row_spec = pl.BlockSpec((1, qg_rows, 128),
-                            lambda b, h, j, jp, js, jk: (b, 0, 0))
-    in_specs = [
-        row_spec, row_spec,
-        pl.BlockSpec((1, 1, qg_rows, d),
-                     lambda b, h, j, jp, js, jk: (b, h, 0, 0)),
-        page_spec, page_spec,
-    ]
-    operands = [rs, rc, qg, k_pages, v_pages]
-    if quant:
-        in_specs += [scale_spec, scale_spec]
-        operands += [_scale_rows(k_scales), _scale_rows(v_scales)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nblocks, kv_heads, num_jobs),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, qg_rows, d),
-                               lambda b, h, j, jp, js, jk: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((qg_rows, 128), jnp.float32),
-            pltpu.VMEM((qg_rows, 128), jnp.float32),
-            pltpu.VMEM((qg_rows, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nblocks, kv_heads, qg_rows, d),
-                                       q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(jnp.asarray(job_page), jnp.asarray(job_slot), jnp.asarray(job_kv),
-      *operands)
-    out = out.reshape(nblocks, kv_heads, qb, group, d).transpose(
-        0, 2, 1, 3, 4).reshape(t_pad, heads, d)
-    return out[:tokens]
-
-
-#: smallest flat job list of the latent kernel; lists are padded to a
-#: power of two from here, so the family of compiled programs stays small
+#: the q-block kernel's job list reaches the device in an array of one of
+#: these lengths (MIN_JOBS x JOBS_STEP^k, MAX_JOBS at the most): the grid's
+#: length is the list's own (a dynamic grid bound), so the array's padding
+#: is never walked and the buckets can be coarse, which keeps the family of
+#: compiled programs small (one a token bucket and job bucket:
+#: :func:`job_buckets`). The latent kernel's grid is the padded list itself
+#: (a static bound): powers of two from LATENT_MIN_JOBS. MAX_JOBS is what
+#: the chip's scalar memory holds of a list (16 bytes a job of 1 MiB, the
+#: rest left to the compiler)
+MIN_JOBS = 1024
+JOBS_STEP = 8
 LATENT_MIN_JOBS = 64
+MAX_JOBS = 32768
 
 
-def latent_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
+def job_bucket(total, latent=False):
+    """The length of the array that carries a list of ``total`` jobs."""
+    if total > MAX_JOBS:
+        raise ValueError(
+            f"a ragged attention call of {total} (q-block, KV page) jobs is "
+            f"over the {MAX_JOBS} the q-block kernel's job list can hold: "
+            "lower the token budget or max_len, raise page_size, or run "
+            "the per-token grid (PADDLE_TPU_RAGGED_IMPL=token)")
+    b, step = (LATENT_MIN_JOBS, 2) if latent else (MIN_JOBS, JOBS_STEP)
+    while b < total:
+        b *= step
+    return min(b, MAX_JOBS)
+
+
+def job_buckets(num_tokens, q_block, max_seqs, pages_per_seq, latent=False):
+    """Every :func:`job_bucket` a call over ``num_tokens`` tokens can land
+    in, whatever its descriptors: a sequence's span is contiguous, so a
+    call of ``b`` q-blocks and at most ``max_seqs`` sequences has at most
+    ``b + max_seqs`` (block, sequence) pairs (and at most one a token), each
+    of at most ``pages_per_seq`` jobs, and at least one job a block. The
+    ladder stops at ``MAX_JOBS``: a call beyond it is refused."""
+    blocks = -(-int(num_tokens) // q_block)
+    most = min(int(num_tokens), blocks + int(max_seqs)) * int(pages_per_seq)
+    top = job_bucket(min(most, MAX_JOBS), latent)
+    out = [job_bucket(min(blocks, MAX_JOBS), latent)]
+    while out[-1] < top:
+        out.append(job_bucket(out[-1] + 1, latent))
+    return out
+
+
+def warm_descriptors(num_tokens, jobs, q_block, page_size, pages_per_seq):
+    """Descriptors ``(block_tables, seq_slots, q_starts, q_lens,
+    context_lens)`` of a call over ``num_tokens`` tokens whose flat list has
+    exactly ``jobs`` jobs (clipped to what that many tokens can hold), for
+    warming the compiled program of a job bucket through the public op:
+    single-token spans of sequences of their own, the first token of every
+    q-block among them, over tables that point at page 0."""
+    t, p = int(num_tokens), int(pages_per_seq)
+    pages = np.zeros(t, np.int64)
+    pages[::q_block] = 1                    # no q-block without a job
+    room = p - pages
+    left = int(np.clip(jobs, pages.sum(), t * p)) - int(pages.sum())
+    filled = np.minimum(np.cumsum(room), left)
+    pages += np.diff(filled, prepend=0)
+    rows = np.flatnonzero(pages).astype(np.int32)
+    return (np.zeros((t, p), np.int32), rows, rows,
+            np.ones(len(rows), np.int32),
+            (pages[rows] * page_size).astype(np.int32))
+
+
+def qblock_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
                     block_tables, q_block, page_size):
-    """Host-side schedule of the latent q-block kernel, vectorized: the
-    same jobs as :func:`qblock_schedule` (one a KV page a sequence of a
-    q-block needs, a sequence's pages ascending) as ONE flat list in block
-    order, so the grid walks the jobs that exist and not ``blocks x the
-    longest block's jobs``: with 64 query heads on one KV head a block of 8
-    decode rows at 8 k of context has 500 jobs and a block of prefill rows
-    64. Padding rows (outside every span) get slot -1 / ctx 0 and own no
-    job; a block without jobs gets one that matches nothing (slot -2), so
-    that its output is written; the list is padded to a power of two with
-    such jobs on the last block.
+    """Host-side schedule of both q-block kernels, vectorized: the flat
+    packed batch is tiled into ``q_block``-row blocks over the cumulative
+    span offsets, and the "jobs" are one (q-block, physical page, owner
+    slot, kv offset) a KV page a sequence of a q-block still needs, as ONE
+    flat list in block order, so the grid walks the jobs that exist and not
+    ``blocks x the longest block's jobs``: 8 decode rows at 350 tokens of
+    context are a block of 176 jobs, 8 rows of a prefill chunk one of 2 to
+    90. Within a block the sequences stand in order of first appearance and
+    a sequence's pages ascend, so each row meets its own pages in exactly
+    the per-token kernel's order.
 
-    Returns ``(row_slot [B*q_block], row_ctx [B*q_block], jobs [4, J])``
+    Sentinels: rows outside every span (bucket and block padding) get slot
+    -1 / ctx 0 and own no job; a block without jobs gets one that matches
+    nothing (slot -2, page 0), so that its output is written. -1 and -2
+    never match each other, and the kernels skip a job of slot -2.
+
+    Returns ``(row_slot [B*q_block], row_ctx [B*q_block], jobs [4, n])``
     int32 numpy; ``jobs`` rows are (q-block, physical page, owner slot,
-    kv offset)."""
-    import numpy as np
-
+    kv offset), and ``n`` is the list's own length: no padding."""
     ss = np.asarray(seq_slots, np.int64).reshape(-1)
     qs = np.asarray(q_starts, np.int64).reshape(-1)
     ql = np.asarray(q_lens, np.int64).reshape(-1)
@@ -497,8 +259,10 @@ def latent_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
         np.maximum.at(pages, inv, n_pages)
         first = np.zeros(len(uniq), np.int64)
         first[inv[::-1]] = np.arange(len(key))[::-1]
-        pair_block, pair_slot, n_pages = (pair_block[first],
-                                          pair_slot[first], pages)
+        keep = np.argsort(first)            # order of first appearance
+        pair_block, pair_slot, n_pages = (pair_block[first[keep]],
+                                          pair_slot[first[keep]],
+                                          pages[keep])
     empty = np.setdiff1d(np.arange(nblocks), pair_block)
     pair_block = np.concatenate([pair_block, empty])
     pair_slot = np.concatenate([pair_slot, np.full(len(empty), -2)])
@@ -508,29 +272,67 @@ def latent_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
                                       n_pages[order])
 
     total = int(n_pages.sum())
-    num_jobs = max(1 << (total - 1).bit_length(), LATENT_MIN_JOBS)
-    jobs = np.zeros((4, num_jobs), np.int32)
-    jobs[0, total:] = nblocks - 1
-    jobs[2, total:] = -2
+    jobs = np.empty((4, total), np.int32)
     page_idx = np.arange(total) - np.repeat(np.cumsum(n_pages) - n_pages,
                                             n_pages)
     slot = np.repeat(pair_slot, n_pages)
-    jobs[0, :total] = np.repeat(pair_block, n_pages)
-    jobs[1, :total] = np.where(slot >= 0,
-                               tbl[np.maximum(slot, 0), page_idx], 0)
-    jobs[2, :total] = slot
-    jobs[3, :total] = page_idx * page_size
+    jobs[0] = np.repeat(pair_block, n_pages)
+    jobs[1] = np.where(slot >= 0, tbl[np.maximum(slot, 0), page_idx], 0)
+    jobs[2] = slot
+    jobs[3] = page_idx * page_size
     return row_slot, row_ctx, jobs
 
 
-def _latent_kernel(jobs_ref, rs_ref, rc_ref, q_ref, kv_ref, o_ref, m_ref,
-                   l_ref, acc_ref, *, sm_scale, num_jobs, value_dim):
-    """One grid step: a q-block's rows (``q_block`` tokens x all heads)
-    against one page of latent rows (``[d, page_size]``: a token a column,
-    as the pool keeps them), read once for keys and values. The
-    products take the pool's type as their operands' (bf16 x bf16 products
-    are exact in the float32 accumulator) and the softmax runs in
-    float32; the weights enter the second product in the pool's type."""
+def _padded_jobs(jobs, length, count=False):
+    """``jobs`` [4, n] in an array of ``length`` columns; the padding is
+    jobs that match nothing (slot -2, page 0) on the last q-block.
+    ``count`` (``n < length``: the caller adds a column to the bucket): the
+    last column, which is then never a job, says ``n`` in its kv-offset
+    entry: the grid's bound for a kernel that reads it on the device (one
+    array to move, not two; a fifth row would double the array in the
+    chip's scalar memory, which tiles rows by 8)."""
+    n = jobs.shape[1]
+    out = np.zeros((4, length), np.int32)
+    out[:, :n] = jobs
+    out[0, n:] = jobs[0, -1]
+    out[2, n:] = -2
+    if count:
+        assert n < length
+        out[3, -1] = n
+    return out
+
+
+def latent_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
+                    block_tables, q_block, page_size):
+    """:func:`qblock_job_list` for the latent kernel, whose grid is the
+    list as it arrives: padded to a power of two (``LATENT_MIN_JOBS`` at
+    the least) with jobs that match nothing, which the kernel skips."""
+    row_slot, row_ctx, jobs = qblock_job_list(
+        num_tokens, seq_slots, q_starts, q_lens, context_lens, block_tables,
+        q_block, page_size)
+    return row_slot, row_ctx, _padded_jobs(
+        jobs, job_bucket(jobs.shape[1], latent=True))
+
+
+def _qblock_masked_scores(s, kv_start, jslot, row_slot, row_ctx):
+    """Causal bound with NEG_INF (bitwise the per-token kernel's mask on
+    a row's own pages), then the whole row to finite BIG_NEG wherever
+    the row's sequence does not own this job's page."""
+    pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(pos < row_ctx, s, NEG_INF)
+    return jnp.where(row_slot == jslot, s, BIG_NEG)
+
+
+def _job_walk(jobs_ref, num_jobs, m_ref, l_ref, acc_ref, step, finalize,
+              skip):
+    """One grid step of a walk over the first ``num_jobs`` (a Python
+    number or a scalar read in the kernel) of the flat job list
+    ``jobs_ref``: the softmax state starts anew at a q-block's first job
+    and the output is written at its last (a block's jobs are neighbours
+    in the list); ``step(jslot, kv_start)`` runs the job. ``skip``: a job
+    without an owner (slot -2) costs the grid step alone; worth its branch
+    where the list is padded (else such a job is the one of a q-block of
+    padding rows, and running it is an exact no-op on rows nobody reads)."""
     j = pl.program_id(0)
     blk = jobs_ref[0, j]
     first = (j == 0) | (jobs_ref[0, jnp.maximum(j - 1, 0)] != blk)
@@ -544,15 +346,201 @@ def _latent_kernel(jobs_ref, rs_ref, rc_ref, q_ref, kv_ref, o_ref, m_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     jslot = jobs_ref[2, j]
+    if skip:
+        pl.when(jslot >= 0)(lambda: step(jslot, jobs_ref[3, j]))
+    else:
+        step(jslot, jobs_ref[3, j])
+    pl.when(last)(finalize)
 
-    @pl.when(jslot >= 0)                            # -2: matches no row
-    def _step():
+
+def _qblock_kernel(jobs_ref, rows_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
+                   quant, unroll):
+    """One grid step: a q-block's rows (``q_block`` tokens x all query
+    heads) against one KV page, EVERY KV head of it: the page block is
+    ``[kv_heads, 1, page_size, d]`` and each head runs the per-token
+    kernel's two 2-D float32 products and its float32 softmax on its own
+    ``[q_block*group, d]`` rows and its own slice of the scratch arrays
+    (a loop over the heads, unrolled for the chip, so that the heads'
+    chains interleave). ``jobs_ref`` [4, J + 1]: the list and, at ``[3, J]``,
+    its length. ``quant`` (int8 KV): the per-row float32 scales arrive as
+    ``[kv_heads, 1, 1, page_size]`` lane vectors and scale the scores /
+    weights around the int8 products (see ``_decode_kernel_quant``)."""
+    if quant:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
+
+    def step(jslot, kv_start):
+        row_slot = rows_ref[0, 0][:, :1]               # [Qg, 1]
+        row_ctx = rows_ref[1, 0][:, :1]
+
+        def head(h, carry):
+            q = q_ref[0, h].astype(jnp.float32)        # [Qg, d]
+            k = k_ref[h, 0].astype(jnp.float32)        # [page_size, d]
+            v = v_ref[h, 0].astype(jnp.float32)
+            scale = ks_ref[h, 0] * sm_scale if quant else sm_scale
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = _qblock_masked_scores(s, kv_start, jslot, row_slot, row_ctx)
+            m_prev = m_ref[h][:, :1]                   # [Qg, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            w = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_ref[h][:, :1] * corr + jnp.sum(w, -1, keepdims=True)
+            pv = jax.lax.dot_general(                  # [Qg, d]
+                w * vs_ref[h, 0] if quant else w, v,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_ref[h] = acc_ref[h] * corr + pv
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            return carry
+
+        jax.lax.fori_loop(0, k_ref.shape[0], head, 0, unroll=unroll)
+
+    def finalize():
+        l = jnp.maximum(l_ref[...][:, :, :1], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    _job_walk(jobs_ref, jobs_ref[3, jobs_ref.shape[1] - 1], m_ref, l_ref,
+              acc_ref, step, finalize, skip=False)
+
+
+def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
+                                          block_tables, seq_slots,
+                                          q_starts, q_lens, context_lens,
+                                          *, sm_scale, interpret,
+                                          k_scales=None, v_scales=None,
+                                          q_block=None, value_dim=None):
+    """Q-block tier: grid ``(jobs,)`` over the flat packed batch — one
+    grid step covers ``q_block`` tokens (all their heads) against one KV
+    page, and the grid is the list of such (q-block, page) jobs that exist
+    (:func:`qblock_job_list`), so a mixed prefill+decode tick runs far
+    fewer (and fatter) MXU steps than the per-token grid. Requires
+    concrete descriptors (the job list is built host-side).
+
+    ``v_pages is None`` is the LATENT call: one pool of one KV head whose
+    row a token holds keys and values alike (``value_dim``: the values are
+    that prefix of the row). The host half (this entry, its spans, then
+    with ``latent=1``, the job list) is shared; the device half is a
+    wrapper of its own name (:func:`_latent_qblock_device` beside
+    :func:`_qblock_device`), so a device trace tells the two kernels
+    apart. The ``attn/qblock`` span says how far the list fills the grid:
+    ``jobs`` (the grid steps of one KV head's walk), ``real_jobs`` (those
+    with an owner), ``steps`` (grid steps the call runs, over all grid
+    axes) and ``blocks``."""
+    tokens = q.shape[0]
+    qb = q_block or _qblock_rows()
+    latent = v_pages is None
+    page_size = k_pages.shape[3 if latent else 2]   # latent: tokens = columns
+    build = latent_job_list if latent else qblock_job_list
+    args = {"latent": 1} if latent else {}
+    with _spans.span("attn/qblock", **args) as sp:
+        with _spans.span("attn/qblock_schedule", **args):
+            row_slot, row_ctx, jobs = build(
+                tokens, seq_slots, q_starts, q_lens, context_lens,
+                block_tables, qb, page_size)
+        n = jobs.shape[1]                   # the grid, either kernel's
+        if sp is not _spans.NULL:           # counted only where recorded
+            sp.set(jobs=n, blocks=row_slot.shape[0] // qb,
+                   real_jobs=int((jobs[2] >= 0).sum()), steps=n)
+        if latent:
+            return _latent_call(jobs, row_slot, row_ctx, q, k_pages,
+                                sm_scale, interpret, value_dim, qb)
+        return _qblock_device(
+            _padded_jobs(jobs, job_bucket(n) + 1, count=True),
+            np.stack([row_slot, row_ctx]).reshape(2, -1, qb), q, k_pages,
+            v_pages, k_scales, v_scales, sm_scale=sm_scale,
+            interpret=interpret)
+
+
+def _spread_rows(a, rows_a_token):
+    """Row metadata ``a`` [..., B, q_block], one value a token, spread over
+    the ``rows_a_token`` kernel rows of a token and over 128 lanes, so that
+    the kernel can slice ``[:, :1]`` (the softmax scratch's layout trick).
+    On the device: host-side these are half a megabyte a layer at 4 rows a
+    token and 16 MB at 64."""
+    a = jnp.repeat(jnp.asarray(a, jnp.int32), rows_a_token, axis=-1)
+    return jnp.broadcast_to(a[..., None], a.shape + (128,))
+
+
+@_device_call
+def _qblock_device(jobs, rows, q, k_pages, v_pages, k_scales, v_scales, *,
+                   sm_scale, interpret):
+    """Device half of the q-block tier: the schedule arrives as two arrays
+    (``jobs`` [4, J + 1], scalar-prefetched: the list and at ``[3, J]`` its
+    own length; ``rows`` [2, B, q_block]: slot and context bound, one value
+    a token). The grid is ``(jobs[3, J],)``, a bound read on the device, so
+    one compiled program serves every tick of a (tokens,
+    :func:`job_bucket`) shape and walks no padding."""
+    tokens, heads, d = q.shape
+    kv_heads, _, page_size, _ = k_pages.shape
+    group = heads // kv_heads
+    _, nblocks, qb = rows.shape
+    t_pad, qg_rows = nblocks * qb, qb * group
+
+    qp = jnp.pad(q, ((0, t_pad - tokens), (0, 0), (0, 0)))
+    qg = qp.reshape(nblocks, qb, kv_heads, group, d).transpose(
+        0, 2, 1, 3, 4).reshape(nblocks, kv_heads, qg_rows, d)
+
+    quant = k_scales is not None
+    kernel = functools.partial(_qblock_kernel, sm_scale=sm_scale,
+                               quant=quant, unroll=not interpret)
+    page_spec = pl.BlockSpec((kv_heads, 1, page_size, d),
+                             lambda j, jobs: (0, jobs[1, j], 0, 0))
+    scale_spec = pl.BlockSpec((kv_heads, 1, 1, page_size),
+                              lambda j, jobs: (0, jobs[1, j], 0, 0))
+    row_spec = pl.BlockSpec((2, 1, qg_rows, 128),
+                            lambda j, jobs: (0, jobs[0, j], 0, 0))
+    block_spec = pl.BlockSpec((1, kv_heads, qg_rows, d),
+                              lambda j, jobs: (jobs[0, j], 0, 0, 0))
+    in_specs = [row_spec, block_spec, page_spec, page_spec]
+    operands = [_spread_rows(rows, group), qg, k_pages, v_pages]
+    if quant:
+        in_specs += [scale_spec, scale_spec]
+        operands += [_scale_rows(k_scales), _scale_rows(v_scales)]
+    jobs = jnp.asarray(jobs, jnp.int32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(jobs[3, -1],),
+        in_specs=in_specs,
+        out_specs=block_spec,
+        scratch_shapes=[
+            pltpu.VMEM((kv_heads, qg_rows, 128), jnp.float32),
+            pltpu.VMEM((kv_heads, qg_rows, 128), jnp.float32),
+            pltpu.VMEM((kv_heads, qg_rows, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((nblocks, kv_heads, qg_rows, d),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jobs, *operands)
+    out = out.reshape(nblocks, kv_heads, qb, group, d).transpose(
+        0, 2, 1, 3, 4).reshape(t_pad, heads, d)
+    return out[:tokens]
+
+
+def _latent_kernel(jobs_ref, rs_ref, rc_ref, q_ref, kv_ref, o_ref, m_ref,
+                   l_ref, acc_ref, *, sm_scale, num_jobs, value_dim):
+    """One grid step: a q-block's rows (``q_block`` tokens x all heads)
+    against one page of latent rows (``[d, page_size]``: a token a column,
+    as the pool keeps them), read once for keys and values. The
+    products take the pool's type as their operands' (bf16 x bf16 products
+    are exact in the float32 accumulator) and the softmax runs in
+    float32; the weights enter the second product in the pool's type."""
+    def step(jslot, kv_start):
         q = q_ref[0]                                # [rows, d]
         kv = kv_ref[0, 0]                           # [d, page_size]
         s = jax.lax.dot_general(
             q, kv, (((1,), (0,)), ((), ())), precision=_DEFAULT,
             preferred_element_type=jnp.float32) * sm_scale
-        s = _qblock_masked_scores(s, jobs_ref[3, j], jslot,
+        s = _qblock_masked_scores(s, kv_start, jslot,
                                   rs_ref[0][:, :1], rc_ref[0][:, :1])
         m_prev = m_ref[...][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -567,18 +555,19 @@ def _latent_kernel(jobs_ref, rs_ref, rc_ref, q_ref, kv_ref, o_ref, m_ref,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(last)
-    def _finalize():
+    def finalize():
         l = jnp.maximum(l_ref[...][:, :1], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    _job_walk(jobs_ref, num_jobs, m_ref, l_ref, acc_ref, step, finalize,
+              skip=True)
 
 
 def _latent_qblock_device(jobs, row_slot, row_ctx, q, kv_pages, sm_scale,
                           interpret, value_dim, q_block):
     """Device half of the latent q-block tier: ``jobs`` [4, J] is scalar-
     prefetched, the rows' slot and context bound arrive one a TOKEN and are
-    spread over the heads here, on the device (64 heads a token would make
-    them 16 MB a layer on the host's side of the link)."""
+    spread over the heads here, on the device."""
     tokens, heads, d = q.shape
     _, _, _, page_size = kv_pages.shape
     num_jobs = jobs.shape[1]
@@ -589,9 +578,7 @@ def _latent_qblock_device(jobs, row_slot, row_ctx, q, kv_pages, sm_scale,
         nblocks, rows, d)
 
     def spread(a):
-        a = jnp.repeat(jnp.asarray(a, jnp.int32).reshape(nblocks, q_block),
-                       heads, axis=1)
-        return jnp.broadcast_to(a[:, :, None], (nblocks, rows, 128))
+        return _spread_rows(jnp.reshape(a, (nblocks, q_block)), heads)
 
     kernel = functools.partial(_latent_kernel, sm_scale=sm_scale,
                                num_jobs=num_jobs, value_dim=value_dim)
@@ -934,8 +921,6 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,
     """Dense numpy-style oracle: per sequence, gather its context from
     the pages and run plain causal softmax attention for its span. Rows
     outside every span are zero."""
-    import numpy as np
-
     tokens, heads, d = q.shape
     kv_heads, _, page_size, _ = k_pages.shape
     group = heads // kv_heads

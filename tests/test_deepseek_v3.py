@@ -28,6 +28,7 @@ from paddle_tpu.inference import ContinuousServingEngine
 from paddle_tpu.models.deepseek_v3 import (DeepseekV3ForCausalLM,
                                            deepseek_v3_tiny)
 from paddle_tpu.models.generation import SlotPagedKVCache, kv_page_nbytes
+from qblock_oracle import qblock_schedule
 
 ref = importlib.import_module("benchmark.reference.deepseek_v3")
 rpa = importlib.import_module("paddle_tpu.ops.pallas.ragged_paged_attention")
@@ -332,17 +333,17 @@ def test_latent_kernel_and_its_flat_job_list():
     want = _dense_latent_attention(q, pool, tables, ss, qs, ql, cl, vd, 0.3)
     np.testing.assert_allclose(got[:23], want[:23], atol=2e-6, rtol=0)
     assert np.isfinite(got).all()                  # padding rows too
-    # the flat list holds the jobs of ``qblock_schedule``, block by block
+    # the flat list holds the jobs of the grid of ``blocks x the longest
+    # block's jobs`` (tests/qblock_oracle.py), block by block
     row_slot, row_ctx, jobs = rpa.latent_job_list(32, ss, qs, ql, cl, tables,
                                                   8, page)
-    _, _, jp, js, jk = rpa.qblock_schedule(32, ss, qs, ql, cl, tables, 8,
-                                           page)
+    _, _, jp, js, jk = qblock_schedule(32, ss, qs, ql, cl, tables, 8, page)
     assert jobs.shape == (4, 64) and (jobs[0, 1:] >= jobs[0, :-1]).all()
     for b in range(4):
         mine = {tuple(j[1:]) for j in jobs.T if j[0] == b and j[2] >= 0}
         theirs = {(p, s, k) for p, s, k in zip(jp[b], js[b], jk[b])
                   if s >= 0}
-        # (qblock_schedule also walks slot 0's first page for padding rows)
+        # (the oracle also walks slot 0's first page for padding rows)
         assert mine == theirs or mine | {(tables[0, 0], 0, 0)} == theirs
     assert (row_slot[:23] >= 0).all() and (row_slot[23:] == -1).all()
     assert row_ctx[3:23].tolist() == list(range(22, 42))

@@ -27,11 +27,14 @@ import paddle_tpu as paddle
 from paddle_tpu.inference import ContinuousServingEngine
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 from paddle_tpu.models.generation import quantize_kv_rows
+from qblock_oracle import qblock_schedule
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     ragged_paged_attention, ragged_paged_attention_reference,
-    qblock_schedule, DEFAULT_QBLOCK, _qblock_rows, _token_descriptors,
+    qblock_job_list, latent_job_list, job_bucket, job_buckets,
+    warm_descriptors, MIN_JOBS, LATENT_MIN_JOBS, MAX_JOBS,
+    DEFAULT_QBLOCK, _qblock_rows, _token_descriptors,
     _ragged_paged_attention_pallas, _ragged_paged_attention_pallas_quant,
-    _ragged_paged_attention_pallas_qblock)
+    _ragged_paged_attention_pallas_qblock, _ragged_paged_attention_xla)
 
 
 # ---------------------------------------------------------------------------
@@ -60,14 +63,14 @@ KERNEL_TOL = dict(rtol=1e-6, atol=1e-6)
 
 
 def _parity(layout, tokens=None, q_block=8, heads=4, d=32, seed=0,
-            tbl_edit=None, quant=False):
+            tbl_edit=None, quant=False, **pool):
     """Run the SAME descriptors through the q-block and per-token
     interpret kernels: span rows must agree to KERNEL_TOL (~1 ulp — the
     q-block grid replays the per-token online-softmax recurrence
     job-by-job in the same order; see KERNEL_TOL for why not bitwise)
     and match the dense reference to float tolerance."""
     kp, vp, tbl = _pool(nslots=max(x[0] for x in layout) + 1, d=d,
-                        seed=seed)
+                        seed=seed, **pool)
     if tbl_edit is not None:
         tbl_edit(tbl)
     seq_slots = np.asarray([x[0] for x in layout], np.int32)
@@ -153,38 +156,185 @@ def test_qblock_small_block_size():
     _parity([(0, 0, 7, 15), (1, 7, 5, 5), (2, 12, 1, 30)], q_block=2)
 
 
+def _jobs_of(jobs, b):
+    """A block's real jobs in list order, as (page, slot, kv offset)."""
+    return [tuple(int(x) for x in j[1:]) for j in jobs.T
+            if j[0] == b and j[2] >= 0]
+
+
 def test_qblock_schedule_contract():
-    """Sentinels, ordering, and pow2 job padding of the host schedule."""
-    kp, vp, tbl = _pool(nslots=3, page=8)
+    """Sentinels, ordering and length of the flat job list."""
+    kp, vp, tbl = _pool(nslots=3, page=8, pages_per_seq=5)
     seq_slots = np.asarray([0, 1, 2], np.int32)
     q_starts = np.asarray([0, 1, 10], np.int32)
     q_lens = np.asarray([1, 9, 6], np.int32)
     ctx = np.asarray([33, 25, 6], np.int32)
-    row_slot, row_ctx, job_page, job_slot, job_kv = qblock_schedule(
+    row_slot, row_ctx, jobs = qblock_job_list(
         17, seq_slots, q_starts, q_lens, ctx, tbl, 8, 8)
     assert row_slot.shape == (24,)               # ceil(17/8)*8
-    # block-pad rows (slot -1 / ctx 0) differ from pad jobs (slot -2)
-    np.testing.assert_array_equal(row_slot[17:], -1)
-    np.testing.assert_array_equal(row_ctx[17:], 0)
-    B, J = job_page.shape
-    assert B == 3 and J & (J - 1) == 0           # pow2 job bucket
-    # pad jobs use the sentinel slot -2 and the scratch page 0
-    assert (job_page[job_slot == -2] == 0).all()
-    # every real job's page comes from its owner's block table, kv
-    # offsets ascend per owner in page order
-    for b in range(B):
-        for j in range(J):
-            s = int(job_slot[b, j])
-            if s < 0:
-                continue
-            p = int(job_kv[b, j]) // 8
-            assert job_page[b, j] == tbl[s, p]
+    # rows outside every span (slot -1 / ctx 0) differ from pad jobs (-2)
+    np.testing.assert_array_equal(row_slot[16:], -1)
+    np.testing.assert_array_equal(row_ctx[16:], 0)
+    block, page, slot, kv = jobs
+    # the list as it is: 8 + 5 real jobs and block 2's one job, no padding
+    assert jobs.shape == (4, 14) and jobs.dtype == np.int32
+    # ONE list in block order: a block's jobs are neighbours
+    assert (np.diff(block) >= 0).all() and set(block) == {0, 1, 2}
+    # block 2 holds padding rows alone: one job that matches nothing
+    # (sentinel slot -2, the scratch page 0), so that its output is written
+    assert (slot[:13] >= 0).all()
+    assert (block[13], page[13], slot[13], kv[13]) == (2, 0, -2, 0)
+    # the latent kernel walks the same list padded to a power of two with
+    # such jobs on the last block; the array the Llama kernel's list rides
+    # in has a bucket of its own, which the grid never walks
+    _, _, padded = latent_job_list(17, seq_slots, q_starts, q_lens, ctx,
+                                   tbl, 8, 8)
+    assert padded.shape == (4, LATENT_MIN_JOBS)
+    np.testing.assert_array_equal(padded[:, :14], jobs)
+    assert (padded[0, 14:] == 2).all() and (padded[2, 14:] == -2).all()
+    assert (padded[1, 14:] == 0).all()
+    assert job_bucket(14) == MIN_JOBS == job_bucket(MIN_JOBS)
+    # every real job's page comes from its owner's block table; within a
+    # block the owners stand in order of first appearance and each
+    # owner's pages ascend, to the bound of its last row in the block
+    assert _jobs_of(jobs, 0) == (
+        [(int(tbl[0, p]), 0, 8 * p) for p in range(5)]       # ctx 33
+        + [(int(tbl[1, p]), 1, 8 * p) for p in range(3)])    # rows to 23
+    assert _jobs_of(jobs, 1) == (
+        [(int(tbl[1, p]), 1, 8 * p) for p in range(4)]       # rows to 25
+        + [(int(tbl[2, 0]), 2, 0)])
+    assert _jobs_of(jobs, 2) == []
+    # the matrix the grid walked before, job for job (it also walked slot
+    # 0's first page for the padding token 16)
+    _, _, jp, js, jk = qblock_schedule(17, seq_slots, q_starts, q_lens, ctx,
+                                       tbl, 8, 8)
+    for b in range(3):
+        theirs = [(int(p), int(s), int(k))
+                  for p, s, k in zip(jp[b], js[b], jk[b]) if s >= 0]
+        assert _jobs_of(jobs, b) == theirs[:len(_jobs_of(jobs, b))]
+        assert theirs[len(_jobs_of(jobs, b)):] in (
+            [], [(int(tbl[0, 0]), 0, 0)])
     # decode-only blocks stop at each owner's context, not the table end
-    _, _, jp2, js2, _ = qblock_schedule(
+    _, _, jobs2 = qblock_job_list(
         3, np.arange(3, dtype=np.int32), np.arange(3, dtype=np.int32),
         np.ones(3, np.int32), np.asarray([7, 19, 30], np.int32), tbl, 8, 8)
-    real = int((js2[0] >= 0).sum())
-    assert real == 1 + 3 + 4                     # ceil(7/8)+ceil(19/8)+ceil(30/8)
+    assert int((jobs2[2] >= 0).sum()) == 1 + 3 + 4   # ceil(7/8)+(19/8)+(30/8)
+    # a slot met twice in one block (two spans of one sequence, as a
+    # speculative verify packs them) is walked ONCE, to its longest bound
+    _, _, jobs3 = qblock_job_list(
+        8, np.asarray([1, 0, 1]), np.asarray([0, 2, 3]),
+        np.asarray([2, 1, 2]), np.asarray([10, 5, 20]), tbl, 8, 8)
+    assert _jobs_of(jobs3, 0) == (
+        [(int(tbl[1, p]), 1, 8 * p) for p in range(3)]
+        + [(int(tbl[0, 0]), 0, 0)])
+
+
+def test_job_buckets_and_warm_descriptors():
+    """The declared family of one token bucket: every bucket between one
+    job a block and the most its pairs can hold, and descriptors that land
+    a call in each of them."""
+    assert job_bucket(0) == job_bucket(1024) == 1024
+    assert job_bucket(1025) == job_bucket(8192) == 8192
+    assert job_bucket(8193) == job_bucket(MAX_JOBS) == MAX_JOBS == 32768
+    with pytest.raises(ValueError, match="over the 32768"):
+        job_bucket(MAX_JOBS + 1)        # 16 bytes a job: the chip's SMEM
+    # the latent kernel's grid is its padded list: powers of two from 64
+    assert [job_bucket(n, latent=True) for n in (0, 64, 65, 5000)] == [
+        LATENT_MIN_JOBS, 64, 128, 8192]
+    assert job_buckets(512, 8, 16, 136, latent=True) == [
+        64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384]
+    # a family's ladder stops at what the list can hold
+    assert job_buckets(1024, 8, 64, 512) == [1024, 8192, MAX_JOBS]
+    # 256 tokens, 32 sequences, 128 pages a sequence: 64 pairs x 128
+    assert job_buckets(256, 8, 32, 128) == [1024, 8192]
+    assert job_buckets(1, 8, 32, 128) == [1024]              # 128 jobs
+    assert job_buckets(8, 8, 32, 128) == [1024]              # 1,024 jobs
+    assert job_buckets(16, 8, 32, 128) == [1024, 8192]       # 2,048 jobs
+    assert job_buckets(16384, 8, 4, 2) == [8192]     # 2,048 blocks at least
+    for tokens in (1, 8, 24, 256):
+        for want in job_buckets(tokens, 8, 32, 128):
+            tbl, ss, qs, ql, cl = warm_descriptors(tokens, want, 8, 16, 128)
+            _, _, jobs = qblock_job_list(tokens, ss, qs, ql, cl, tbl, 8, 16)
+            assert jobs.shape[1] == min(want, tokens * 128)
+            assert job_bucket(jobs.shape[1]) == want, (tokens, want)
+            assert (np.diff(qs) > 0).all() and (cl <= 128 * 16).all()
+
+
+#: one tick of ``serve_chat_closed`` (benchmark/traffic/chat_closed_32.json
+#: on benchmark/configs/mistral-7b-serve-16l.json): 30 decode rows part of
+#: the way through their output and one 226-token chunk of a long prompt,
+#: in the 256-token bucket; pages of 16, ``max_len`` 2048
+def _cell_tick(seed=0, chunk_ctx=1100):
+    rng = np.random.RandomState(seed)
+    prompt = np.clip(np.exp(rng.normal(np.log(256), 0.8, 30)), 32, 1536)
+    done = rng.uniform(0, 1, 30) * np.clip(
+        np.exp(rng.normal(np.log(48), 0.7, 30)), 8, 192)
+    ctx = np.concatenate([(prompt + done).astype(np.int32) + 1,
+                          [chunk_ctx]]).astype(np.int32)
+    q_lens = np.asarray([1] * 30 + [226], np.int32)
+    q_starts = np.arange(31, dtype=np.int32)
+    return np.arange(31, dtype=np.int32), q_starts, q_lens, ctx
+
+
+def test_qblock_grid_walks_the_jobs_that_exist():
+    """The mechanism, pinned on the CPU: on a tick of the cell's shape the
+    (job, KV head) pairs the grid walks are at most twice the real ones
+    (they are the real ones, and one more for a block of padding rows). A
+    grid of ``blocks x the longest block's jobs`` walks 8,192 a head here
+    for under 2,000 real ones and fails this."""
+    from paddle_tpu.profiler import get_tracer, spans
+    kv_heads, group, d, page, pps = 8, 4, 16, 16, 128
+    ss, qs, ql, cl = _cell_tick()
+    tbl = (1 + np.arange(32 * pps, dtype=np.int32)).reshape(32, pps)
+    _, _, jobs = qblock_job_list(256, ss, qs, ql, cl, tbl, 8, page)
+    real = int((jobs[2] >= 0).sum())
+    assert 1000 < real and jobs.shape[1] <= 2 * real
+    # ... and by the counters the kernel's own span carries, which is what
+    # ``qblock_job_fill_pct`` reads on the chip
+    pool = jnp.zeros((kv_heads, 5, page, d), jnp.float32)
+    q = jnp.zeros((16, kv_heads * group, d), jnp.float32)
+    tracer = get_tracer()
+    tracer.drain()
+    tracer.enable()
+    try:
+        spans.latch()
+        _ragged_paged_attention_pallas_qblock(
+            q, pool, pool, np.zeros((3, 4), np.int32), ss[:3], qs[:3],
+            np.ones(3, np.int32), np.asarray([40, 17, 64], np.int32),
+            sm_scale=1.0, interpret=True)
+    finally:
+        tracer.disable()
+        spans.latch()
+    got = [s for s in tracer.drain() if s.name == "attn/qblock"][-1].args
+    assert got["real_jobs"] == 3 + 2 + 4 and got["blocks"] == 2
+    # one grid axis, the list itself: block 1's one job is its only other
+    assert got["jobs"] == got["steps"] == 3 + 2 + 4 + 1
+
+
+def test_qblock_cell_shape_parity():
+    # the cell's tick: 8 KV heads x group 4 in one grid step, decode
+    # blocks of 8 owners beside 29 blocks of one long prefill span. Every
+    # span row against the gather+softmax tier; a sample of rows (the
+    # per-token grid would take 131 k interpreted steps for all of them)
+    # against the per-token kernel, whose recurrence this one replays
+    ss, qs, ql, cl = _cell_tick(seed=3, chunk_ctx=700)
+    cl = np.minimum(cl, 1024)
+    kp, vp, tbl = _pool(nslots=31, d=16, kv_heads=8, page=16,
+                        pages_per_seq=64)
+    q = jnp.asarray(np.random.RandomState(1).randn(256, 32, 16), jnp.float32)
+    sm = 16 ** -0.5
+    qb = np.asarray(_ragged_paged_attention_pallas_qblock(
+        q, kp, vp, tbl, ss, qs, ql, cl, sm_scale=sm, interpret=True))
+    ts, tc = _token_descriptors(256, ss, qs, ql, cl)
+    dense = np.asarray(_ragged_paged_attention_xla(
+        q, kp, vp, tbl, ts, tc, sm_scale=sm))
+    assert np.isfinite(qb).all()
+    np.testing.assert_allclose(qb, dense, rtol=2e-5, atol=2e-5)
+    rows = np.r_[0:30:4, 30:256:45]              # 8 decode rows, 6 chunk rows
+    tok = np.asarray(_ragged_paged_attention_pallas(
+        q[rows], kp, vp, jnp.asarray(tbl), ts[rows], tc[rows], sm_scale=sm,
+        interpret=True))
+    np.testing.assert_allclose(qb[rows], tok, **KERNEL_TOL)
 
 
 def test_qblock_rows_env_knob(monkeypatch):
@@ -318,6 +468,129 @@ def test_engine_qblock_vs_token_bit_identical(model, monkeypatch):
     for a, b in zip(got_qb, got_tok):
         np.testing.assert_array_equal(a, b)
     np.testing.assert_array_equal(got_qb[0], _oracle(model, prompts[0], 5))
+
+
+def _random_tick(rng, slots, max_len, budget, page, num_pages):
+    """A tick the ragged scheduler could pack within an engine's limits:
+    up to ``slots`` sequences, a span each, ``budget`` tokens in all,
+    contexts to ``max_len``, block tables that may share pages (prefix
+    hits alias them, so the page pool bounds nothing here)."""
+    nseq = int(rng.integers(1, slots + 1))
+    kind = rng.integers(0, 3)              # decode / mixed / prefill-heavy
+    q_lens = np.ones(nseq, np.int64)
+    left = budget - nseq
+    for i in rng.permutation(nseq)[:{0: 0, 1: 1, 2: nseq}[int(kind)]]:
+        q_lens[i] += int(rng.integers(0, left + 1))
+        left = budget - int(q_lens.sum())
+    q_lens = np.minimum(q_lens, max_len)
+    gaps = rng.integers(0, 2, nseq) * (rng.random(nseq) < 0.1)
+    q_starts = np.cumsum(q_lens + gaps) - q_lens
+    keep = q_starts + q_lens <= budget
+    q_lens, q_starts = q_lens[keep], q_starts[keep]
+    ctx = np.array([rng.integers(n, max_len + 1) if rng.random() < 0.7
+                    else max_len for n in q_lens])
+    pps = -(-max_len // page)
+    tables = rng.integers(1, num_pages, (slots, pps)).astype(np.int32)
+    seq_slots = rng.permutation(slots)[:len(q_lens)]
+    return tables, seq_slots, q_starts, q_lens, ctx
+
+
+@pytest.mark.parametrize("limits", [
+    dict(max_batch_size=32, max_len=2048, token_budget=256, page_size=16,
+         num_pages=2049),                            # serve_chat_closed's
+    dict(max_batch_size=4, max_len=64, token_budget=16, page_size=8,
+         num_pages=None),
+    dict(max_batch_size=8, max_len=200, token_budget=24, page_size=16,
+         num_pages=40),                              # a pool far too small
+])
+def test_engine_declares_every_kernel_bucket_a_tick_can_reach(model, limits):
+    """Property: whatever a tick within the engine's limits holds, its
+    (token bucket, job bucket) is one ``warmup_programs`` compiles, so no
+    kernel program is first met inside a window."""
+    from paddle_tpu.inference.serving import _token_bucket
+    eng = ContinuousServingEngine(model, **limits)
+    family = eng.declared_kernel_buckets()
+    assert sorted(family) == sorted(eng.declared_token_buckets())
+    rng = np.random.default_rng(28)
+    num_pages = limits["num_pages"] or 1 + eng.max_batch * -(
+        -eng.max_len // eng.page_size)
+    met = set()
+    for _ in range(300):
+        tables, ss, qs, ql, cl = _random_tick(
+            rng, eng.max_batch, eng.max_len, eng.token_budget,
+            eng.page_size, num_pages)
+        padded = _token_bucket(int((qs + ql).max()), eng.token_budget)
+        jobs = job_bucket(qblock_job_list(
+            padded, ss, qs, ql, cl, tables, DEFAULT_QBLOCK,
+            eng.page_size)[2].shape[1])
+        assert jobs in family[padded], (padded, jobs, ql, cl)
+        met.add((padded, jobs))
+    # the worst case is reachable, not a bound's slack: a span astride
+    # every q-block boundary and the other slots a token each, all at the
+    # full context, over shared pages
+    b, qb = max(family), DEFAULT_QBLOCK
+    astride = [k * qb + qb - 1 for k in range(min(-(-b // qb) - 1,
+                                                  eng.max_batch))]
+    free = [t for t in range(b) if t % qb not in (0, qb - 1)]
+    single = free[:eng.max_batch - len(astride)]
+    qs = np.sort(np.asarray(astride + single))
+    ql = np.where(np.isin(qs, astride), 2, 1)
+    tables = np.ones((eng.max_batch, -(-eng.max_len // eng.page_size)),
+                     np.int32)
+    jobs = qblock_job_list(b, np.arange(len(qs)), qs, ql,
+                           np.full(len(qs), eng.max_len), tables, qb,
+                           eng.page_size)[2].shape[1]
+    assert job_bucket(jobs) == family[b][-1]
+    assert jobs > family[b][-1] // 2 or len(family[b]) == 1
+    assert len({t for t, _ in met}) >= 4       # the draws vary the tick
+
+
+def test_warmup_compiles_the_declared_kernel_family(model):
+    """``warmup_programs`` calls the kernel once for every declared (token
+    bucket, job bucket), through the cache and the public op, and a served
+    tick then lands in one of them."""
+    import importlib
+    rpa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    eng = ContinuousServingEngine(model, max_batch_size=2, max_len=32,
+                                  page_size=8, token_budget=8)
+    assert eng.declared_kernel_buckets() == {1: [1024], 2: [1024],
+                                             4: [1024], 8: [1024]}
+    # 256 pages a sequence: a tick of 8 tokens can hold 5 x 256 jobs
+    eng = ContinuousServingEngine(model, max_batch_size=4, max_len=2048,
+                                  page_size=8, token_budget=8)
+    family = eng.declared_kernel_buckets()
+    assert family == {1: [1024], 2: [1024], 4: [1024], 8: [1024, 8192]}
+    seen = []
+    entry = rpa._ragged_paged_attention_pallas_qblock
+
+    def spy(q, *a, **kw):
+        out = entry(q, *a, **kw)
+        seen.append(q.shape[0])
+        return out
+
+    lengths = []
+    build = rpa.qblock_job_list
+
+    def lists(*a, **kw):
+        out = build(*a, **kw)
+        lengths.append(job_bucket(out[2].shape[1]))
+        return out
+
+    rpa._ragged_paged_attention_pallas_qblock = spy
+    rpa.qblock_job_list = lists
+    try:
+        took = eng.warmup_programs()
+        warmed = set(zip(seen, lengths))
+        assert warmed == {(t, j) for t, js in family.items() for j in js}
+        assert took["serving.ragged_attention"] > 0
+        del seen[:], lengths[:]
+        rng = np.random.RandomState(2)
+        _drive(eng, [rng.randint(0, 128, (1, 21)).astype(np.int64)], 3)
+        assert seen and set(zip(seen, lengths)) <= warmed
+    finally:
+        rpa._ragged_paged_attention_pallas_qblock = entry
+        rpa.qblock_job_list = build
 
 
 # ---------------------------------------------------------------------------

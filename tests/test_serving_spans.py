@@ -110,6 +110,9 @@ def test_names_and_parents(traced):
     for s in spans:
         if s.name == "attn/qblock":
             assert s.args["jobs"] >= 1 and s.args["blocks"] >= 1
+            # one grid axis, the flat job list: its real jobs and padding
+            assert 1 <= s.args["real_jobs"] <= s.args["jobs"]
+            assert s.args["steps"] == s.args["jobs"]
 
 
 def test_tick_args_are_the_engines_counter_deltas(traced):

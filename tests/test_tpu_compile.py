@@ -232,6 +232,58 @@ def test_qblock_device_event_name_is_the_one_its_roofline_matches(
     assert calls and all(kernel.search(c) for c in calls), calls
 
 
+# -- the device half at serve_chat_closed's widths --------------------------
+# (benchmark/configs/mistral-7b-serve-16l.json: 32 slots, max_len 2048,
+# 2,049 pages of 16 tokens, float32 pools under a bf16 model)
+CELL_SLOTS, CELL_PAGES = 32, 2049
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_qblock_device_half_at_the_cells_widths_and_largest_job_bucket(
+        compile_on_chip, kv_dtype):
+    """The flat-list grid ``(jobs,)`` with every KV head of a page in one
+    step: Mosaic accepts the ``(kv_heads, 1, 16, 128)`` page block, the
+    head axis on the three scratch arrays and a grid bound read on the
+    device, at the largest job bucket the engine declares for the cell's
+    256-token tick, on float32 pools; the call is still the one
+    ``qblock_roofline`` finds. The int8-KV variant at least compiles
+    there."""
+    rpa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    jobs = rpa.job_buckets(TOKEN_BUDGET, rpa.DEFAULT_QBLOCK, CELL_SLOTS,
+                           PAGES_PER_SEQ)[-1]
+    assert jobs == 8192                 # 64 (block, sequence) pairs x 128
+    blocks = TOKEN_BUDGET // rpa.DEFAULT_QBLOCK
+    pool = ((KV_HEADS, CELL_PAGES, PAGE_SIZE, HEAD_DIM), jnp.dtype(kv_dtype))
+    scales = ((KV_HEADS, CELL_PAGES, PAGE_SIZE), jnp.float32)
+    # the job list with its own length, the grid's bound, in a last column
+    # of its own (128 KB of SMEM), and slot / context bound a token
+    rows = ((2, blocks, rpa.DEFAULT_QBLOCK), jnp.int32)
+    specs = [((4, jobs + 1), jnp.int32), rows,
+             ((TOKEN_BUDGET, HEADS, HEAD_DIM), jnp.bfloat16), pool, pool]
+
+    def plain(jobs, rows, q, kp, vp):
+        return rpa._qblock_device(jobs, rows, q, kp, vp, None, None,
+                                  sm_scale=SM_SCALE, interpret=False)
+
+    def quant(jobs, rows, q, kp, vp, ks, vs):
+        return rpa._qblock_device(jobs, rows, q, kp, vp, ks, vs,
+                                  sm_scale=SM_SCALE, interpret=False)
+
+    if kv_dtype == "int8":
+        text = compile_on_chip(quant, *specs, scales, scales)
+    else:
+        text = compile_on_chip(plain, *specs)
+    calls = _custom_calls(text)
+    kernel = re.compile(_roofline_patterns("qblock_roofline").KERNEL)
+    assert len(calls) == 1 and kernel.search(calls[0]), calls
+    # no copy of a pool around the call (a page block that the compiler
+    # could not read in place would show as one)
+    shape = r"\[%d,%d,%d,%d\]" % pool[0]
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"= \w+" + shape + r"\S* (copy|transpose)\(", ln)]
+
+
 def test_flash_device_event_names_are_the_ones_their_roofline_matches(
         compile_on_chip):
     """``flash_roofline`` tells the forward kernel and the backward's two

@@ -390,7 +390,8 @@ INVENTORY = [
     # -- device-tier decode speed (ISSUE 16) ---------------------------------
     ("Q-block ragged attention (fixed-q-block grid)",
      "paddle_tpu.ops.pallas.ragged_paged_attention",
-     ["qblock_schedule", "DEFAULT_QBLOCK", "ragged_paged_attention"]),
+     ["qblock_job_list", "job_buckets", "DEFAULT_QBLOCK",
+      "ragged_paged_attention"]),
     ("Int8 weight serving path (quantize + fused forward)",
      "paddle_tpu.quantization",
      ["quantize_linears", "int8_linear"]),
