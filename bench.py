@@ -1151,9 +1151,9 @@ def bench_serving():
             }
         return stats
 
-    def run_mixed(ragged):
-        """Ragged-vs-legacy variant: MIXED concurrent load (varied prompt
-        lengths, staggered arrivals) so prefill and decode contend for
+    def run_mixed():
+        """MIXED concurrent load (varied prompt lengths, staggered
+        arrivals) so prefill and decode contend for
         every tick — the regime the one-kernel token-budget scheduler
         (Ragged Paged Attention, arxiv 2604.15464) exists for."""
         mix_rng = np.random.default_rng(1)
@@ -1164,7 +1164,7 @@ def bench_serving():
         eng = ContinuousServingEngine(
             model, max_batch_size=4, max_len=max(lens) + new + 16,
             enable_prefix_cache=False, prefill_chunk_tokens=chunk,
-            token_budget=chunk, enable_ragged=ragged)
+            token_budget=chunk)
         with eng:
             eng.generate(mix[0], max_new_tokens=new, timeout=1800)  # warmup
             t0 = time.perf_counter()
@@ -1248,7 +1248,7 @@ def bench_serving():
         eng = ContinuousServingEngine(
             model, max_batch_size=4, max_len=max(lens) + new + 16,
             enable_prefix_cache=False, prefill_chunk_tokens=chunk,
-            token_budget=chunk, enable_ragged=True)
+            token_budget=chunk)
         warmup_s = sum(eng.warmup_programs().values())
         base = co.snapshot()["totals"]["misses"]
         with eng:
@@ -1506,8 +1506,7 @@ def bench_serving():
 
     off = run(False)
     on = run(True)
-    mixed_ragged = run_mixed(True)
-    mixed_legacy = run_mixed(False)
+    mixed_ragged = run_mixed()
     spec_on = run_spec(True)
     spec_off = run_spec(False)
     qblock = qblock_step_probe()
@@ -1522,16 +1521,12 @@ def bench_serving():
                 else None)
     kv_tier = run_kv_tier()
     long_ctx = run_long_context()
-    ragged_ratio = round(mixed_ragged["tokens_per_sec"]
-                         / max(mixed_legacy["tokens_per_sec"], 1e-9), 2)
     # latency percentiles + goodput from the request-trace SLO monitor
     # (every engine generate above fed it) — the bench trajectory's
     # first latency-percentile entries
     slo = rt.slo_report()
     aux = [
-        ("serving_ragged_tokens_per_s_ratio", ragged_ratio),
         ("serving_ragged_waste_ratio", mixed_ragged["waste_ratio"]),
-        ("serving_legacy_waste_ratio", mixed_legacy["waste_ratio"]),
         ("serving_p95_ttft_ms", round(slo["ttft"]["p95_s"] * 1e3, 2)),
         ("serving_p95_tpot_ms", round(slo["tpot"]["p95_s"] * 1e3, 2)),
         ("serving_goodput_ratio", round(slo["goodput_ratio"], 3)),
@@ -1580,12 +1575,9 @@ def bench_serving():
         "prefix_hits": on["prefix_hits"],
         "prefix_cached_tokens": on["cached_tokens"],
         "serving_token_digest": on["token_digest"],
-        # ragged-vs-legacy under mixed concurrent prefill+decode load
-        "serving_ragged_tokens_per_s_ratio": ragged_ratio,
+        # mixed concurrent prefill+decode load
         "ragged_tokens_per_sec": round(mixed_ragged["tokens_per_sec"], 2),
-        "legacy_tokens_per_sec": round(mixed_legacy["tokens_per_sec"], 2),
         "ragged_waste_ratio": mixed_ragged["waste_ratio"],
-        "legacy_waste_ratio": mixed_legacy["waste_ratio"],
         "ragged_buckets": mixed_ragged["buckets"],
         # speculative decode on-vs-off (self-draft upper bound)
         "serving_spec_tpot_speedup": spec_speedup,

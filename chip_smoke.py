@@ -114,8 +114,6 @@ class CompileWatch:
 _ATTENTION_IMPLS = {
     "ragged q-block (Pallas)": ("ragged_paged_attention",
                                 "_ragged_paged_attention_pallas_qblock"),
-    "ragged per-token (Pallas)": ("ragged_paged_attention",
-                                  "_ragged_paged_attention_pallas"),
     "ragged XLA tier": ("ragged_paged_attention",
                         "_ragged_paged_attention_xla"),
     "paged decode (Pallas)": ("paged_attention", "_paged_attention_pallas"),

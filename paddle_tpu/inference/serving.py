@@ -44,23 +44,12 @@ DEFAULT_SEP_STRIPE_TOKENS = 512
 _TELEMETRY = None      # lazily bound registry families
 
 
-def _chunk_bucket(n_valid, cap):
-    """Pad a prefill chunk to the next power-of-two bucket (min 8, capped
-    at the chunk budget) so the engine runs a BOUNDED set of compiled
-    prefill programs — {8, 16, ..., cap} plus the decode step — instead
-    of one program per prompt length."""
-    b = 8
-    while b < n_valid:
-        b *= 2
-    return min(b, max(int(cap), 1)) if n_valid <= cap else int(cap)
-
-
 def _token_bucket(n, cap):
     """Pad a ragged tick's packed token batch to the next power of two
-    (min 1, capped at the token budget). Unlike the chunk buckets there
-    is no floor of 8: a decode-only tick with two live slots runs a
-    2-token program, not an 8-token one — padded-token waste on
-    decode-heavy ticks is what the ragged scheduler exists to remove."""
+    (min 1, capped at the token budget). There is no floor: a
+    decode-only tick with two live slots runs a 2-token program — padded-
+    token waste on decode-heavy ticks is what the ragged scheduler
+    exists to remove."""
     b = 1
     while b < n:
         b *= 2
@@ -89,7 +78,7 @@ def _telemetry():
                                 labels=("engine",)),
             "decode_step": r.histogram(
                 "paddle_serving_decode_step_seconds",
-                "one fixed-shape decode step over all active slots"),
+                "one tick that carried decode tokens (ragged or sep)"),
             "token": r.histogram(
                 "paddle_serving_token_latency_seconds",
                 "per-token decode latency (step time / active slots)"),
@@ -213,8 +202,6 @@ def _engine_state(engine) -> dict:
         state["request_ages"] = ages[:8]
     else:
         state["oldest_request_age_s"] = 0.0
-    if getattr(engine, "enable_ragged", None) is not None:
-        state["ragged"] = engine.enable_ragged
     if getattr(engine, "enable_spec", None) is not None:
         state["spec_decode"] = engine.enable_spec
     if getattr(engine, "draft_batch", None) is not None:
@@ -642,31 +629,22 @@ class ContinuousServingEngine:
     TPU-native scheduling: admission is NON-BLOCKING — it only maps a
     request onto a free slot of a :class:`SlotPagedKVCache` (prompt
     blocks that hit the prefix index reuse already-filled pages with no
-    model work at all); the uncached prompt suffix then prefills in
-    fixed-bucket chunks of at most ``prefill_chunk_tokens``, with a
-    ``[max_batch, 1]`` decode step interleaved between chunks so a long
-    prompt never head-of-line-blocks active decodes. Sequences of
-    different prompt lengths and decode budgets share every step, a
-    finished sequence's slot is reused immediately, and the engine runs
-    a bounded set of compiled programs (the power-of-two chunk buckets
-    plus the fixed-shape decode step).
+    model work at all). Each tick then packs up to ``token_budget``
+    tokens into ONE flat batch — every live decode slot's single token
+    plus as many prefill tokens of the uncached prompt suffixes as fit
+    (per-span cap ``prefill_chunk_tokens``) — and runs them through the
+    single ragged paged-attention program family (Ragged Paged
+    Attention, arxiv 2604.15464), so a long prompt never
+    head-of-line-blocks active decodes. Sequences of different prompt
+    lengths and decode budgets share every step, a finished sequence's
+    slot is reused immediately, and the batch is padded to a bounded
+    bucket set, so the whole mixed prefill+decode workload compiles a
+    small fixed family of programs.
 
     engine = ContinuousServingEngine(model, max_batch_size=8)
     engine.start()
     out = engine.generate(prompt_ids, max_new_tokens=64)   # blocks
     engine.stop()
-
-    **Ragged continuous batching (default).** Each tick packs up to
-    ``token_budget`` tokens into ONE flat batch — every live decode
-    slot's single token plus as many prefill tokens as fit (per-span cap
-    ``prefill_chunk_tokens``) — and runs them through the single ragged
-    paged-attention program family (Ragged Paged Attention, arxiv
-    2604.15464). The batch is padded to a bounded bucket set, so the
-    whole mixed prefill+decode workload compiles a small fixed family of
-    programs and decode liveness no longer trades against the prefill
-    chunk budget. ``PADDLE_SERVING_RAGGED=0`` / ``enable_ragged=False``
-    restores the legacy two-program scheduler (one prefill chunk + one
-    fixed-shape decode step per tick).
 
     Prefix caching defaults on; disable with ``enable_prefix_cache=False``
     or ``PADDLE_SERVING_PREFIX_CACHE=0`` (legacy per-request prefill
@@ -680,7 +658,7 @@ class ContinuousServingEngine:
     def __init__(self, model, max_batch_size=8, page_size=16, max_len=2048,
                  pad_token_id=0, prefill_chunk_tokens=None,
                  enable_prefix_cache=None, num_pages=None,
-                 token_budget=None, enable_ragged=None, kv_dtype=None,
+                 token_budget=None, kv_dtype=None,
                  spec_decode=None, spec_k=None, drafter=None,
                  draft_model=None, weight_dtype=None, draft_batch=None,
                  host_pool_mb=None, sep_prefill=None,
@@ -715,10 +693,6 @@ class ContinuousServingEngine:
                 "PADDLE_SERVING_CHUNK_TOKENS",
                 str(DEFAULT_PREFILL_CHUNK_TOKENS)))
         self.chunk_tokens = max(int(prefill_chunk_tokens), 1)
-        if enable_ragged is None:
-            enable_ragged = os.environ.get(
-                "PADDLE_SERVING_RAGGED", "1") != "0"
-        self.enable_ragged = bool(enable_ragged)
         if token_budget is None:
             token_budget = int(os.environ.get(
                 "PADDLE_SERVING_TOKEN_BUDGET",
@@ -734,8 +708,7 @@ class ContinuousServingEngine:
         # up to spec_k tokens per live decode slot each tick; the ragged
         # forward verifies them as one q_len=k+1 span and the scheduler
         # keeps the longest matching prefix (greedy acceptance => output
-        # bit-identical to plain greedy). Requires the ragged scheduler —
-        # the legacy fixed-shape decode step has no multi-token span.
+        # bit-identical to plain greedy).
         if spec_decode is None:
             spec_decode = os.environ.get("PADDLE_SPEC_DECODE", "0") == "1"
         self.enable_spec = bool(spec_decode)
@@ -744,11 +717,6 @@ class ContinuousServingEngine:
             spec_k = int(os.environ.get("PADDLE_SPEC_K",
                                         str(DEFAULT_SPEC_K)))
         self.spec_k = max(int(spec_k), 1)
-        if self.enable_spec and not self.enable_ragged:
-            raise ValueError(
-                "speculative decoding needs the ragged scheduler "
-                "(enable_ragged=True / PADDLE_SERVING_RAGGED=1): "
-                "verification is a q_len=k+1 ragged span")
         self._drafter = None
         if self.enable_spec:
             if drafter is None:
@@ -795,10 +763,6 @@ class ContinuousServingEngine:
         self.sep_threshold = int(sep_threshold_tokens)
         self.sep_requests = 0
         if self.sep_prefill_enabled:
-            if not self.enable_ragged:
-                raise ValueError(
-                    "sep prefill needs the ragged scheduler "
-                    "(enable_ragged=True / PADDLE_SERVING_RAGGED=1)")
             if self.sep_stripe <= 0 or self.sep_stripe % self.page_size:
                 raise ValueError(
                     f"sep_stripe_tokens {self.sep_stripe} must be a "
@@ -842,17 +806,17 @@ class ContinuousServingEngine:
         self.model_counters: dict = {}
         self.ragged_prefill_tokens = 0
         self.ragged_decode_tokens = 0
-        # padded-vs-useful accounting for BOTH schedulers (the bench's
-        # waste-ratio metric): padded counts every token position a
-        # compiled program processed, useful only the real ones
+        # padded-vs-useful accounting (the bench's waste-ratio metric):
+        # padded counts every token position a compiled program
+        # processed, useful only the real ones
         self.padded_tokens_total = 0
         self.useful_tokens_total = 0
         #: bucket sizes actually compiled — the inventory guard asserts
         #: this stays inside :meth:`declared_token_buckets`
         self.ragged_buckets_used: set = set()
         # scheduling trace for liveness tests / debugging: ("chunk",
-        # slot, n_valid, done) and ("decode", n_active) events in order
-        # (the ragged scheduler emits both per packed tick)
+        # slot, n_valid, done) and ("decode", n_active) events in order,
+        # both per packed tick
         self.events: deque = deque(maxlen=4096)
         self._declare_programs()
 
@@ -881,29 +845,13 @@ class ContinuousServingEngine:
         reach (``max_batch_size`` sequences of at most ``max_len`` tokens,
         pages shared or not), which :meth:`warmup_programs` compiles.
         ``latent``: the ladder of a latent (one-pool) layer's kernel,
-        whose lists are padded to powers of two from 64. Empty where the
-        per-token grid runs
-        (``PADDLE_TPU_RAGGED_IMPL=token`` / ``xla``): one program a token
-        bucket, which the warm-up forward meets."""
+        whose lists are padded to powers of two from 64."""
         from ..ops.pallas.ragged_paged_attention import (
-            _qblock_eligible, _qblock_rows, _ragged_impl, job_buckets)
-        if not self.enable_ragged or not _qblock_eligible(_ragged_impl()):
-            return {}
+            _qblock_rows, job_buckets)
         pages_per_seq = -(-self.max_len // self.page_size)
         return {b: job_buckets(b, _qblock_rows(), self.max_batch,
                                pages_per_seq, latent=latent)
                 for b in sorted(self.declared_token_buckets())}
-
-    def declared_chunk_buckets(self):
-        """The legacy prefill path's compiled-shape family: every chunk
-        pads to one of these widths (:func:`_chunk_bucket`, pow2 min 8
-        capped at ``chunk_tokens``)."""
-        out, b = set(), 8
-        while b < self.chunk_tokens:
-            out.add(b)
-            b *= 2
-        out.add(self.chunk_tokens)
-        return out
 
     def declared_draft_buckets(self):
         """The batched drafter's compiled-shape family: (rows, width)
@@ -949,16 +897,6 @@ class ContinuousServingEngine:
     def _kernel_signature(self, padded, jobs):
         sig = {"tokens": _co.tensor_arg((int(padded),), "int64"),
                "jobs": _co.tensor_arg((int(jobs),), "int32")}
-        sig.update(self._static_args())
-        return sig
-
-    def _chunk_signature(self, padded):
-        sig = {"tokens": _co.tensor_arg((int(padded),), "int64")}
-        sig.update(self._static_args())
-        return sig
-
-    def _decode_signature(self):
-        sig = {"tokens": _co.tensor_arg((self.max_batch, 1), "int64")}
         sig.update(self._static_args())
         return sig
 
@@ -1011,32 +949,21 @@ class ContinuousServingEngine:
             eng = ref()
             return eng.warmup_programs(families=names) if eng else {}
 
-        if self.enable_ragged:
-            _co.declare_family(
-                "serving.ragged",
-                buckets={"tokens": sorted(self.declared_token_buckets())},
-                warmup=lambda: warm(("serving.ragged",)))
-            kernel = self.declared_kernel_buckets()
-            if kernel:
-                # both ladders: which kind of layer the model has shows
-                # only once a forward has built its pools
-                ladders = (kernel, self.declared_kernel_buckets(latent=True))
-                _co.declare_family(
-                    "serving.ragged_attention",
-                    buckets={"tokens": sorted(kernel),
-                             "jobs": sorted({j for fam in ladders
-                                             for js in fam.values()
-                                             for j in js})},
-                    warmup=lambda: warm(("serving.ragged_attention",)))
-        else:
-            _co.declare_family(
-                "serving.prefill_chunk",
-                buckets={"tokens": sorted(self.declared_chunk_buckets())},
-                warmup=lambda: warm(("serving.prefill_chunk",)))
-            _co.declare_family(
-                "serving.decode",
-                buckets={"tokens": [self.max_batch]},
-                warmup=lambda: warm(("serving.decode",)))
+        _co.declare_family(
+            "serving.ragged",
+            buckets={"tokens": sorted(self.declared_token_buckets())},
+            warmup=lambda: warm(("serving.ragged",)))
+        # both ladders: which kind of layer the model has shows only once
+        # a forward has built its pools
+        kernel = self.declared_kernel_buckets()
+        ladders = (kernel, self.declared_kernel_buckets(latent=True))
+        _co.declare_family(
+            "serving.ragged_attention",
+            buckets={"tokens": sorted(kernel),
+                     "jobs": sorted({j for fam in ladders
+                                     for js in fam.values()
+                                     for j in js})},
+            warmup=lambda: warm(("serving.ragged_attention",)))
         draft = self.declared_draft_buckets()
         if draft is not None:
             rows, widths = draft
@@ -1087,9 +1014,8 @@ class ContinuousServingEngine:
                     max_len=self.max_len, num_pages=self.num_pages,
                     enable_prefix_cache=False, kv_dtype=self.kv_dtype,
                     allow_page_overcommit=self.sep_prefill_enabled)
-                kernel = bool(want("serving.ragged_attention")
-                              and self.declared_kernel_buckets())
-                if self.enable_ragged and (want("serving.ragged") or kernel):
+                kernel = want("serving.ragged_attention")
+                if want("serving.ragged") or kernel:
                     t0 = time.perf_counter()
                     t_kernel = 0.0
                     for b in sorted(self.declared_token_buckets()):
@@ -1116,41 +1042,6 @@ class ContinuousServingEngine:
                             time.perf_counter() - t0 - t_kernel
                     if kernel:
                         out["serving.ragged_attention"] = t_kernel
-                if not self.enable_ragged and want("serving.prefill_chunk"):
-                    t0 = time.perf_counter()
-                    for b in sorted(self.declared_chunk_buckets()):
-                        cache.assign(0, np.zeros(1, np.int64))
-                        cache.begin_prefill(0, 1)
-                        chunk = np.full(b, self.pad_token_id, np.int64)
-                        pos = np.zeros(b, np.int32)
-                        t_run = time.perf_counter()
-                        self.model.forward(Tensor(chunk[None]), cache=cache,
-                                           position_ids=pos)
-                        _co.observe("serving.prefill_chunk",
-                                    self._chunk_signature(b),
-                                    seconds=time.perf_counter() - t_run)
-                        cache.free(0)
-                    out["serving.prefill_chunk"] = time.perf_counter() - t0
-                if not self.enable_ragged and want("serving.decode"):
-                    t0 = time.perf_counter()
-                    cache.assign(0, np.zeros(1, np.int64))
-                    cache.begin_prefill(0, 1)
-                    self.model.forward(
-                        Tensor(np.zeros((1, 8), np.int64)), cache=cache,
-                        position_ids=np.zeros(8, np.int32))
-                    mask = np.zeros(self.max_batch, bool)
-                    mask[0] = True
-                    cache.begin_decode(mask)
-                    cur = np.full((self.max_batch, 1), self.pad_token_id,
-                                  np.int64)
-                    pos = cache.lens.astype(np.int32)[:, None]
-                    t_run = time.perf_counter()
-                    self.model.forward(Tensor(cur), cache=cache,
-                                       position_ids=pos)
-                    _co.observe("serving.decode", self._decode_signature(),
-                                seconds=time.perf_counter() - t_run)
-                    cache.free(0)
-                    out["serving.decode"] = time.perf_counter() - t0
                 draft = self.declared_draft_buckets()
                 if draft is not None and want("spec.draft_batch"):
                     rows, widths = draft
@@ -1235,7 +1126,7 @@ class ContinuousServingEngine:
                     n = 2 * self.page_size
                     prompt = np.zeros(n, np.int64)
                     hcache.assign(0, prompt)
-                    hcache.begin_prefill(0, n)
+                    hcache.begin_ragged([(0, 0, n)])
                     self.model.forward(
                         Tensor(prompt[None]), cache=hcache,
                         position_ids=np.arange(n, dtype=np.int32))
@@ -1383,64 +1274,6 @@ class ContinuousServingEngine:
             prefill_q.append(slot)
             self.prefills += 1
 
-    def _prefill_chunk(self, cache, free, active, prefill_q):
-        """Run ONE fixed-bucket prefill chunk for the longest-waiting
-        mid-prefill slot. On the final chunk, sample the first token and
-        hand the row to the decode path; the prompt's full blocks are
-        registered in the prefix index for later reuse."""
-        from ..models.generation import _sample_logits
-        tele = _telemetry()
-        slot = prefill_q[0]
-        row = active[slot]
-        start = int(cache.lens[slot])
-        n_valid = min(self.chunk_tokens, row.prompt.shape[0] - start)
-        # the padded shape comes ONLY from the fixed bucket set — never
-        # clamped to max_len - start, which would compile a dedicated
-        # program per request tail (pad positions past the slot's page
-        # table scatter to the scratch page, so over-padding is safe)
-        padded = _chunk_bucket(n_valid, self.chunk_tokens)
-        chunk = np.full(padded, self.pad_token_id, row.prompt.dtype)
-        chunk[:n_valid] = row.prompt[start:start + n_valid]
-        # pad positions clip to the last valid position (their rope /
-        # K/V output is garbage and discarded; clipping keeps them
-        # inside the model's rope table)
-        pos = np.minimum(np.arange(start, start + padded, dtype=np.int32),
-                         start + n_valid - 1)
-        cache.begin_prefill(slot, n_valid)
-        t_chunk = time.perf_counter()
-        logits = self.model.forward(Tensor(chunk[None]), cache=cache,
-                                    position_ids=pos)
-        self.prefill_chunks += 1
-        self.padded_tokens_total += padded
-        self.useful_tokens_total += n_valid
-        tele["chunk_util"].observe(n_valid / max(padded, 1))
-        done = start + n_valid >= row.prompt.shape[0]
-        self.events.append(("chunk", slot, n_valid, done))
-        chunk_dt = time.perf_counter() - t_chunk
-        if _co.is_enabled():
-            ev = _co.observe("serving.prefill_chunk",
-                             self._chunk_signature(padded),
-                             seconds=chunk_dt)
-            if ev is not None and ev["miss"]:
-                _rt.add_span(row.req.trace, "compile", t0=t_chunk,
-                             dur=chunk_dt, family="serving.prefill_chunk",
-                             cause=ev["cause"])
-        _rt.add_span(row.req.trace, "prefill_chunk", t0=t_chunk,
-                     dur=chunk_dt, slot=slot,
-                     tokens=n_valid, start=start, last=done)
-        if not done:
-            return
-        prefill_q.popleft()
-        cache.commit_prefix(slot)
-        kw = row.req.kwargs
-        nxt = int(np.asarray(_sample_logits(
-            logits._data[:, n_valid - 1].astype(jnp.float32),
-            kw.get("do_sample", False), kw.get("top_k", 0),
-            kw.get("top_p", 1.0), kw.get("temperature", 1.0),
-            key=self._row_key(row, len(row.generated))))[0])
-        row.state = "decode"
-        self._push_token(cache, free, active, slot, nxt)
-
     def _push_token(self, cache, free, active, slot, token):
         row = active[slot]
         row.generated.append(token)
@@ -1487,7 +1320,7 @@ class ContinuousServingEngine:
     def _serve(self):
         from ..autograd.tape import no_grad
         with no_grad():
-            self._serve_impl()
+            self._serve_ragged()
 
     def _new_cache(self):
         from ..models.generation import SlotPagedKVCache
@@ -1559,17 +1392,12 @@ class ContinuousServingEngine:
                 jax.random.key(int(seed)), row.row_idx)
         return jax.random.fold_in(row._key_base, int(token_idx))
 
-    def _serve_impl(self):
-        if self.enable_ragged:
-            return self._serve_ragged()
-        return self._serve_legacy()
-
     def _serve_ragged(self):
         """Token-budget continuous batching: ONE ragged forward per tick
         covering every live decode slot's token plus as many prefill
         tokens as fit in ``token_budget`` (per-span cap
         ``chunk_tokens``), padded to the fixed bucket set — the single
-        ragged program family replaces the legacy chunk+decode pair."""
+        ragged program family."""
         from ..models.generation import _sample_logits
 
         was_training = self.model.training
@@ -1645,7 +1473,9 @@ class ContinuousServingEngine:
                 if not self._running and pending:
                     # stop(): un-admitted rows fail fast — including any
                     # already-admitted SIBLING rows of the same request
-                    # (the base engine's contract, see _serve_legacy)
+                    # (finishing them would be wasted work: the caller
+                    # already got the error). Fully-admitted requests
+                    # decode to completion (the base engine's contract).
                     dropped = {row.req for row in pending}
                     for row in pending:
                         row.req.error = RuntimeError("ServingEngine stopped")
@@ -2080,180 +1910,3 @@ class ContinuousServingEngine:
             kw.get("top_p", 1.0), kw.get("temperature", 1.0),
             key=self._row_key(row, len(row.generated))))[0])
         self._push_token(cache, free, active, slot, tok)
-
-    def _serve_legacy(self):
-        from ..models.generation import _sample_logits
-
-        was_training = self.model.training
-        self.model.eval()
-        try:
-            cache = self._new_cache()
-            free: deque = deque(range(self.max_batch))
-            active: list = [None] * self.max_batch
-            pending: deque = deque()
-            prefill_q: deque = deque()    # slots mid-prefill, FIFO
-
-            def enqueue(item):
-                """False = stop token; otherwise split into rows."""
-                if item is self._STOP or item is None:
-                    return False
-                if isinstance(item, _Control):
-                    item.run(self)       # tick boundary: scheduler-safe
-                    return True
-                item._rows = [_Row(item, row, i)
-                              for i, row in enumerate(item.ids)]
-                pending.extend(item._rows)
-                return True
-
-            def drop_slot(i):
-                active[i] = None
-                cache.free(i)
-                if i in prefill_q:
-                    prefill_q.remove(i)
-                free.append(i)
-
-            while True:
-                if self._aborted:
-                    # replica death (fleet abort()): no drain — every
-                    # queued and in-flight request fails NOW so callers
-                    # can requeue to a surviving replica
-                    err = RuntimeError("ServingEngine aborted")
-                    for row in list(pending) + [r for r in active
-                                                if r is not None]:
-                        _rt.add_event(row.req.trace, "engine_aborted",
-                                      engine=self._ENGINE)
-                        row.req.error = err
-                        row.req.done.set()
-                    break
-                draining = not self._running
-                if draining and all(r is None for r in active):
-                    break
-                # block only when idle; otherwise drain without waiting
-                if not draining and not pending and \
-                        all(r is None for r in active):
-                    if not enqueue(self._q.get()):
-                        self._running = False
-                        continue     # drain in-flight rows before exit
-                if not draining:
-                    try:
-                        while True:
-                            if not enqueue(self._q.get_nowait()):
-                                self._running = False
-                                break
-                    except queue.Empty:
-                        pass
-                if not self._running and pending:
-                    # stop(): un-admitted rows fail fast — including any
-                    # already-admitted SIBLING rows of the same request
-                    # (finishing them would be wasted work: the caller
-                    # already got the error). Fully-admitted requests
-                    # decode to completion (the base engine's contract).
-                    dropped = {row.req for row in pending}
-                    for row in pending:
-                        row.req.error = RuntimeError("ServingEngine stopped")
-                        row.req.done.set()
-                    pending.clear()
-                    for i, r in enumerate(active):
-                        if r is not None and r.req in dropped:
-                            drop_slot(i)
-                # cancellation sweep (step boundary): free slots/pages a
-                # timed-out client still holds
-                for i, r in enumerate(active):
-                    if r is not None and r.req.cancelled:
-                        r.done = True
-                        self.cancelled_rows += 1
-                        _rt.add_event(r.req.trace, "cancelled", slot=i,
-                                      engine=self._ENGINE)
-                        drop_slot(i)
-                tele = _telemetry()
-                try:
-                    if self._running:
-                        self._admit(cache, free, active, pending, prefill_q)
-                    # ONE prefill chunk per tick: a long prompt advances
-                    # chunk-by-chunk while decodes keep flowing below
-                    if prefill_q:
-                        self._prefill_chunk(cache, free, active, prefill_q)
-                    mask = np.asarray([r is not None and r.state == "decode"
-                                       for r in active])
-                    n_active = int(mask.sum())
-                    tele["active"].set(sum(r is not None for r in active))
-                    tele["free_slots"].set(len(free))
-                    tele["free_pages"].set(cache.free_page_count)
-                    tele["pool_occupancy"].set(
-                        cache.used_page_count / max(cache.num_pages - 1, 1))
-                    page_nb = cache.page_nbytes     # dtype-aware bytes
-                    tele["pool_bytes"].set(cache.used_page_count * page_nb,
-                                           kind="used")
-                    tele["pool_bytes"].set((cache.num_pages - 1) * page_nb,
-                                           kind="capacity")
-                    self._mirror_kv_tier(tele, cache)
-                    if not mask.any():
-                        continue
-                    t_step = time.perf_counter()
-                    # ONE fixed-shape decode step for every decoding slot
-                    cache.begin_decode(mask)
-                    cur = np.full((self.max_batch, 1), self.pad_token_id,
-                                  np.int64)
-                    for i, r in enumerate(active):
-                        if r is not None and r.state == "decode":
-                            cur[i, 0] = (r.generated[-1] if r.generated
-                                         else r.prompt[-1])
-                    pos = cache.lens.astype(np.int32)[:, None]
-                    logits = self.model.forward(Tensor(cur), cache=cache,
-                                                position_ids=pos)
-                    lg = logits._data[:, -1].astype(jnp.float32)
-                    self.decode_steps += 1
-                    # the fixed-shape decode step burns a token position
-                    # for every slot, live or not — the padding waste the
-                    # ragged scheduler exists to remove
-                    self.padded_tokens_total += self.max_batch
-                    self.useful_tokens_total += n_active
-                    self.events.append(("decode", n_active))
-                    step_dt = time.perf_counter() - t_step
-                    tele["decode_step"].observe(step_dt)
-                    # every active slot earned one token this step
-                    for _ in range(n_active):
-                        tele["token"].observe(step_dt / max(n_active, 1))
-                    compile_ev = None
-                    if _co.is_enabled():
-                        ev = _co.observe("serving.decode",
-                                         self._decode_signature(),
-                                         seconds=step_dt)
-                        if ev is not None and ev["miss"]:
-                            compile_ev = ev
-                    greedy = np.asarray(jnp.argmax(lg, axis=-1))
-                    for i, r in enumerate(list(active)):
-                        if r is None or r.state != "decode":
-                            continue
-                        if compile_ev is not None:
-                            _rt.add_span(r.req.trace, "compile", t0=t_step,
-                                         dur=step_dt,
-                                         family="serving.decode",
-                                         cause=compile_ev["cause"])
-                        _rt.add_span(r.req.trace, "decode", t0=t_step,
-                                     dur=step_dt, slot=i, tokens=1,
-                                     tick=self.decode_steps)
-                        kw = r.req.kwargs
-                        if kw.get("do_sample", False):
-                            tok = int(np.asarray(_sample_logits(
-                                lg[i:i + 1], True, kw.get("top_k", 0),
-                                kw.get("top_p", 1.0),
-                                kw.get("temperature", 1.0),
-                                key=self._row_key(r, len(r.generated))))[0])
-                        else:
-                            tok = int(greedy[i])
-                        self._push_token(cache, free, active, i, tok)
-                except Exception as e:      # fail everything in flight
-                    reqs = {r.req for r in pending}
-                    reqs |= {r.req for r in active if r is not None}
-                    for req in reqs:
-                        req.error = e
-                        req.done.set()
-                    pending.clear()
-                    prefill_q.clear()
-                    active = [None] * self.max_batch
-                    free = deque(range(self.max_batch))
-                    cache = self._new_cache()
-        finally:
-            if was_training:
-                self.model.train()

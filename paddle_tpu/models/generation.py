@@ -430,18 +430,19 @@ class SlotPagedKVCache:
     every slot here has its own context length and lifecycle: a slot is
     **assigned** a prompt on admission (leading full blocks that hit the
     hash-chained prefix index map straight onto already-filled pages —
-    refcount++, zero prefill work), **prefilled** in chunks for the
-    uncached suffix, participates in fixed-shape [max_batch, 1]
-    **decode** steps with its own position, and is **freed** on
-    completion (refcount--, pages return to the free list at zero). The
-    decode step's shape never changes, so the whole serve loop stays on
-    one compiled program while requests come and go.
+    refcount++, zero prefill work), takes part in **ragged** steps
+    (:meth:`begin_ragged`: prefill spans of the uncached suffix and
+    single decode tokens of many slots in one flat batch, each at its
+    own position), and is **freed** on completion (refcount--, pages
+    return to the free list at zero). The flat batch is padded to a
+    bounded bucket set, so the serve loop stays on a small family of
+    compiled programs while requests come and go.
 
     Pages are allocated from one free list shared by all slots; page 0
-    is a scratch page — the fixed-shape decode write of a free or
-    mid-prefill slot is steered there so it can never corrupt a page
-    another request owns. Writes into a shared page (refcount > 1 or
-    registered in the prefix index) trigger copy-on-write.
+    is a scratch page — the write of a bucket-padding token is steered
+    there so it can never corrupt a page a request owns. Writes into a
+    shared page (refcount > 1 or registered in the prefix index) trigger
+    copy-on-write.
     """
 
     def __init__(self, max_batch, page_size=16, max_len=2048,
@@ -488,10 +489,10 @@ class SlotPagedKVCache:
                                 np.int32)
         self._n_blocks = np.zeros(self.max_batch, np.int32)
         self.lens = np.zeros(self.max_batch, np.int32)   # filled ctx/slot
-        self._mode = None            # ("prefill", slot) | ("decode", mask)
+        self._mode = None            # ("ragged", spans) | ("sep_*", slot)
         self._idx = None             # per-forward index memo
         self._touched = None         # ... and ragged_touched_pages' own
-        self._prefill_valid = None   # real tokens in the current chunk
+        self._prefill_valid = None   # real tokens in a sep prefill chunk
         # prefix-cache statistics (mirrored into the telemetry registry
         # by the serving engine)
         self.prefix_hits = 0          # full blocks served from the index
@@ -804,25 +805,6 @@ class SlotPagedKVCache:
             self._ref[page] += 1          # the index's own reference
             registered += 1
         return registered
-
-    def begin_prefill(self, slot, n_valid=None):
-        """Arm the next forward as a prefill chunk for ``slot`` writing at
-        position ``lens[slot]``. ``n_valid`` is the number of REAL tokens
-        in the chunk when the engine pads it to a fixed bucket shape —
-        pad positions scatter to the scratch page and don't advance the
-        context."""
-        self._mode = ("prefill", int(slot))
-        self._idx = None             # per-forward index memo (see attend)
-        self._prefill_valid = None if n_valid is None else int(n_valid)
-
-    def begin_decode(self, active_mask):
-        mask = np.asarray(active_mask, bool)
-        self._mode = ("decode", mask)
-        self._idx = None
-        for i in np.nonzero(mask)[0]:
-            self._ensure_blocks(int(i), int(self.lens[i]) + 1)
-            self._make_writable(int(i),
-                                int(self.lens[i]) // self.page_size)
 
     def begin_ragged(self, spans):
         """Arm the next forward as ONE ragged mixed prefill+decode step
@@ -1166,16 +1148,13 @@ class SlotPagedKVCache:
         # models read cache.pos for default position ids; the engine
         # always passes explicit per-slot positions instead
         m = self._mode
-        if m and m[0] in ("prefill", "sep_prefill"):
+        if m and m[0] == "sep_prefill":
             return int(self.lens[m[1]])
         return 0
 
     def advance(self, s):
         mode, arg = self._mode
-        if mode == "prefill":
-            n = self._prefill_valid
-            self.lens[arg] += int(s) if n is None else min(int(s), n)
-        elif mode == "sep_prefill":
+        if mode == "sep_prefill":
             sep = self._sep[arg]
             n = self._prefill_valid
             n = int(s) if n is None else min(int(s), n)
@@ -1189,7 +1168,7 @@ class SlotPagedKVCache:
         elif mode == "ragged":
             for slot, _, n_new in arg:
                 self.lens[slot] += n_new
-        else:                   # "decode" mask or "sep_decode" slot
+        else:                   # "sep_decode" slot
             self.lens[arg] += 1
 
     def _pool(self, layer, kv_heads, d, dtype, latent=False):
@@ -1394,85 +1373,17 @@ class SlotPagedKVCache:
     # -- attention ----------------------------------------------------------
     def attend(self, layer, q, k, v, training=False, dropout_p=0.0):
         from ..autograd.tape import apply
-        from ..nn import functional as F
 
+        if self._mode is None:
+            raise RuntimeError(
+                "SlotPagedKVCache.attend on an unarmed cache: arm the "
+                "forward with begin_ragged (or begin_sep_prefill / "
+                "begin_sep_decode for a sep slot) first")
         mode, arg = self._mode
         ka = k._data if isinstance(k, Tensor) else k
         va = v._data if isinstance(v, Tensor) else v
         b, s, kv_heads, d = ka.shape
         k_pages, v_pages = self._pool(layer, kv_heads, d, ka.dtype)
-
-        if mode == "prefill":
-            assert b == 1, "prefill admits one request at a time"
-            slot = arg
-            start = int(self.lens[slot])
-            n_valid = s if self._prefill_valid is None \
-                else min(self._prefill_valid, s)
-            if start + n_valid > self.max_len:
-                raise ValueError(f"slot overflow: {start}+{n_valid} > "
-                                 f"{self.max_len}")
-            # NB: start + s (PADDED chunk) may exceed the slot's page
-            # table near max_len — pad positions scatter to the scratch
-            # page regardless, so the engine can keep every chunk shape
-            # inside its fixed bucket set instead of compiling a
-            # per-request tail shape
-            if self._idx is None:    # indices shared by every layer
-                self._ensure_blocks(slot, start + n_valid)
-                for blk in range(start // self.page_size,
-                                 -(-(start + n_valid) // self.page_size)):
-                    self._make_writable(slot, blk)
-                pos = np.arange(start, start + s)
-                valid = pos < start + n_valid
-                # pad positions scatter into the scratch page: their K/V
-                # is garbage and must never land in an allocatable page
-                blk_ids = np.minimum(pos // self.page_size,
-                                     self.pages_per_seq - 1)
-                self._idx = (
-                    jnp.asarray(np.where(valid,
-                                         self._tables[slot, blk_ids], 0)),
-                    jnp.asarray(np.where(valid, pos % self.page_size, 0)))
-            page_ids, slot_ids = self._idx
-            kt = jnp.moveaxis(ka[0], 1, 0)          # [kv, s, d]
-            vt = jnp.moveaxis(va[0], 1, 0)
-            new_kp, new_vp = self._scatter(layer, k_pages, v_pages, kt, vt,
-                                           page_ids, slot_ids)
-            if start > 0 or self.kv_quant:
-                # chunked / prefix-cached prefill: read the whole prefix
-                # back from the pages; sdpa's bottom-right causal
-                # alignment handles sq != sk. Table entries past the
-                # allocated blocks are the scratch page — those keys sit
-                # at pad positions and are never attended by valid
-                # queries. int8 pools ALWAYS read back (dequantized) so
-                # every attention consistently sees the quantized KV the
-                # later decode steps will see.
-                n_pages = min(-(-(start + s) // self.page_size),
-                              self.pages_per_seq)
-                tb = jnp.asarray(self._tables[slot, :n_pages])
-                kp_g, vp_g = new_kp[:, tb], new_vp[:, tb]
-                if self.kv_quant:
-                    ks, vs = self._scales[id(layer)]
-                    kp_g = dequantize_kv_rows(kp_g, ks[:, tb], ka.dtype)
-                    vp_g = dequantize_kv_rows(vp_g, vs[:, tb], va.dtype)
-                kf_flat = jnp.moveaxis(kp_g, 0, 2).reshape(
-                    n_pages * self.page_size, kv_heads, d)
-                vf_flat = jnp.moveaxis(vp_g, 0, 2).reshape(
-                    n_pages * self.page_size, kv_heads, d)
-                if n_pages * self.page_size < start + s:
-                    # bucket-padded chunk ran past the table: keep sdpa's
-                    # bottom-right causal alignment by zero-padding the
-                    # key axis — the extra rows sit past every valid
-                    # query's window, only pad queries (output discarded)
-                    # ever attend them
-                    pad = start + s - n_pages * self.page_size
-                    kf_flat = jnp.pad(kf_flat, ((0, pad), (0, 0), (0, 0)))
-                    vf_flat = jnp.pad(vf_flat, ((0, pad), (0, 0), (0, 0)))
-                kf = Tensor(kf_flat[None, :start + s])
-                vf = Tensor(vf_flat[None, :start + s])
-            else:
-                kf, vf = k, v
-            return F.scaled_dot_product_attention(
-                q, kf, vf, attn_mask=None, is_causal=True,
-                training=training)
 
         if mode in ("sep_prefill", "sep_decode"):
             # long-context serving: attention over the slot's host-side
@@ -1572,58 +1483,22 @@ class SlotPagedKVCache:
                 return jnp.swapaxes(out, 1, 2)
             return apply(fn, q, op_name="sep_ring_attention")
 
-        if mode == "ragged":
-            # ONE program for the whole tick: decode tokens and prefill
-            # spans of several sequences packed into a flat [1, tokens]
-            # batch (token-budget scheduler). K/V scatter first, then
-            # the ragged kernel reads every span's full context back
-            # from the pages — causal masking inside each span comes
-            # from the kernel's per-token context bound.
-            assert b == 1, "ragged step packs one flat token batch"
-            page_ids, slot_ids = self.ragged_scatter_ids(s)
-            kt = jnp.moveaxis(ka[0], 1, 0)          # [kv, s, d]
-            vt = jnp.moveaxis(va[0], 1, 0)
-            self._scatter(layer, k_pages, v_pages, kt, vt, page_ids,
-                          slot_ids)
-
-            def fn(qa):
-                return self.ragged_attention(layer, qa[0])[None]
-            return apply(fn, q, op_name="ragged_paged_attention")
-
-        # decode: one token for EVERY slot (fixed shape), per-slot ctx
-        assert b == self.max_batch and s == 1
-        if self._idx is None:        # indices shared by every layer
-            lens = self.lens.copy()
-            # inactive / mid-prefill slots still flow through the kernel
-            # (fixed shape) but their write is steered to the scratch
-            # page and their ctx=1 read covers only page 0 slot 0 —
-            # finite, discarded, and never a page someone else owns
-            wr_blk = np.minimum(lens // self.page_size,
-                                self.pages_per_seq - 1)
-            self._idx = (
-                jnp.asarray(np.where(
-                    arg, self._tables[np.arange(b), wr_blk], 0))[:, None],
-                jnp.asarray(np.where(arg, lens % self.page_size,
-                                     0))[:, None],
-                jnp.asarray(self._tables),
-                jnp.asarray(np.where(arg, lens + 1, 1).astype(np.int32)))
-        page_ids, slot_ids, tables, ctx = self._idx
-        kt = jnp.moveaxis(ka, 2, 0)                 # [kv, b, 1, d]
-        vt = jnp.moveaxis(va, 2, 0)
-        new_kp, new_vp = self._scatter(layer, k_pages, v_pages, kt, vt,
-                                       page_ids, slot_ids)
-        ksc, vsc = self._layer_scales(layer)
-
-        from ..ops.pallas.paged_attention import paged_attention
-        import jax as _jax
-        interpret = _jax.default_backend() != "tpu"
+        # ragged: ONE program for the whole tick: decode tokens and
+        # prefill spans of several sequences packed into a flat
+        # [1, tokens] batch (token-budget scheduler). K/V scatter first,
+        # then the ragged kernel reads every span's full context back
+        # from the pages — causal masking inside each span comes from
+        # the kernel's per-token context bound.
+        assert b == 1, "ragged step packs one flat token batch"
+        page_ids, slot_ids = self.ragged_scatter_ids(s)
+        kt = jnp.moveaxis(ka[0], 1, 0)          # [kv, s, d]
+        vt = jnp.moveaxis(va[0], 1, 0)
+        self._scatter(layer, k_pages, v_pages, kt, vt, page_ids,
+                      slot_ids)
 
         def fn(qa):
-            out = paged_attention(qa[:, 0], new_kp, new_vp, tables, ctx,
-                                  k_scales=ksc, v_scales=vsc,
-                                  interpret=interpret)
-            return out[:, None]
-        return apply(fn, q, op_name="paged_attention")
+            return self.ragged_attention(layer, qa[0])[None]
+        return apply(fn, q, op_name="ragged_paged_attention")
 
 
 def _sample_logits(logits, do_sample, top_k, top_p, temperature, key=None):

@@ -22,42 +22,33 @@ Tokens outside every span (bucket padding) attend one garbage key
 (page 0 slot 0, the pool's scratch page) and their output is discarded
 by the caller — identical to the decode kernel's inactive-slot story.
 
-Tiers (``PADDLE_TPU_RAGGED_IMPL``), mirroring
-``ops/pallas/paged_attention.py``:
+One Pallas kernel and one XLA form, chosen by what the entry can
+observe:
 
-* ``auto`` / ``inrepo`` / ``qblock`` / ``token``: an in-repo kernel —
-  block-table-steered dynamic BlockSpec index maps (scalar prefetch in
+* concrete descriptors (the serving tick): the in-repo **q-block** kernel
+  — block-table-steered dynamic BlockSpec index maps (scalar prefetch in
   SMEM), online-softmax scratch accumulation, the decode kernel's
   streaming recurrence with per-TOKEN (not per-row) context bounds and
   table rows. Compiled by Mosaic on a TPU backend (a compiler error
   propagates), run in interpret mode by the CPU tests;
-* ``xla``: a plain-XLA gather+softmax, no kernel at all — only ever
-  reached through this explicit switch.
+* descriptors that are ``jit`` tracers: a plain-XLA gather+softmax
+  (:func:`_ragged_paged_attention_xla`), because the kernel's job list is
+  built on the host. It is also the tests' second reference.
 
-Two in-repo grids. The default **q-block** grid ``(jobs,)`` tiles the
-flat batch into fixed ``PADDLE_TPU_RAGGED_QBLOCK``-row blocks over the
-cumulative span offsets and walks ONE flat host-built job list in block
-order (:func:`qblock_job_list`: one (q-block, page, owner-slot, kv-offset)
-per KV page any sequence in the block needs; the grid's bound is the
-list's own length, read on the device) —
-one grid step covers a whole block of tokens, every KV head of it,
-against one page, so a mixed tick runs far fewer, fatter MXU steps, and
-the grid holds the jobs that exist: a block's softmax state starts at
-its first job and its output is written at its last, and a job without
-an owner (a block of padding rows has one) skips the body. A block may
+The q-block grid ``(jobs,)`` tiles the flat batch into fixed
+``DEFAULT_QBLOCK``-row blocks over the cumulative span offsets and walks
+ONE flat host-built job list in block order (:func:`qblock_job_list`: one
+(q-block, page, owner-slot, kv-offset) per KV page any sequence in the
+block needs; the grid's bound is the list's own length, read on the
+device) — one grid step covers a whole block of tokens, every KV head of
+it, against one page, so a mixed tick runs few, fat MXU steps, and the
+grid holds the jobs that exist: a block's softmax state starts at its
+first job and its output is written at its last, and a job without an
+owner (a block of padding rows has one) skips the body. A block may
 straddle span boundaries: rows past a span's causal bound mask with
--inf exactly like the per-token kernel, and cross-span keys are steered
-out with a finite ``BIG_NEG`` so alien jobs are bitwise no-ops (see
-``BIG_NEG``). The latent (one pool, one KV head) kernel walks the same
-list. The historical **per-token** grid ``(tokens, kv_head,
-pages)`` remains as the escape hatch (``PADDLE_TPU_RAGGED_IMPL=token``)
-and is used automatically under jit tracing, where the q-block
-schedule's host-side job build cannot run. The two grids run the SAME
-online-softmax recurrence in the same per-row page order — the masking
-is an exact no-op on alien jobs, so outputs agree to ~1 ulp (the only
-reorder is the dot shape itself: ``[q_block*group, d]`` vs
-``[group, d]`` MXU tiles accumulate in different orders) and greedy
-token streams through the serving engine are bit-identical.
+-inf, and cross-span keys are steered out with a finite ``BIG_NEG`` so
+alien jobs are bitwise no-ops (see ``BIG_NEG``). The latent (one pool,
+one KV head) kernel walks the same list.
 
 Unused block-table entries MUST be 0 (a valid page): their scores are
 masked by the per-token context bound but the DMA address must be in
@@ -78,8 +69,8 @@ from ...profiler import spans as _spans
 from .paged_attention import NEG_INF, _device_call, _scale_rows
 
 #: finite cross-span mask for the q-block kernel. The causal bound keeps
-#: NEG_INF (= -inf, matching the per-token kernel bit for bit on a row's
-#: own pages); keys belonging to ANOTHER sequence's job must stay finite:
+#: NEG_INF (= -inf, the decode kernel's mask, on a row's own pages); keys
+#: belonging to ANOTHER sequence's job must stay finite:
 #: a row whose first visited job is alien would otherwise accumulate
 #: m = -inf and hit exp(-inf - -inf) = NaN, which no later correction
 #: can wash out. With -1e30, the first own-slot job's rescale factor
@@ -93,24 +84,18 @@ BIG_NEG = -1e30
 #: would ask Mosaic for a float32 product of bf16 operands, which it refuses
 _DEFAULT = jax.lax.Precision.DEFAULT
 
-#: default q-block rows (tokens per grid step); PADDLE_TPU_RAGGED_QBLOCK
+#: q-block rows (tokens per grid step)
 DEFAULT_QBLOCK = 8
 
 
 def _qblock_rows():
-    import os
-    try:
-        qb = int(os.environ.get("PADDLE_TPU_RAGGED_QBLOCK",
-                                str(DEFAULT_QBLOCK)))
-    except ValueError:
-        qb = DEFAULT_QBLOCK
-    return max(qb, 1)
+    return DEFAULT_QBLOCK
 
 
 def _token_descriptors(num_tokens, seq_slots, q_starts, q_lens,
                        context_lens):
     """Expand per-sequence ``(slot, q_start, q_len, context_len)``
-    descriptors into the per-token arrays the kernel grid consumes:
+    descriptors into the per-token arrays the XLA form consumes:
     ``tok_slot[t]`` (block-table row) and ``tok_ctx[t]`` (key positions
     visible to token ``t``). Padding tokens — outside every span — get
     ``(slot 0, ctx 1)``: one finite, discarded garbage score instead of
@@ -153,8 +138,7 @@ def job_bucket(total, latent=False):
         raise ValueError(
             f"a ragged attention call of {total} (q-block, KV page) jobs is "
             f"over the {MAX_JOBS} the q-block kernel's job list can hold: "
-            "lower the token budget or max_len, raise page_size, or run "
-            "the per-token grid (PADDLE_TPU_RAGGED_IMPL=token)")
+            "lower the token budget or max_len, or raise page_size")
     b, step = (LATENT_MIN_JOBS, 2) if latent else (MIN_JOBS, JOBS_STEP)
     while b < total:
         b *= step
@@ -207,8 +191,8 @@ def qblock_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
     ``blocks x the longest block's jobs``: 8 decode rows at 350 tokens of
     context are a block of 176 jobs, 8 rows of a prefill chunk one of 2 to
     90. Within a block the sequences stand in order of first appearance and
-    a sequence's pages ascend, so each row meets its own pages in exactly
-    the per-token kernel's order.
+    a sequence's pages ascend, so each row meets its own pages in
+    ascending order, as the decode kernel does.
 
     Sentinels: rows outside every span (bucket and block padding) get slot
     -1 / ctx 0 and own no job; a block without jobs gets one that matches
@@ -315,8 +299,8 @@ def latent_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
 
 
 def _qblock_masked_scores(s, kv_start, jslot, row_slot, row_ctx):
-    """Causal bound with NEG_INF (bitwise the per-token kernel's mask on
-    a row's own pages), then the whole row to finite BIG_NEG wherever
+    """Causal bound with NEG_INF (the decode kernel's mask, on a row's
+    own pages), then the whole row to finite BIG_NEG wherever
     the row's sequence does not own this job's page."""
     pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos < row_ctx, s, NEG_INF)
@@ -357,7 +341,7 @@ def _qblock_kernel(jobs_ref, rows_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
                    quant, unroll):
     """One grid step: a q-block's rows (``q_block`` tokens x all query
     heads) against one KV page, EVERY KV head of it: the page block is
-    ``[kv_heads, 1, page_size, d]`` and each head runs the per-token
+    ``[kv_heads, 1, page_size, d]`` and each head runs the decode
     kernel's two 2-D float32 products and its float32 softmax on its own
     ``[q_block*group, d]`` rows and its own slice of the scratch arrays
     (a loop over the heads, unrolled for the chip, so that the heads'
@@ -413,12 +397,11 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
                                           *, sm_scale, interpret,
                                           k_scales=None, v_scales=None,
                                           q_block=None, value_dim=None):
-    """Q-block tier: grid ``(jobs,)`` over the flat packed batch — one
-    grid step covers ``q_block`` tokens (all their heads) against one KV
-    page, and the grid is the list of such (q-block, page) jobs that exist
-    (:func:`qblock_job_list`), so a mixed prefill+decode tick runs far
-    fewer (and fatter) MXU steps than the per-token grid. Requires
-    concrete descriptors (the job list is built host-side).
+    """The q-block kernel's entry: grid ``(jobs,)`` over the flat packed
+    batch — one grid step covers ``q_block`` tokens (all their heads)
+    against one KV page, and the grid is the list of such (q-block, page)
+    jobs that exist (:func:`qblock_job_list`). Requires concrete
+    descriptors (the job list is built host-side).
 
     ``v_pages is None`` is the LATENT call: one pool of one KV head whose
     row a token holds keys and values alike (``value_dim``: the values are
@@ -624,212 +607,14 @@ def _latent_call(jobs, row_slot, row_ctx, q, kv_pages, sm_scale, interpret,
               value_dim, q_block)
 
 
-def _ragged_kernel(slots_ref, ctx_ref, tables_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_ref, l_ref, acc_ref, *, sm_scale, page_size,
-                   pages_per_seq, group):
-    t = pl.program_id(0)
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    ctx = ctx_ref[t]
-    q = q_ref[0, 0].astype(jnp.float32)            # [group, d]
-    k = k_ref[0, 0].astype(jnp.float32)            # [page_size, d]
-    v = v_ref[0, 0].astype(jnp.float32)
-    # s[g, ps] — one plain 2-D MXU dot per (token, head, page)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < ctx, s, NEG_INF)
-
-    m_prev = m_ref[...][:, :1]                     # [g, 1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    w = jnp.exp(s - m_new)                         # masked -> 0
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_ref[...][:, :1] * corr + jnp.sum(w, -1, keepdims=True)
-    pv = jax.lax.dot_general(                      # [g, d]
-        w, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(p == pages_per_seq - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...][:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _ragged_kernel_quant(slots_ref, ctx_ref, tables_ref, q_ref, k_ref,
-                         v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref,
-                         acc_ref, *, sm_scale, page_size, pages_per_seq,
-                         group):
-    """int8-KV variant of :func:`_ragged_kernel`: page blocks arrive as
-    int8 rows plus one fp32 scale per (page, slot) row (a
-    ``[1, page_size]`` lane vector scaling the scores / weights around
-    the dots, see ``_decode_kernel_quant``) — fp32 pages never exist in
-    HBM."""
-    t = pl.program_id(0)
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    ctx = ctx_ref[t]
-    q = q_ref[0, 0].astype(jnp.float32)            # [group, d]
-    k = k_ref[0, 0].astype(jnp.float32)            # [page_size, d]
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * (ks_ref[0, 0] * sm_scale)
-    pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < ctx, s, NEG_INF)
-
-    m_prev = m_ref[...][:, :1]                     # [g, 1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    w = jnp.exp(s - m_new)                         # masked -> 0
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_ref[...][:, :1] * corr + jnp.sum(w, -1, keepdims=True)
-    pv = jax.lax.dot_general(                      # [g, d]
-        w * vs_ref[0, 0], v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * corr + pv
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(p == pages_per_seq - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...][:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-@_device_call
-def _ragged_paged_attention_pallas_quant(q, k_pages, v_pages, k_scales,
-                                         v_scales, block_tables, tok_slot,
-                                         tok_ctx, *, sm_scale, interpret):
-    tokens, heads, d = q.shape
-    kv_heads, _, page_size, _ = k_pages.shape
-    pages_per_seq = block_tables.shape[1]
-    group = heads // kv_heads
-    qg = q.reshape(tokens, kv_heads, group, d)
-
-    kernel = functools.partial(
-        _ragged_kernel_quant, sm_scale=sm_scale, page_size=page_size,
-        pages_per_seq=pages_per_seq, group=group)
-    page_spec = pl.BlockSpec((1, 1, page_size, d),
-                             lambda t, h, p, slot, ctx, tbl:
-                             (h, tbl[slot[t], p], 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, 1, page_size),
-                              lambda t, h, p, slot, ctx, tbl:
-                              (h, tbl[slot[t], p], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(tokens, kv_heads, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, d),
-                         lambda t, h, p, slot, ctx, tbl: (t, h, 0, 0)),
-            page_spec, page_spec, scale_spec, scale_spec,
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, d),
-                               lambda t, h, p, slot, ctx, tbl: (t, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((tokens, kv_heads, group, d),
-                                       q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(jnp.asarray(tok_slot, jnp.int32), jnp.asarray(tok_ctx, jnp.int32),
-      jnp.asarray(block_tables, jnp.int32), qg, k_pages, v_pages,
-      _scale_rows(k_scales), _scale_rows(v_scales))
-    return out.reshape(tokens, heads, d)
-
-
-@_device_call
-def _ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
-                                   tok_slot, tok_ctx, *, sm_scale,
-                                   interpret):
-    tokens, heads, d = q.shape
-    kv_heads, _, page_size, _ = k_pages.shape
-    pages_per_seq = block_tables.shape[1]
-    group = heads // kv_heads
-    qg = q.reshape(tokens, kv_heads, group, d)
-
-    kernel = functools.partial(
-        _ragged_kernel, sm_scale=sm_scale, page_size=page_size,
-        pages_per_seq=pages_per_seq, group=group)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(tokens, kv_heads, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, 1, group, d),
-                         lambda t, h, p, slot, ctx, tbl: (t, h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d),
-                         lambda t, h, p, slot, ctx, tbl:
-                         (h, tbl[slot[t], p], 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d),
-                         lambda t, h, p, slot, ctx, tbl:
-                         (h, tbl[slot[t], p], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, d),
-                               lambda t, h, p, slot, ctx, tbl: (t, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((tokens, kv_heads, group, d),
-                                       q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(jnp.asarray(tok_slot, jnp.int32), jnp.asarray(tok_ctx, jnp.int32),
-      jnp.asarray(block_tables, jnp.int32), qg, k_pages, v_pages)
-    return out.reshape(tokens, heads, d)
-
-
-def _ragged_impl():
-    import os
-    return os.environ.get("PADDLE_TPU_RAGGED_IMPL", "auto").lower()
-
-
-def _qblock_eligible(impl, *values):
-    """The q-block schedule is built host-side, so it needs concrete
-    descriptor/block-table values — under jit tracing the per-token grid
-    (whose index maps trace fine) is the escape hatch."""
-    if impl in ("token", "pertoken", "xla"):
-        return False
-    return not any(isinstance(v, jax.core.Tracer) for v in values)
-
-
 def _ragged_paged_attention_xla(q, k_pages, v_pages, block_tables,
                                 tok_slot, tok_ctx, *, sm_scale,
                                 k_scales=None, v_scales=None):
     """Vectorized jittable XLA tier: gather each token's sequence pages
     as dense KV (dequantized when int8 row scales are given), then
-    masked softmax-attention. O(tokens * S_max) HBM — the explicit
-    ``PADDLE_TPU_RAGGED_IMPL=xla`` tier."""
+    masked softmax-attention. O(tokens * S_max) HBM: what runs where the
+    descriptors are tracers (the q-block kernel's job list is built on
+    the host), and the tests' second reference."""
     kv_heads, _, page_size, d = k_pages.shape
     tokens, heads, _ = q.shape
     group = heads // kv_heads
@@ -863,8 +648,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_slots,
     tokens are its columns) with ``v_pages=None``: every query head attends
     the one row a token, whose first ``value_dim`` values are also the
     values; the result is
-    ``[tokens, heads, value_dim]``. It runs on the q-block tier only
-    (concrete descriptors).
+    ``[tokens, heads, value_dim]``. It is read by the q-block kernel
+    alone (concrete descriptors).
 
     q               [tokens, heads, head_dim] — the flat packed batch
     k_pages/v_pages [kv_heads, num_pages, page_size, head_dim]
@@ -878,41 +663,36 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_slots,
     k_scales/v_scales [kv_heads, num_pages, page_size] f32 — per-row
                     dequant scales for int8 pages (None = native pages)
     -> [tokens, heads, head_dim]; rows outside every span are garbage.
+
+    Concrete descriptors and block tables run the q-block kernel, whose
+    job list is built on the host; under ``jit`` tracing they are tracers
+    and the jittable XLA form answers instead.
     """
     tokens, heads, d = q.shape
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
-    impl = _ragged_impl()
-    eligible = _qblock_eligible(impl, seq_slots, q_starts, q_lens,
-                                context_lens, block_tables)
+    traced = any(isinstance(v, jax.core.Tracer) for v in (
+        seq_slots, q_starts, q_lens, context_lens, block_tables))
     if v_pages is None:
-        if not eligible or value_dim is None or k_scales is not None:
+        if traced or value_dim is None or k_scales is not None:
             raise NotImplementedError(
                 "a latent pool is read by the q-block kernel alone: "
-                "concrete descriptors, a value_dim, native pages "
-                f"(PADDLE_TPU_RAGGED_IMPL={impl!r})")
+                "concrete descriptors (not jit tracers), a value_dim, "
+                "native pages")
         return _ragged_paged_attention_pallas_qblock(
             q, k_pages, None, block_tables, seq_slots, q_starts, q_lens,
             context_lens, sm_scale=sm_scale, interpret=interpret,
             value_dim=int(value_dim))
-    if eligible:
+    if not traced:
         return _ragged_paged_attention_pallas_qblock(
             q, k_pages, v_pages, block_tables, seq_slots, q_starts, q_lens,
             context_lens, sm_scale=sm_scale, interpret=interpret,
             k_scales=k_scales, v_scales=v_scales)
     tok_slot, tok_ctx = _token_descriptors(tokens, seq_slots, q_starts,
                                            q_lens, context_lens)
-    if impl == "xla":
-        return _ragged_paged_attention_xla(
-            q, k_pages, v_pages, block_tables, tok_slot, tok_ctx,
-            sm_scale=sm_scale, k_scales=k_scales, v_scales=v_scales)
-    if k_scales is not None:
-        return _ragged_paged_attention_pallas_quant(
-            q, k_pages, v_pages, k_scales, v_scales, block_tables,
-            tok_slot, tok_ctx, sm_scale=sm_scale, interpret=interpret)
-    return _ragged_paged_attention_pallas(
+    return _ragged_paged_attention_xla(
         q, k_pages, v_pages, block_tables, tok_slot, tok_ctx,
-        sm_scale=sm_scale, interpret=interpret)
+        sm_scale=sm_scale, k_scales=k_scales, v_scales=v_scales)
 
 
 def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from .flash_attention import flash_attention_with_lse, mha_reference, NEG_INF
 
-#: PADDLE_SEP_RING_IMPL values (mirrors PADDLE_TPU_RAGGED_IMPL): "auto"
+#: PADDLE_SEP_RING_IMPL values (as PADDLE_TPU_PAGED_IMPL's): "auto"
 #: and "kernel" run the Pallas flash kernel (Mosaic on a TPU backend,
 #: interpret mode off it); "xla" forces the pure reference.
 SEP_RING_IMPLS = ("auto", "kernel", "xla")

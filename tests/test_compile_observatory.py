@@ -393,10 +393,9 @@ def test_spec_draft_replay_zero_post_warmup_misses(model):
     assert snap["families"]["spec.draft_batch"]["hits"] > 0
 
 
-def test_qblock_replay_zero_post_warmup_misses(model, monkeypatch):
-    """The q-block ragged grid serves the same declared token-bucket
-    family: warm replay is miss-free there too."""
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_IMPL", "qblock")
+def test_qblock_replay_zero_post_warmup_misses(model):
+    """The q-block ragged grid serves the declared token-bucket family:
+    warm replay is miss-free."""
     eng = ContinuousServingEngine(model, **ENGINE_KW)
     snap = _zero_miss_replay(eng, _prompts((23, 5), seed=2), 3)
     assert eng.ragged_steps > 0

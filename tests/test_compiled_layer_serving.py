@@ -415,7 +415,7 @@ def pjit_names(jaxpr):
     return out
 
 
-def test_a_traced_layer_takes_the_eager_pieces(model, monkeypatch):
+def test_a_traced_layer_takes_the_eager_pieces(model):
     layer = model.llama.layers[0]
     fm = FunctionalModule(layer, training=False)
     hidden = jnp.ones((1, 8, model.config.hidden_size), jnp.float32)
@@ -436,7 +436,6 @@ def test_a_traced_layer_takes_the_eager_pieces(model, monkeypatch):
         return whole(p, [], jax.random.key(0), ids, cache=cache,
                      position_ids=pos)[0]
 
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_IMPL", "xla")   # a tier that traces
     jaxpr = jax.make_jaxpr(traced)(whole.param_arrays())
     assert cache.compiled_layer_calls == 0
     assert not {"pre_fn", "post_fn"} & set(pjit_names(jaxpr.jaxpr))

@@ -297,9 +297,9 @@ def test_the_latent_pool_is_one_array_a_layer(model, prompt):
     assert [len(arrs) for arrs in blob["layers"]] == [1, 1, 1]
     other = SlotPagedKVCache(2, page_size=8, max_len=64, num_pages=20)
     assert other.import_pages(blob) == 2
-    with pytest.raises(NotImplementedError, match="ragged"):
-        cache.begin_prefill(1)
-        model.forward(Tensor(prompt[None, :8]), cache=cache,
+    # a latent layer attends through an armed ragged step alone
+    with pytest.raises(NotImplementedError, match="begin_ragged"):
+        model.forward(Tensor(prompt[None, :8]), cache=other,
                       position_ids=np.arange(8, dtype=np.int32))
 
 

@@ -59,10 +59,12 @@ def _router(model, n=2, **kw):
     return ServingRouter(model, num_replicas=n, **kw)
 
 
-def _wait_engine_down(router, rid, timeout=5.0):
+def _wait_engine_down(router, rid, timeout=120.0):
     """Let a killed replica's abort finish winding down its serve loop
     (the controller's own guard skips a winding-down engine; tests step
-    deterministically so they wait here instead)."""
+    deterministically so they wait here instead). Returns as soon as the
+    thread is gone; the deadline only ends a hung run (5 s left a thread
+    starved under six busy xdist workers no room)."""
     eng = router._replica(rid).engine
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:

@@ -102,10 +102,9 @@ def test_serving_program_budget():
 
 def test_quantized_config_catalog():
     """Quantized-config guard (ISSUE 16): every device-tier decode-speed
-    knob (PADDLE_WEIGHT_DTYPE / PADDLE_TPU_RAGGED_QBLOCK /
-    PADDLE_SPEC_DRAFT_BATCH / PADDLE_TPU_RAGGED_IMPL / PADDLE_KV_DTYPE)
-    is documented in docs/*.md AND exercised by a test, and the
-    fully-int8 serving config (int8 weights + int8 KV pages on the
+    knob (PADDLE_WEIGHT_DTYPE / PADDLE_SPEC_DRAFT_BATCH /
+    PADDLE_KV_DTYPE) is documented in docs/*.md AND exercised by a test,
+    and the fully-int8 serving config (int8 weights + int8 KV pages on the
     q-block ragged grid) is bit-stable across two same-seed runs with a
     matching token digest."""
     from check_inventory import check_quantized_config

@@ -12,6 +12,7 @@ from paddle_tpu.inference.serving import _engine_state
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 from paddle_tpu.models.generation import (HostKVPool, SlotPagedKVCache,
                                           block_hash_chain)
+from kv_fill import write_rows
 from paddle_tpu.profiler.telemetry import metrics
 
 
@@ -45,11 +46,8 @@ def _prefill(cache, slot, toks, kv, rng, layer=None):
     start = int(cache.lens[slot])
     n = len(toks) - start
     q = rng.standard_normal((1, n, h, d)).astype(np.float32)
-    cache.begin_prefill(slot, n_valid=n)
-    cache.attend(layer, jnp.asarray(q),
-                 jnp.asarray(kv[0][:, start:start + n]),
-                 jnp.asarray(kv[1][:, start:start + n]))
-    cache.advance(n)
+    write_rows(cache, slot, layer, q, kv[0][:, start:start + n],
+               kv[1][:, start:start + n])
     cache.commit_prefix(slot)
     return start
 
@@ -174,11 +172,8 @@ def test_promoted_page_shared_then_written_cow():
         n = 12 - start
         t = np.asarray(toks[start:], np.float32)
         k = np.broadcast_to(t[None, :, None, None], (1, n, 1, 4)).copy()
-        cache.begin_prefill(slot, n_valid=n)
-        cache.attend(layer, jnp.asarray(np.zeros((1, n, 1, 4),
-                                                 np.float32)),
-                     jnp.asarray(k), jnp.asarray(k))
-        cache.advance(n)
+        write_rows(cache, slot, layer, np.zeros((1, n, 1, 4), np.float32),
+                   k, k)
         cache.commit_prefix(slot)
 
     fill(0)
@@ -197,10 +192,7 @@ def test_promoted_page_shared_then_written_cow():
     cache.lens[1] = 6
     t = np.asarray([100.0, 101.0], np.float32)
     k = np.broadcast_to(t[None, :, None, None], (1, 2, 1, 4)).copy()
-    cache.begin_prefill(1, n_valid=2)
-    cache.attend(layer, jnp.asarray(np.zeros((1, 2, 1, 4), np.float32)),
-                 jnp.asarray(k), jnp.asarray(k))
-    cache.advance(2)
+    write_rows(cache, 1, layer, np.zeros((1, 2, 1, 4), np.float32), k, k)
     assert cache.cow_copies == 1
     assert int(cache._tables[1, 1]) != shared
     assert int(cache._index[chain[1]]) == shared

@@ -12,6 +12,7 @@ from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 from paddle_tpu.models.generation import (SlotPagedKVCache, block_hash_chain,
                                           dequantize_kv_rows, kv_page_nbytes,
                                           quantize_kv_rows)
+from kv_fill import write_rows
 
 
 @pytest.fixture(scope="module")
@@ -132,7 +133,7 @@ def test_cache_attend_int8_close_to_native():
     for dtype in ("native", "int8"):
         cache = SlotPagedKVCache(2, page_size=8, max_len=64,
                                  kv_dtype=dtype)
-        # identical prefill chunk then one decode step
+        # identical prefill span then one decode token
         k = Tensor(jnp.asarray(np.random.RandomState(5)
                                .randn(1, 12, 2, 32), jnp.float32))
         v = Tensor(jnp.asarray(np.random.RandomState(6)
@@ -140,17 +141,15 @@ def test_cache_attend_int8_close_to_native():
         q = Tensor(jnp.asarray(np.random.RandomState(7)
                                .randn(1, 12, 4, 32), jnp.float32))
         cache.assign(0, np.arange(12))
-        cache.begin_prefill(0, 12)
-        out = cache.attend(layer, q, k, v)
-        cache.advance(12)
+        out = write_rows(cache, 0, layer, q, k, v)
         qd = Tensor(jnp.asarray(np.random.RandomState(8)
-                                .randn(2, 1, 4, 32), jnp.float32))
+                                .randn(1, 1, 4, 32), jnp.float32))
         kd = Tensor(jnp.asarray(np.random.RandomState(9)
-                                .randn(2, 1, 2, 32), jnp.float32))
+                                .randn(1, 1, 2, 32), jnp.float32))
         vd = Tensor(jnp.asarray(np.random.RandomState(10)
-                                .randn(2, 1, 2, 32), jnp.float32))
-        cache.begin_decode(np.asarray([True, False]))
-        dec = cache.attend(layer, qd, kd, vd)
+                                .randn(1, 1, 2, 32), jnp.float32))
+        dec = write_rows(cache, 0, layer, qd, kd, vd)
+        assert int(cache.lens[0]) == 13
         outs[dtype] = (np.asarray(out._data), np.asarray(dec._data))
     np.testing.assert_allclose(outs["int8"][0], outs["native"][0],
                                atol=8e-2)
@@ -326,9 +325,7 @@ def test_export_import_bf16_pool_dtype_guard():
         v = Tensor(jnp.asarray(rs.randn(1, 8, 2, 16), dtype))
         q = Tensor(jnp.asarray(rs.randn(1, 8, 4, 16), dtype))
         cache.assign(0, np.arange(8))
-        cache.begin_prefill(0, 8)
-        cache.attend(layer, q, k, v)
-        cache.advance(8)
+        write_rows(cache, 0, layer, q, k, v)
         cache.commit_prefix(0)
         return cache, layer
 

@@ -14,6 +14,7 @@ import paddle_tpu as paddle
 from paddle_tpu.inference import ContinuousServingEngine
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny
 from paddle_tpu.models.generation import SlotPagedKVCache, block_hash_chain
+from kv_fill import write_rows
 
 
 @pytest.fixture(scope="module")
@@ -111,15 +112,12 @@ def test_env_flag_disables_prefix_cache(model, monkeypatch):
 # ---------------------------------------------------------------------------
 
 def _write_tokens(cache, slot, layer, tokens):
-    """Push synthetic K/V for ``tokens`` through the prefill path (the
+    """Push synthetic K/V for ``tokens`` through a ragged span (the
     content is the token value broadcast, so page content is checkable)."""
     s = len(tokens)
     t = np.asarray(tokens, np.float32)
     k = np.broadcast_to(t[None, :, None, None], (1, s, 1, 4)).copy()
-    q = np.zeros((1, s, 1, 4), np.float32)
-    cache.begin_prefill(slot, s)
-    cache.attend(layer, jnp.asarray(q), jnp.asarray(k), jnp.asarray(k))
-    cache.advance(s)
+    write_rows(cache, slot, layer, np.zeros((1, s, 1, 4), np.float32), k, k)
 
 
 def test_refcount_and_cow_lifecycle():
@@ -348,13 +346,8 @@ def test_prefix_and_chunk_telemetry(model):
     snap = metrics()
     assert snap["paddle_serving_prefix_hits"]["series"][""] >= 2
     assert snap["paddle_serving_prefix_cached_tokens"]["series"][""] >= 32
-    # the ragged scheduler observes batch-level budget utilization; the
-    # legacy path observes per-chunk utilization
-    if eng.enable_ragged:
-        util = snap["paddle_serving_token_budget_utilization"]["series"][""]
-        assert util["count"] >= eng.ragged_steps > 0
-    else:
-        util = snap["paddle_serving_chunk_utilization"]["series"][""]
-        assert util["count"] >= eng.prefill_chunks > 0
+    # the scheduler observes batch-level budget utilization
+    util = snap["paddle_serving_token_budget_utilization"]["series"][""]
+    assert util["count"] >= eng.ragged_steps > 0
     assert "paddle_serving_page_pool_occupancy" in snap
     assert "paddle_serving_prefix_misses" in snap

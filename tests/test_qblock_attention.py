@@ -3,12 +3,11 @@ int8 weights end-to-end, and batched drafting.
 
 Three layers, one bar each:
 
-* the fixed-q-block ragged kernel replays the per-token kernel's exact
-  online-softmax recurrence on every descriptor layout (straddling
-  spans, pure decode, shared-prefix page aliasing, int8-KV pages,
-  padded tail blocks): outputs agree to ~1 ulp — the only reorder is
-  the MXU dot shape itself — and greedy token streams through the
-  engine are BIT-identical between the two grids;
+* the fixed-q-block ragged kernel agrees with the XLA form and the
+  dense oracle on every descriptor layout (straddling spans, pure
+  decode, shared-prefix page aliasing, int8-KV pages, padded tail
+  blocks), and greedy token streams through the engine are
+  BIT-identical to ``model.generate``;
 * ``quantize_linears`` + ``weight_dtype="int8"`` routes Linear forwards
   through the int8 GEMM and the fully-quantized serving config is
   bit-stable across same-seed runs (ledger token-stream attestation);
@@ -16,6 +15,7 @@ Three layers, one bar each:
   one padded forward per step, bit-identical to per-sequence
   ``propose``, inside a power-of-two compiled-program family.
 """
+import importlib
 import threading
 
 import numpy as np
@@ -33,12 +33,11 @@ from paddle_tpu.ops.pallas.ragged_paged_attention import (
     qblock_job_list, latent_job_list, job_bucket, job_buckets,
     warm_descriptors, MIN_JOBS, LATENT_MIN_JOBS, MAX_JOBS,
     DEFAULT_QBLOCK, _qblock_rows, _token_descriptors,
-    _ragged_paged_attention_pallas, _ragged_paged_attention_pallas_quant,
     _ragged_paged_attention_pallas_qblock, _ragged_paged_attention_xla)
 
 
 # ---------------------------------------------------------------------------
-# kernel parity: q-block grid vs per-token grid (bitwise) vs dense oracle
+# kernel parity: q-block grid vs the XLA form vs dense oracle
 # ---------------------------------------------------------------------------
 
 def _pool(nslots=4, pages_per_seq=4, page=8, kv_heads=2, d=32, seed=0):
@@ -53,22 +52,22 @@ def _pool(nslots=4, pages_per_seq=4, page=8, kv_heads=2, d=32, seed=0):
     return kp, vp, tbl
 
 
-#: q-block vs per-token kernel tolerance: the grids run the SAME
-#: recurrence in the same per-row page order, but the q-block MXU dot is
-#: [q_block*group, d] where the per-token dot is [group, d] — different
-#: tile shapes accumulate the d-reduction in different orders, worth ~1
-#: ulp (<1e-7 observed). A masking bug would be O(1), int8-KV error
-#: ~1e-2, so 1e-6 still proves the recurrence is the same one.
+#: tolerance between two runs of the SAME form (the XLA form eager and
+#: under jit): ~1 ulp of reordered float32 reductions. A masking bug
+#: would be O(1), int8-KV error ~1e-2.
 KERNEL_TOL = dict(rtol=1e-6, atol=1e-6)
+#: q-block kernel against the XLA form on the same pages (native or the
+#: same int8 rows and scales): an online softmax page by page against
+#: one softmax over the gathered context
+FORM_TOL = dict(rtol=2e-5, atol=2e-5)
 
 
 def _parity(layout, tokens=None, q_block=8, heads=4, d=32, seed=0,
             tbl_edit=None, quant=False, **pool):
-    """Run the SAME descriptors through the q-block and per-token
-    interpret kernels: span rows must agree to KERNEL_TOL (~1 ulp — the
-    q-block grid replays the per-token online-softmax recurrence
-    job-by-job in the same order; see KERNEL_TOL for why not bitwise)
-    and match the dense reference to float tolerance."""
+    """Run the SAME descriptors through the q-block interpret kernel and
+    the XLA form: span rows must agree to FORM_TOL (on int8 pages too:
+    both read the same rows and scales) and match the dense reference to
+    float (int8: quantization) tolerance."""
     kp, vp, tbl = _pool(nslots=max(x[0] for x in layout) + 1, d=d,
                         seed=seed, **pool)
     if tbl_edit is not None:
@@ -91,24 +90,23 @@ def _parity(layout, tokens=None, q_block=8, heads=4, d=32, seed=0,
             sm_scale=sm, interpret=True, k_scales=ks, v_scales=vs,
             q_block=q_block))
         ts, tc = _token_descriptors(T, seq_slots, q_starts, q_lens, ctx)
-        tok = np.asarray(_ragged_paged_attention_pallas_quant(
-            q, kq, vq, ks, vs, jnp.asarray(tbl), ts, tc,
-            sm_scale=sm, interpret=True))
+        tok = np.asarray(_ragged_paged_attention_xla(
+            q, kq, vq, jnp.asarray(tbl), ts, tc, sm_scale=sm,
+            k_scales=ks, v_scales=vs))
         ref_tol = dict(rtol=5e-2, atol=5e-2)    # int8 quantization error
     else:
         qb = np.asarray(_ragged_paged_attention_pallas_qblock(
             q, kp, vp, jnp.asarray(tbl), seq_slots, q_starts, q_lens, ctx,
             sm_scale=sm, interpret=True, q_block=q_block))
         ts, tc = _token_descriptors(T, seq_slots, q_starts, q_lens, ctx)
-        tok = np.asarray(_ragged_paged_attention_pallas(
-            q, kp, vp, jnp.asarray(tbl), ts, tc, sm_scale=sm,
-            interpret=True))
+        tok = np.asarray(_ragged_paged_attention_xla(
+            q, kp, vp, jnp.asarray(tbl), ts, tc, sm_scale=sm))
         ref_tol = dict(rtol=2e-5, atol=2e-5)
     ref = np.asarray(ragged_paged_attention_reference(
         q, kp, vp, tbl, seq_slots, q_starts, q_lens, ctx))
     for slot, qs, ql, _ in layout:               # pad rows are garbage
         np.testing.assert_allclose(qb[qs:qs + ql], tok[qs:qs + ql],
-                                   **KERNEL_TOL)
+                                   **FORM_TOL)
         assert np.isfinite(qb[qs:qs + ql]).all()
         np.testing.assert_allclose(qb[qs:qs + ql], ref[qs:qs + ql],
                                    **ref_tol)
@@ -146,8 +144,8 @@ def test_qblock_padded_tail_blocks():
 
 
 def test_qblock_int8_kv_parity():
-    # int8 KV pages: the q-block quant kernel dequantizes per row-scale
-    # exactly like the per-token quant kernel — same KERNEL_TOL parity
+    # int8 KV pages: the q-block kernel scales scores and weights by the
+    # row scales the XLA form dequantizes the rows with — same FORM_TOL
     _parity([(0, 0, 1, 12), (1, 1, 5, 25), (2, 6, 9, 9)], quant=True)
 
 
@@ -314,9 +312,8 @@ def test_qblock_grid_walks_the_jobs_that_exist():
 def test_qblock_cell_shape_parity():
     # the cell's tick: 8 KV heads x group 4 in one grid step, decode
     # blocks of 8 owners beside 29 blocks of one long prefill span. Every
-    # span row against the gather+softmax tier; a sample of rows (the
-    # per-token grid would take 131 k interpreted steps for all of them)
-    # against the per-token kernel, whose recurrence this one replays
+    # span row against the gather+softmax form; a sample of rows against
+    # the dense oracle
     ss, qs, ql, cl = _cell_tick(seed=3, chunk_ctx=700)
     cl = np.minimum(cl, 1024)
     kp, vp, tbl = _pool(nslots=31, d=16, kv_heads=8, page=16,
@@ -331,71 +328,19 @@ def test_qblock_cell_shape_parity():
     assert np.isfinite(qb).all()
     np.testing.assert_allclose(qb, dense, rtol=2e-5, atol=2e-5)
     rows = np.r_[0:30:4, 30:256:45]              # 8 decode rows, 6 chunk rows
-    tok = np.asarray(_ragged_paged_attention_pallas(
-        q[rows], kp, vp, jnp.asarray(tbl), ts[rows], tc[rows], sm_scale=sm,
-        interpret=True))
-    np.testing.assert_allclose(qb[rows], tok, **KERNEL_TOL)
+    ref = np.asarray(ragged_paged_attention_reference(
+        q[rows], kp, vp, tbl, np.asarray(ts)[rows],
+        np.arange(len(rows)), np.ones(len(rows), np.int32),
+        np.asarray(tc)[rows]))
+    np.testing.assert_allclose(qb[rows], ref, rtol=2e-5, atol=2e-5)
 
 
-def test_qblock_rows_env_knob(monkeypatch):
-    """PADDLE_TPU_RAGGED_QBLOCK tunes the block size; junk values fall
-    back to DEFAULT_QBLOCK; the public entry keeps KERNEL_TOL parity
-    with the per-token grid at any block size."""
-    assert _qblock_rows() == DEFAULT_QBLOCK == 8
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_QBLOCK", "4")
-    assert _qblock_rows() == 4
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_QBLOCK", "notanint")
-    assert _qblock_rows() == DEFAULT_QBLOCK
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_QBLOCK", "5")   # odd size
-    kp, vp, tbl = _pool(nslots=3)
-    seq_slots = np.asarray([0, 1, 2], np.int32)
-    q_starts = np.asarray([0, 1, 8], np.int32)
-    q_lens = np.asarray([1, 7, 4], np.int32)
-    ctx = np.asarray([17, 22, 4], np.int32)
-    rng = np.random.RandomState(11)
-    q = jnp.asarray(rng.randn(12, 4, 32), jnp.float32)
-    out = np.asarray(ragged_paged_attention(
-        q, kp, vp, jnp.asarray(tbl), seq_slots, q_starts, q_lens, ctx,
-        interpret=True))
-    ts, tc = _token_descriptors(12, seq_slots, q_starts, q_lens, ctx)
-    tok = np.asarray(_ragged_paged_attention_pallas(
-        q, kp, vp, jnp.asarray(tbl), ts, tc, sm_scale=32 ** -0.5,
-        interpret=True))
-    np.testing.assert_allclose(out, tok, **KERNEL_TOL)
-
-
-def test_ragged_impl_env_dispatch(monkeypatch):
-    """PADDLE_TPU_RAGGED_IMPL selects the grid: "qblock" (the default
-    under "auto") and "token" (per-token escape hatch) agree to
-    KERNEL_TOL through the public entry; "xla" to float tolerance."""
-    kp, vp, tbl = _pool(nslots=3)
-    seq_slots = np.asarray([0, 1, 2], np.int32)
-    q_starts = np.asarray([0, 1, 6], np.int32)
-    q_lens = np.asarray([1, 5, 9], np.int32)
-    ctx = np.asarray([19, 25, 9], np.int32)
-    rng = np.random.RandomState(5)
-    q = jnp.asarray(rng.randn(15, 4, 32), jnp.float32)
-
-    def run():
-        return np.asarray(ragged_paged_attention(
-            q, kp, vp, jnp.asarray(tbl), seq_slots, q_starts, q_lens,
-            ctx, interpret=True))
-
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_IMPL", "qblock")
-    out_qb = run()
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_IMPL", "token")
-    out_tok = run()
-    monkeypatch.setenv("PADDLE_TPU_RAGGED_IMPL", "xla")
-    out_xla = run()
-    np.testing.assert_allclose(out_qb, out_tok, **KERNEL_TOL)
-    np.testing.assert_allclose(out_qb[:12], out_xla[:12],
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_qblock_traced_descriptors_fall_back():
+@pytest.mark.parametrize("quant", [False, True], ids=["native", "int8"])
+def test_qblock_traced_descriptors_fall_back(quant):
     """The q-block schedule needs concrete descriptor values (host-side
-    numpy); under jit tracing the public entry must quietly fall back to
-    the per-token grid and stay correct."""
+    numpy); under jit tracing the public entry answers with the XLA form
+    (to KERNEL_TOL of that form called directly) and stays correct against
+    the oracle, on native and on int8 pools."""
     kp, vp, tbl = _pool(nslots=2)
     seq_slots = np.asarray([0, 1], np.int32)
     q_starts = np.asarray([0, 4], np.int32)
@@ -403,20 +348,54 @@ def test_qblock_traced_descriptors_fall_back():
     ctx = np.asarray([12, 3], np.int32)
     rng = np.random.RandomState(9)
     q = jnp.asarray(rng.randn(7, 4, 32), jnp.float32)
+    pools, scales, tol = (kp, vp), {}, dict(rtol=2e-5, atol=2e-5)
+    if quant:
+        kq, ks = quantize_kv_rows(np.asarray(kp))
+        vq, vs = quantize_kv_rows(np.asarray(vp))
+        pools = (jnp.asarray(kq), jnp.asarray(vq))
+        scales = dict(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs))
+        tol = dict(rtol=5e-2, atol=5e-2)        # int8 quantization error
 
     @jax.jit
     def f(q, ss, qs, ql, cx):
-        return ragged_paged_attention(q, kp, vp, jnp.asarray(tbl),
-                                      ss, qs, ql, cx, interpret=True)
+        return ragged_paged_attention(q, *pools, jnp.asarray(tbl),
+                                      ss, qs, ql, cx, interpret=True,
+                                      **scales)
 
     out = np.asarray(f(q, seq_slots, q_starts, q_lens, ctx))
+    ts, tc = _token_descriptors(7, seq_slots, q_starts, q_lens, ctx)
+    xla = np.asarray(_ragged_paged_attention_xla(
+        q, *pools, jnp.asarray(tbl), ts, tc, sm_scale=32 ** -0.5, **scales))
+    np.testing.assert_allclose(out, xla, **KERNEL_TOL)
     ref = np.asarray(ragged_paged_attention_reference(
         q, kp, vp, tbl, seq_slots, q_starts, q_lens, ctx))
-    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(out, ref, **tol)
+
+
+def test_latent_pool_under_jit_raises():
+    """A latent pool is read by the q-block kernel alone: traced
+    descriptors have no XLA form to fall back to."""
+    pool = jnp.zeros((1, 3, 32, 8), jnp.float32)
+    q = jnp.zeros((2, 4, 32), jnp.float32)
+    tbl = np.zeros((1, 2), np.int32)
+
+    @jax.jit
+    def f(q, ss, qs, ql, cx):
+        return ragged_paged_attention(q, pool, None, tbl, ss, qs, ql, cx,
+                                      value_dim=16, interpret=True)
+
+    one = np.ones(1, np.int32)
+    with pytest.raises(NotImplementedError, match="not jit tracers"):
+        f(q, one * 0, one * 0, one * 2, one * 2)
+    # the same call with concrete descriptors is served
+    out = ragged_paged_attention(q, pool, None, tbl, one * 0, one * 0,
+                                 one * 2, one * 2, value_dim=16,
+                                 interpret=True)
+    assert out.shape == (2, 4, 16)
 
 
 # ---------------------------------------------------------------------------
-# engine acceptance: q-block grid == per-token grid, bit for bit
+# engine acceptance: the q-block engine == model.generate, bit for bit
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -448,14 +427,16 @@ def _drive(eng, prompts, new_tokens):
 
 def test_engine_qblock_vs_token_bit_identical(model, monkeypatch):
     """Acceptance bar: a mixed chunked-prefill + decode workload under
-    the q-block grid produces greedy outputs bit-identical to the
-    per-token grid — and matches the dense oracle."""
+    the q-block kernel produces greedy outputs bit-identical to the dense
+    oracle on every request — and to the same engine with the kernel's
+    entry swapped for the XLA form (argmax absorbs the forms' 2e-5)."""
+    rpa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, 128, (1, n)).astype(np.int64)
                for n in (23, 5, 37, 11)]
 
-    def run(impl):
-        monkeypatch.setenv("PADDLE_TPU_RAGGED_IMPL", impl)
+    def run():
         eng = ContinuousServingEngine(
             model, max_batch_size=4, max_len=64, token_budget=16,
             prefill_chunk_tokens=16)
@@ -463,11 +444,64 @@ def test_engine_qblock_vs_token_bit_identical(model, monkeypatch):
         assert eng.ragged_steps > 0
         return out
 
-    got_qb = run("qblock")
-    got_tok = run("token")
-    for a, b in zip(got_qb, got_tok):
+    got_qb = run()
+
+    def xla_entry(q, kp, vp, tbl, ss, qs, ql, cl, *, sm_scale, interpret,
+                  k_scales=None, v_scales=None):
+        ts, tc = _token_descriptors(q.shape[0], ss, qs, ql, cl)
+        return _ragged_paged_attention_xla(
+            q, kp, vp, tbl, ts, tc, sm_scale=sm_scale, k_scales=k_scales,
+            v_scales=v_scales)
+
+    monkeypatch.setattr(rpa, "_ragged_paged_attention_pallas_qblock",
+                        xla_entry)
+    got_xla = run()
+    for p, a, b in zip(prompts, got_qb, got_xla):
         np.testing.assert_array_equal(a, b)
-    np.testing.assert_array_equal(got_qb[0], _oracle(model, prompts[0], 5))
+        np.testing.assert_array_equal(a, _oracle(model, p, 5))
+
+
+def test_dead_switches_change_nothing(model, monkeypatch):
+    """``PADDLE_SERVING_RAGGED``, ``PADDLE_TPU_RAGGED_IMPL`` and
+    ``PADDLE_TPU_RAGGED_QBLOCK`` are gone, not half-read: with all three
+    set an engine still serves in ragged ticks through the q-block entry,
+    eight rows a block, and its tokens are the ones served without them."""
+    rpa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(0, 128, (1, n)).astype(np.int64)
+               for n in (19, 4, 26)]
+    entry, calls = rpa._ragged_paged_attention_pallas_qblock, []
+
+    def spy(*a, **kw):
+        calls.append(kw.get("q_block"))
+        return entry(*a, **kw)
+
+    monkeypatch.setattr(rpa, "_ragged_paged_attention_pallas_qblock", spy)
+
+    def run():
+        eng = ContinuousServingEngine(
+            model, max_batch_size=2, max_len=48, token_budget=16,
+            prefill_chunk_tokens=16)
+        out = _drive(eng, prompts, 4)
+        return out, eng.ragged_steps, len(calls)
+
+    plain, _, n_plain = run()
+    monkeypatch.setenv("PADDLE_SERVING_RAGGED", "0")
+    monkeypatch.setenv("PADDLE_TPU_RAGGED_IMPL", "token")
+    monkeypatch.setenv("PADDLE_TPU_RAGGED_QBLOCK", "4")
+    assert _qblock_rows() == DEFAULT_QBLOCK == 8
+    got, ticks, n_all = run()
+    assert ticks > 0 and n_all - n_plain == 2 * ticks    # two layers a tick
+    assert set(calls) == {None}             # no caller overrides the block
+    for a, b in zip(plain, got):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_engine_has_no_scheduler_switch(model):
+    """One scheduler: the constructor takes no ``enable_ragged``."""
+    with pytest.raises(TypeError, match="enable_ragged"):
+        ContinuousServingEngine(model, enable_ragged=False)
 
 
 def _random_tick(rng, slots, max_len, budget, page, num_pages):

@@ -1,9 +1,8 @@
 """Ragged paged attention (ISSUE 7): one kernel + token-budget scheduler
 for true continuous batching — kernel parity vs the dense reference
 across ragged descriptor layouts, and engine acceptance that greedy
-outputs under the ragged scheduler stay bit-identical to the legacy
-two-program path and the dense oracle (incl. prefix-cache hits and
-cancellation)."""
+outputs under the ragged scheduler stay bit-identical to the dense
+oracle (incl. prefix-cache hits and cancellation)."""
 import threading
 import time
 
@@ -114,17 +113,17 @@ def test_kernel_matches_decode_kernel_on_pure_decode():
     lens = np.asarray([7, 19, 30], np.int32)
     rng = np.random.RandomState(3)
     q = jnp.asarray(rng.randn(3, 4, 32), jnp.float32)
-    legacy = np.asarray(paged_attention(q, kp, vp, jnp.asarray(tbl),
+    decode = np.asarray(paged_attention(q, kp, vp, jnp.asarray(tbl),
                                         jnp.asarray(lens), interpret=True))
     ragged = np.asarray(ragged_paged_attention(
         q, kp, vp, jnp.asarray(tbl), np.arange(3, dtype=np.int32),
         np.arange(3, dtype=np.int32), np.ones(3, np.int32), lens,
         interpret=True))
-    np.testing.assert_allclose(ragged, legacy, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(ragged, decode, rtol=1e-6, atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
-# engine acceptance: ragged scheduler == legacy two-program path == oracle
+# engine acceptance: ragged scheduler == oracle
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -141,20 +140,19 @@ def _oracle(model, p, n):
 
 def test_ragged_vs_legacy_mixed_workload_bit_identical(model):
     """The PR's acceptance bar: a mixed 8-request workload (shared
-    prefixes, staggered arrivals, one timeout cancellation) produces
-    greedy outputs bit-identical between the ragged token-budget
-    scheduler and the legacy chunked+decode path — and both match the
-    dense oracle."""
+    prefixes, staggered arrivals, one timeout cancellation) through the
+    ragged token-budget scheduler produces greedy outputs bit-identical
+    to the dense oracle (``model.generate``) on every request."""
     rng = np.random.RandomState(0)
     shared = rng.randint(0, 128, 48)
     specs = [3, 9, 5, 14, 7, 4, 11, 6]           # unique tail lengths
     prompts = [np.concatenate([shared, rng.randint(0, 128, t)])
                .astype(np.int64)[None] for t in specs]
 
-    def run(ragged):
+    def run():
         eng = ContinuousServingEngine(
             model, max_batch_size=4, max_len=96, page_size=16,
-            prefill_chunk_tokens=24, token_budget=32, enable_ragged=ragged)
+            prefill_chunk_tokens=24, token_budget=32)
         results = [None] * len(prompts)
         with eng:
             # request 0 lands first and registers the shared prefix
@@ -181,19 +179,14 @@ def test_ragged_vs_legacy_mixed_workload_bit_identical(model):
         assert eng.cancelled_rows >= 1
         return results, eng
 
-    got_r, eng_r = run(True)
-    got_l, eng_l = run(False)
-    for a, b in zip(got_r, got_l):
-        np.testing.assert_array_equal(a, b)
-    for i in (0, 4):                             # spot-check dense oracle
-        np.testing.assert_array_equal(got_r[i],
-                                      _oracle(model, prompts[i], 6))
+    got_r, eng_r = run()
+    for got, p in zip(got_r, prompts):
+        np.testing.assert_array_equal(got, _oracle(model, p, 6))
     # the ragged run really used the single program family, with both
     # prefill and decode tokens flowing through it
     assert eng_r.ragged_steps > 0
     assert eng_r.ragged_prefill_tokens > 0
     assert eng_r.ragged_decode_tokens > 0
-    assert eng_l.ragged_steps == 0
     # prefix-cache hits happened under the ragged scheduler too
     assert eng_r._cache.prefix_hits > 0
 
@@ -227,8 +220,8 @@ def test_ragged_bucket_set_bounded(model):
 
 def test_ragged_respects_chunk_cap_and_emits_events(model):
     """prefill_chunk_tokens still caps any ONE sequence's per-tick span
-    (fairness), and the scheduler emits legacy-compatible chunk/decode
-    events so liveness remains observable."""
+    (fairness), and the scheduler emits chunk/decode events so liveness
+    remains observable."""
     rng = np.random.RandomState(3)
     p = rng.randint(0, 128, (1, 40)).astype(np.int64)
     eng = ContinuousServingEngine(model, max_batch_size=2, max_len=64,
@@ -244,17 +237,26 @@ def test_ragged_respects_chunk_cap_and_emits_events(model):
 
 
 def test_ragged_env_knobs(model, monkeypatch):
-    monkeypatch.setenv("PADDLE_SERVING_RAGGED", "0")
-    assert ContinuousServingEngine(model).enable_ragged is False
-    monkeypatch.setenv("PADDLE_SERVING_RAGGED", "1")
     monkeypatch.setenv("PADDLE_SERVING_TOKEN_BUDGET", "128")
     eng = ContinuousServingEngine(model)
-    assert eng.enable_ragged is True
     assert eng.token_budget == 128
     # budget is clamped so every decode slot keeps its per-tick token
     monkeypatch.setenv("PADDLE_SERVING_TOKEN_BUDGET", "4")
     assert ContinuousServingEngine(
         model, max_batch_size=8).token_budget == 8
+
+
+def test_unarmed_cache_attend_raises():
+    """``attend`` has three modes (a ragged step, the two sep modes) and
+    no default: a cache nobody armed says which call is missing."""
+    from paddle_tpu.models.generation import SlotPagedKVCache
+    cache = SlotPagedKVCache(2, page_size=8, max_len=32)
+    x = jnp.zeros((1, 4, 2, 8), jnp.float32)
+    with pytest.raises(RuntimeError, match="begin_ragged"):
+        cache.attend(object(), x, x, x)
+    assert not cache._pools                   # and built nothing
+    assert not hasattr(cache, "begin_prefill")
+    assert not hasattr(cache, "begin_decode")
 
 
 def test_ragged_telemetry_and_flight_state(model):
@@ -278,4 +280,3 @@ def test_ragged_telemetry_and_flight_state(model):
                 "ragged_decode_tokens", "ragged_buckets_used",
                 "padded_tokens_total", "useful_tokens_total"):
         assert key in state, key
-    assert state["ragged"] is True

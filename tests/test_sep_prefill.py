@@ -248,10 +248,7 @@ def test_engine_env_knobs_and_validation(model, monkeypatch):
     monkeypatch.setenv("PADDLE_SEP_STRIPE_TOKENS", "30")
     with pytest.raises(ValueError):
         ContinuousServingEngine(model, page_size=16)
-    # sep needs the ragged scheduler
     monkeypatch.setenv("PADDLE_SEP_STRIPE_TOKENS", "32")
-    with pytest.raises(ValueError):
-        ContinuousServingEngine(model, page_size=16, enable_ragged=False)
     # int8 KV pools can't back the ring schedule
     with pytest.raises(ValueError):
         ContinuousServingEngine(model, page_size=16, kv_dtype="int8")

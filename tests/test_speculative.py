@@ -143,14 +143,8 @@ def test_rollback_keeps_prefix_registered_page():
 
 
 # ---------------------------------------------------------------------------
-# engine: spec requires ragged; env knobs
+# engine: env knobs
 # ---------------------------------------------------------------------------
-
-def test_spec_requires_ragged_scheduler(model):
-    with pytest.raises(ValueError):
-        ContinuousServingEngine(model, spec_decode=True,
-                                enable_ragged=False)
-
 
 def test_spec_env_knobs(model, monkeypatch):
     assert ContinuousServingEngine(model).enable_spec is False
@@ -298,8 +292,8 @@ def test_seeded_sampling_reproducible(model):
     a, b = run(7), run(7)
     np.testing.assert_array_equal(a, b)      # same seed -> same text
     assert not np.array_equal(a, run(8))     # different seed diverges
-    # legacy scheduler derives the identical per-token keys
-    np.testing.assert_array_equal(a, run(7, enable_ragged=False))
+    # the per-token keys do not depend on how the ticks pack the prompt
+    np.testing.assert_array_equal(a, run(7, prefill_chunk_tokens=4))
 
 
 def test_seeded_sampling_spec_verification_exact(model):

@@ -145,30 +145,6 @@ def test_paged_decode_compiles(compile_on_chip, kv_dtype):
 
 
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
-def test_ragged_per_token_compiles(compile_on_chip, kv_dtype):
-    rpa = importlib.import_module(
-        "paddle_tpu.ops.pallas.ragged_paged_attention")
-    q = ((TOKEN_BUDGET, HEADS, HEAD_DIM), jnp.bfloat16)
-    tables = ((MAX_BATCH, PAGES_PER_SEQ), jnp.int32)   # SMEM-prefetched
-    tok = ((TOKEN_BUDGET,), jnp.int32)
-    if kv_dtype == "int8":
-        def fn(q, kp, vp, ks, vs, tbl, slot, ctx):
-            return rpa._ragged_paged_attention_pallas_quant(
-                q, kp, vp, ks, vs, tbl, slot, ctx, sm_scale=SM_SCALE,
-                interpret=False)
-        text = compile_on_chip(fn, q, _pool(jnp.int8), _pool(jnp.int8),
-                               _SCALES, _SCALES, tables, tok, tok)
-    else:
-        def fn(q, kp, vp, tbl, slot, ctx):
-            return rpa._ragged_paged_attention_pallas(
-                q, kp, vp, tbl, slot, ctx, sm_scale=SM_SCALE,
-                interpret=False)
-        text = compile_on_chip(fn, q, _pool(jnp.bfloat16),
-                               _pool(jnp.bfloat16), tables, tok, tok)
-    assert _has_kernel(text)
-
-
-@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_ragged_qblock_compiles(compile_on_chip, kv_dtype):
     """The q-block grid builds its job schedule host-side, so the
     descriptors are concrete (as in the eager serving tick) and only the

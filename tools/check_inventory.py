@@ -500,13 +500,8 @@ def check_serving_programs(verbose=True):
     ragged program family, and (second pass) that speculative-decode
     verify spans (q_len = 1 + k drafted tokens) stay inside the SAME
     declared family — spec decode must not explode the compiled-program
-    set — and (third pass) that the fixed-q-block ragged grid
-    (``PADDLE_TPU_RAGGED_IMPL=qblock``, the ISSUE-16 default decode
-    path) keeps the identical bucket discipline: the q-block schedule
-    re-tiles the flat token batch but the engine still pads the token
-    dimension to declared buckets. Last, the decoder layer's two
-    compiled programs hold exactly one executable a bucket met. Returns
-    a list of violation strings."""
+    set. Last, the decoder layer's two compiled programs hold exactly
+    one executable a bucket met. Returns a list of violation strings."""
     import threading
 
     import numpy as np
@@ -563,37 +558,13 @@ def check_serving_programs(verbose=True):
             f"(declared {sorted(spec.declared_token_buckets())})")
     if not spec.spec_drafted_tokens:
         violations.append("speculative pass drafted no tokens")
-    # q-block pass: the same mixed load with the fixed-q-block ragged
-    # grid forced — the new default decode grid must not grow the
-    # compiled-program family
-    prev_impl = os.environ.get("PADDLE_TPU_RAGGED_IMPL")
-    os.environ["PADDLE_TPU_RAGGED_IMPL"] = "qblock"
-    try:
-        qb = ContinuousServingEngine(model, max_batch_size=2, max_len=48,
-                                     token_budget=16,
-                                     prefill_chunk_tokens=16)
-        drive(qb, prompts)
-    finally:
-        if prev_impl is None:
-            os.environ.pop("PADDLE_TPU_RAGGED_IMPL", None)
-        else:
-            os.environ["PADDLE_TPU_RAGGED_IMPL"] = prev_impl
-    qb_stray = qb.ragged_buckets_used - qb.declared_token_buckets()
-    if qb_stray:
-        violations.append(
-            f"q-block serving ran shapes outside the declared bucket set: "
-            f"{sorted(qb_stray)} (declared "
-            f"{sorted(qb.declared_token_buckets())})")
-    if not qb.ragged_steps:
-        violations.append("q-block pass never reached the ragged scheduler")
     # the decoder layer's two compiled programs (models/llama.py): one
     # executable a token bucket met, shared by every layer, tick and
     # engine of this geometry — a recompile a tick or a layer shows here
-    met = (eng.ragged_buckets_used | spec.ragged_buckets_used
-           | qb.ragged_buckets_used)
+    met = eng.ragged_buckets_used | spec.ragged_buckets_used
     programs = model.llama._programs
     pieces = programs.program_counts() if programs is not None else {}
-    for e, name in ((eng, "mixed"), (spec, "speculative"), (qb, "q-block")):
+    for e, name in ((eng, "mixed"), (spec, "speculative")):
         if e.compiled_layer_calls != e.ragged_steps:     # one layer
             violations.append(
                 f"{name} pass ran {e.compiled_layer_calls} compiled layers "
@@ -613,8 +584,7 @@ def check_serving_programs(verbose=True):
               f"decode={eng.ragged_decode_tokens} tokens; spec buckets "
               f"{sorted(spec.ragged_buckets_used)} drafted="
               f"{spec.spec_drafted_tokens} accepted="
-              f"{spec.spec_accepted_tokens}; qblock buckets "
-              f"{sorted(qb.ragged_buckets_used)}")
+              f"{spec.spec_accepted_tokens}")
     return violations
 
 
@@ -651,8 +621,7 @@ def check_quantized_config(verbose=True):
             with open(os.path.join(tests_dir, name), errors="replace") as f:
                 tests_text += f.read()
     violations = []
-    knobs = ["PADDLE_WEIGHT_DTYPE", "PADDLE_TPU_RAGGED_QBLOCK",
-             "PADDLE_SPEC_DRAFT_BATCH", "PADDLE_TPU_RAGGED_IMPL",
+    knobs = ["PADDLE_WEIGHT_DTYPE", "PADDLE_SPEC_DRAFT_BATCH",
              "PADDLE_KV_DTYPE"]
     for k in knobs:
         if k not in docs_text:
@@ -661,15 +630,6 @@ def check_quantized_config(verbose=True):
         if k not in tests_text:
             violations.append(
                 f"quantized-config knob {k} not exercised by any test")
-    # impl selector values a user must be able to discover (the quoted
-    # form keeps prose mentions of the word "token" from matching)
-    for value in ('"qblock"', '"token"'):
-        if value.strip('"') not in docs_text:
-            violations.append(
-                f"ragged impl value {value} missing from docs/*.md")
-        if value not in tests_text:
-            violations.append(
-                f"ragged impl value {value} not exercised by any test")
 
     def run_once():
         paddle.seed(0)
