@@ -114,7 +114,7 @@ _SLOW_FILES = {
     "test_models.py", "test_moe.py",
     "test_nn.py", "test_nn_extras.py", "test_op_suite.py",
     "test_op_surface_r3.py", "test_paged_attention.py",
-    "test_pallas_flash.py", "test_pipeline_1f1b.py",
+    "test_pipeline_1f1b.py",
     "test_pipeline_dropout.py", "test_pipeline_transformer.py",
     "test_quant_inference.py", "test_review_fixes.py", "test_rnn.py",
     "test_serving.py", "test_sharding_offload.py", "test_sparse_quant.py",

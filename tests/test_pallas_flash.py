@@ -105,6 +105,207 @@ def test_grads_gqa():
 
 
 # ---------------------------------------------------------------------------
+# Tiles from the shapes, operands as they arrive, the causal grid (PR 30)
+# ---------------------------------------------------------------------------
+
+import importlib  # noqa: E402
+
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+
+#: (sq, sk, head_dim, dtype): the training cell's call, its shorter kin,
+#: another model's head widths, float32 operands, lengths no tile divides
+RULE_SHAPES = [(4096, 4096, 128, "bfloat16"), (2048, 2048, 128, "bfloat16"),
+               (128, 128, 128, "bfloat16"), (512, 4096, 128, "bfloat16"),
+               (4096, 4096, 192, "bfloat16"), (4096, 4096, 64, "bfloat16"),
+               (4096, 4096, 128, "float32"), (16384, 16384, 256, "float32"),
+               (4224, 4224, 128, "bfloat16"), (1000, 1000, 64, "bfloat16"),
+               (96, 96, 16, "float32")]
+
+
+@pytest.mark.parametrize("kernel", fa.KERNELS)
+@pytest.mark.parametrize("sq,sk,d,dtype", RULE_SHAPES)
+def test_tile_rule_is_a_function_of_shapes_and_dtype(kernel, sq, sk, d, dtype):
+    bq, bk = fa.tile_rule(kernel, sq, sk, d, dtype)
+    assert (bq, bk) == fa.tile_rule(kernel, sq, sk, d, dtype)
+    for block, length in ((bq, sq), (bk, sk)):
+        padded = -(-length // block) * block
+        assert block <= padded < length + max(block, 128)
+        if length > 128:             # whole lane rows, no more padding
+            assert block % 128 == 0 and padded - length < 128 * (
+                padded // block)
+        else:
+            assert block == max(length, 8)
+    plan = fa.vmem_plan(kernel, bq, bk, d, dtype)
+    assert plan["total"] == sum(v for k, v in plan.items()
+                                if k not in ("total", "limit"))
+    assert plan["total"] <= fa._VMEM_BUDGET
+    # the set fits the limit the call states (Mosaic's own where none)
+    assert plan["total"] <= (plan["limit"] or fa._VMEM_DEFAULT_SCOPE)
+
+
+def test_tile_rule_shrinks_with_the_working_set():
+    """A wider head or float32 operands never take a LARGER tile, and an
+    explicit tile is clipped to the axis as ever."""
+    for kernel in fa.KERNELS:
+        base = fa.tile_rule(kernel, 8192, 8192, 128, "bfloat16")
+        for d, dtype in ((256, "bfloat16"), (128, "float32"),
+                         (512, "float32")):
+            bq, bk = fa.tile_rule(kernel, 8192, 8192, d, dtype)
+            assert bq <= base[0] and bk <= base[1]
+    assert fa._tiles("fwd", 64, 64, 96, 40, 16, "float32") == (64, 40, 128, 40)
+    assert fa._tiles("fwd", None, None, 200, 200, 16, "float32")[2:] == (
+        256, 256)
+
+
+def _brute_force_plan(bq, bk, sq, sk, causal, q_off, kv_off):
+    """Count tiles from the full [sq_pad, sk_pad] pair matrix."""
+    sq_pad, sk_pad = -(-sq // bq) * bq, -(-sk // bk) * bk
+    rows = q_off + np.arange(sq_pad)[:, None]
+    cols = kv_off + np.arange(sk_pad)[None, :]
+    # what a tile would have to look at: the causal pattern over its whole
+    # square (padded query rows included), and the padding behind sk
+    future = (rows < cols) if causal else np.zeros((sq_pad, sk_pad), bool)
+    padding = np.broadcast_to(np.arange(sk_pad)[None, :] >= sk,
+                              (sq_pad, sk_pad))
+    wanted = ~future & ~padding
+    wanted[sq:] = False
+    steps = compute = masked = 0
+    for i in range(0, sq_pad, bq):
+        for j in range(0, sk_pad, bk):
+            steps += 1
+            f = future[i:i + bq, j:j + bk]
+            if f.all():
+                continue
+            compute += 1
+            masked += bool(f.any() or padding[i:i + bq, j:j + bk].any())
+    return {"tile": (bq, bk), "steps": steps, "compute_steps": compute,
+            "masked_tiles": masked, "pairs_needed": int(wanted.sum()),
+            "pairs_computed": compute * bq * bk}
+
+
+@pytest.mark.parametrize("bq,bk,sq,sk,causal,q_off,kv_off", [
+    (128, 128, 1024, 1024, True, 0, 0), (512, 256, 2048, 2048, True, 0, 0),
+    (256, 512, 2048, 2048, True, 0, 0), (128, 256, 512, 1536, True, 1024, 0),
+    (128, 128, 512, 512, True, 0, 1024), (128, 128, 512, 512, True, 512, 0),
+    (256, 256, 1000, 1000, True, 0, 0), (256, 256, 1000, 900, False, 0, 0),
+    (128, 128, 512, 512, True, 0, 64)])
+def test_grid_plan_counts_match_a_brute_force_count(bq, bk, sq, sk, causal,
+                                                    q_off, kv_off):
+    assert fa._plan_counts(bq, bk, sq, sk, causal, q_off, kv_off) == \
+        _brute_force_plan(bq, bk, sq, sk, causal, q_off, kv_off)
+
+
+def test_grid_plan_at_the_training_cells_shapes():
+    plan = fa.grid_plan(4096, 4096, 128, "bfloat16", True, 0, 0)
+    assert set(plan) == set(fa.KERNELS)
+    for kernel, counts in plan.items():
+        assert counts["tile"] == fa.tile_rule(kernel, 4096, 4096, 128,
+                                              "bfloat16")
+        assert counts == _brute_force_plan(*counts["tile"], 4096, 4096, True,
+                                           0, 0)
+        assert counts["pairs_needed"] == 4096 * 4097 // 2
+    # the price of a tile, as the issue states it
+    fill = lambda b: 100 * fa._plan_counts(b, b, 4096, 4096, True, 0, 0)[
+        "pairs_needed"] / fa._plan_counts(b, b, 4096, 4096, True, 0, 0)[
+        "pairs_computed"]
+    assert [round(fill(b)) for b in (128, 512, 1024)] == [97, 89, 80]
+
+
+def _flash_and_ref(q, k, v, g, *, causal=True, q_offset=0, kv_offset=0,
+                   block=None, cotangent_lse=None):
+    """(out, lse, dq, dk, dv) of the kernel (interpret mode, inputs as they
+    are) and of ``mha_reference`` on the same inputs in float32."""
+    def run(fn, cast):
+        def loss(q, k, v):
+            out, lse = fn(cast(q), cast(k), cast(v))
+            total = jnp.sum(out.astype(jnp.float32) * g)
+            if cotangent_lse is not None:
+                live = lse > -1e29
+                total += jnp.sum(jnp.where(live, lse * cotangent_lse, 0.0))
+            return total, (out, lse)
+        grads, (out, lse) = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v)
+        return (out, lse) + grads
+
+    kern = run(lambda q, k, v: flash_attention_with_lse(
+        q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset,
+        block_q=block, block_k=block, interpret=True), lambda x: x)
+    ref = run(lambda q, k, v: mha_reference(
+        q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset,
+        with_lse=True), lambda x: x.astype(jnp.float32))
+    return kern, ref
+
+
+def _assert_close(kern, ref, tol):
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), kern, ref):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        live = b > -1e29 if name == "lse" else np.ones(b.shape, bool)
+        assert (a[~live] < -1e29).all(), name
+        np.testing.assert_allclose(a[live], b[live], rtol=tol, atol=tol,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("h,hk", [(2, 2), (4, 2)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("block", [64, None], ids=["tile64", "rule"])
+def test_bf16_operands_forward_and_gradients(h, hk, block):
+    """bf16 q, k, v, dO go to the products as they are, p and dS rounded to
+    bf16 for the second products: the reference in float32 at a bf16
+    tolerance (the output alone is rounded to 2^-9 of values near one)."""
+    s, d = 256, 32
+    q, k, v = (_rand((1, n, s, d), 50 + i, np.float32).astype(jnp.bfloat16)
+               for i, n in enumerate((h, hk, hk)))
+    g = _rand((1, h, s, d), 60)
+    kern, ref = _flash_and_ref(q, k, v, g, block=block)
+    assert kern[0].dtype == jnp.bfloat16 and kern[1].dtype == jnp.float32
+    assert [x.dtype for x in kern[2:]] == [jnp.bfloat16] * 3
+    _assert_close(kern, ref, tol=3e-2)
+    # and the error is rounding, not a missing term: relative to the norm
+    for a, b in zip(kern[2:], ref[2:]):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) < 1e-2 * np.linalg.norm(b)
+
+
+@pytest.mark.parametrize("q_offset,kv_offset,sq,sk", [
+    (128, 0, 128, 384),      # whole K blocks of the shard in the future
+    (0, 1024, 128, 256),     # the whole shard in the future: block 0, nothing
+    (0, 32, 128, 128),       # a tile whose first rows see no key at all
+    (128, 0, 64, 192),       # sq != sk with the decode offset (bottom-right)
+    (256, 128, 128, 128)],   # a shard wholly in the past: no tile masks
+    ids=["future_blocks", "future_shard", "dead_rows", "decode_offset",
+         "past_shard"])
+@pytest.mark.parametrize("h,hk", [(2, 2), (4, 1)], ids=["mha", "gqa"])
+def test_causal_offsets_forward_and_gradients(q_offset, kv_offset, sq, sk, h,
+                                              hk):
+    """The clamped index maps: a step in the causal future names the block
+    already held and computes nothing; lse's cotangent rides along (the
+    ring merge differentiates through it)."""
+    d = 16
+    q, k, v = _rand((1, h, sq, d), 70), _rand((1, hk, sk, d), 71), _rand(
+        (1, hk, sk, d), 72)
+    g, g_lse = _rand((1, h, sq, d), 73), _rand((1, h, sq), 74)
+    kern, ref = _flash_and_ref(q, k, v, g, q_offset=q_offset,
+                               kv_offset=kv_offset, block=64,
+                               cotangent_lse=g_lse)
+    _assert_close(kern, ref, tol=5e-4)
+    if kv_offset > q_offset + sq:
+        assert all(float(jnp.abs(x).max()) == 0.0
+                   for x in (kern[0],) + kern[2:])
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("sq,sk", [(200, 200), (1100, 1100), (130, 300)])
+def test_a_length_the_default_tile_does_not_divide(causal, sq, sk):
+    """The rule's tiles pad both axes (1,100 runs as 2 x 640): padded keys
+    are masked, padded query rows dropped, in all three kernels."""
+    q, k, v = _rand((1, 1, sq, 16), 80), _rand((1, 1, sk, 16), 81), _rand(
+        (1, 1, sk, 16), 82)
+    g = _rand((1, 1, sq, 16), 83)
+    kern, ref = _flash_and_ref(q, k, v, g, causal=causal,
+                               q_offset=sk - sq if causal else 0)
+    _assert_close(kern, ref, tol=5e-4)
+
+
+# ---------------------------------------------------------------------------
 # Ring attention over the sep axis
 # ---------------------------------------------------------------------------
 

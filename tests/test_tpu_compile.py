@@ -108,6 +108,25 @@ def test_flash_fwd_bwd_compiles(compile_on_chip):
     assert text.count("tpu_custom_call") >= 3      # fwd + dq + dkv
 
 
+@pytest.mark.parametrize("head_dim", [64, 192])
+def test_flash_default_tiles_compile_at_other_head_widths(compile_on_chip,
+                                                          head_dim):
+    """The tiles come from the shapes (``flash_attention.tile_rule``), so a
+    head of another width (64: GPT-2 / Whisper-class; 192: DeepSeek-V3's
+    q / k heads) takes whatever the rule gives it at a training length: a
+    tile that overflows VMEM there fails here and not in a user's run."""
+    from paddle_tpu.ops.pallas import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    q = ((1, 2 * TRAIN_SEQ, 8, head_dim), jnp.bfloat16)
+    kv = ((1, 2 * TRAIN_SEQ, 2, head_dim), jnp.bfloat16)
+    text = compile_on_chip(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert len(_custom_calls(text)) == 3           # fwd + dq + dkv
+
+
 def test_ring_partial_with_lse_compiles(compile_on_chip):
     """The blockwise/ring partial (sep long-context prefill): one
     512-token stripe of queries against one stripe of keys, returning
