@@ -12,13 +12,23 @@ every (token, choice) pair. Nothing here stands in for the other chips or
 their exchange: a caller that runs every share adds the parts up
 (``tests/test_deepseek_v3.py`` does, against the uncut layer).
 
-The router is the sigmoid / group-limited one of the DeepSeek-V3 family
-(``topk_method = noaux_tc``): scores ``s = sigmoid(x W_r)`` in float32; the
-choice is made on ``s + b`` (``b`` a learned bias an expert that steers
-load and is NOT part of the weight): the experts stand in ``n_group``
-groups, a group's score is the sum of its two best, the best
-``topk_group`` groups are kept and the best ``top_k`` experts among them
-chosen; the weights are ``s_i / sum(s_chosen) * routed_scaling_factor``.
+Two routers, chosen by the layer's ``router`` argument:
+
+* ``"sigmoid_group"``: the sigmoid / group-limited one of the DeepSeek-V3
+  family (``topk_method = noaux_tc``): scores ``s = sigmoid(x W_r)`` in
+  float32; the choice is made on ``s + b`` (``b`` a learned bias an expert
+  that steers load and is NOT part of the weight): the experts stand in
+  ``n_group`` groups, a group's score is the sum of its two best, the best
+  ``topk_group`` groups are kept and the best ``top_k`` experts among them
+  chosen; the weights are ``s_i / sum(s_chosen) * routed_scaling_factor``;
+* ``"topk_softmax"`` (SmallThinker): the best ``top_k`` of the logits
+  ``z = x W_r``, weighed by a softmax over the kept logits alone.
+
+The experts are gated units ``W_down(act(W_gate x) * (W_up x))`` whose
+``activation`` is an argument: SiLU (SwiGLU, the DeepSeek-V3 family) or
+ReLU (ReGLU, SmallThinker). The router may read another input than the
+experts (``forward(x, router_input=...)``): SmallThinker routes from the
+layer's normalised input, before attention.
 """
 from __future__ import annotations
 
@@ -30,8 +40,8 @@ from .....framework.core import Tensor
 from .....nn.initializer import Normal
 from .....nn.layer import Layer
 
-__all__ = ["group_limited_topk", "sigmoid_group_route", "held_expert_sum",
-           "HeldExperts"]
+__all__ = ["group_limited_topk", "sigmoid_group_route", "topk_softmax_route",
+           "held_expert_sum", "HeldExperts"]
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -67,8 +77,26 @@ def sigmoid_group_route(x, w_router, bias, *, n_group, topk_group, top_k,
     return idx.astype(jnp.int32), w * scale
 
 
-def held_expert_sum(x, idx, weights, w_gate, w_up, w_down, lo, valid=None):
-    """The held experts' part of a routed SwiGLU layer.
+def topk_softmax_route(x, w_router, *, top_k):
+    """``x`` [S, h] -> (chosen experts [S, k] int32, weights [S, k]
+    float32): the best ``top_k`` logits ``x W_r`` and a softmax over those
+    alone (so the weights add up to 1 and ``norm_topk_prob`` has nothing
+    left to do). Float32 at the highest matmul precision, as
+    :func:`sigmoid_group_route` and for its reason."""
+    logits = jnp.matmul(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                        precision=_HI)
+    kept, idx = jax.lax.top_k(logits, top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(kept, axis=-1)
+
+
+#: the gate's activation, by the name a configuration gives it
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def held_expert_sum(x, idx, weights, w_gate, w_up, w_down, lo, valid=None,
+                    activation=jax.nn.silu):
+    """The held experts' part of a routed layer of gated units
+    ``W_down(activation(W_gate x) * (W_up x))`` (SiLU: SwiGLU; ReLU: ReGLU).
 
     ``x`` [S, h]; ``idx`` / ``weights`` [S, k] from the router (over ALL
     experts); ``w_gate`` / ``w_up`` [n, h, m] and ``w_down`` [n, m, h] are
@@ -87,7 +115,7 @@ def held_expert_sum(x, idx, weights, w_gate, w_up, w_down, lo, valid=None):
     rows = x[order // k]                                      # [S*k, h]
     gate = jax.lax.ragged_dot(rows, w_gate, sizes)
     up = jax.lax.ragged_dot(rows, w_up, sizes)
-    act = (jax.nn.silu(gate.astype(jnp.float32))
+    act = (activation(gate.astype(jnp.float32))
            * up.astype(jnp.float32)).astype(x.dtype)
     y = jax.lax.ragged_dot(act, w_down, sizes,
                            preferred_element_type=jnp.float32)
@@ -109,14 +137,19 @@ def held_expert_sum(x, idx, weights, w_gate, w_up, w_down, lo, valid=None):
 
 
 class HeldExperts(Layer):
-    """Router over ``num_experts`` + the stacked SwiGLU weights of the
-    experts ``held = (lo, n)`` this chip holds (default: all of them).
-    ``forward(x [.., h], valid=None)`` returns ``(the held experts' sum,
-    {"moe_expert_tokens": [n], "moe_unheld_tokens": []})``."""
+    """Router over ``num_experts`` + the stacked gate / up / down weights
+    of the experts ``held = (lo, n)`` this chip holds (default: all of
+    them). ``router``: ``"sigmoid_group"`` (with its bias) or
+    ``"topk_softmax"``; ``activation``: ``"silu"`` or ``"relu"`` (module
+    docstring). ``forward(x [.., h], valid=None, router_input=None)``
+    returns ``(the held experts' sum, {"moe_expert_tokens": [n],
+    "moe_unheld_tokens": []})``; the router reads ``router_input`` where
+    one is given, else ``x``."""
 
     def __init__(self, hidden_size, expert_size, num_experts, top_k, *,
                  n_group=1, topk_group=1, scale=1.0, norm_topk=True,
-                 held=None, initializer_range=0.02):
+                 held=None, initializer_range=0.02, router="sigmoid_group",
+                 activation="silu"):
         super().__init__()
         lo, n = (0, num_experts) if held is None else map(int, held)
         if not (0 <= lo and lo + n <= num_experts and n > 0):
@@ -125,18 +158,24 @@ class HeldExperts(Layer):
         if num_experts % n_group:
             raise ValueError(f"{num_experts} experts do not divide into "
                              f"{n_group} groups")
+        if router not in ("sigmoid_group", "topk_softmax"):
+            raise ValueError(f"unknown router {router!r}")
+        if activation not in ACTIVATIONS:
+            raise ValueError(f"unknown activation {activation!r}")
         self.num_experts, self.top_k = num_experts, top_k
         self.n_group, self.topk_group = n_group, topk_group
         self.scale, self.norm_topk = float(scale), bool(norm_topk)
         self.held = (lo, n)
+        self.router_kind, self.activation = router, activation
         init = Normal(0.0, initializer_range)
         # the router stays float32 under a bf16 model (as the published
         # checkpoints keep it): its scores decide which experts run
         self.router = self.create_parameter(
             [hidden_size, num_experts], dtype="float32",
             default_initializer=init)
-        self.router_bias = self.create_parameter(
-            [num_experts], dtype="float32", is_bias=True)
+        if router == "sigmoid_group":
+            self.router_bias = self.create_parameter(
+                [num_experts], dtype="float32", is_bias=True)
         self.w_gate = self.create_parameter(
             [n, hidden_size, expert_size], default_initializer=init)
         self.w_up = self.create_parameter(
@@ -144,27 +183,35 @@ class HeldExperts(Layer):
         self.w_down = self.create_parameter(
             [n, expert_size, hidden_size], default_initializer=init)
 
-    def forward(self, x, valid=None):
+    def _route(self, tok, wr, br):
+        if self.router_kind == "topk_softmax":
+            return topk_softmax_route(tok, wr, top_k=self.top_k)
+        return sigmoid_group_route(
+            tok, wr, br, n_group=self.n_group, topk_group=self.topk_group,
+            top_k=self.top_k, scale=self.scale, norm_topk=self.norm_topk)
+
+    def forward(self, x, valid=None, router_input=None):
         lo, _ = self.held
         shape = x.shape
         if isinstance(valid, Tensor):
             valid = valid._data
+        biased = self.router_kind == "sigmoid_group"
+        act = ACTIVATIONS[self.activation]
 
-        def fn(xa, wr, br, wg, wu, wd):
+        def fn(xa, ra, wr, wg, wu, wd, *br):
             tok = xa.reshape(-1, shape[-1])
             with jax.named_scope("moe/route"):
-                idx, w = sigmoid_group_route(
-                    tok, wr, br, n_group=self.n_group,
-                    topk_group=self.topk_group, top_k=self.top_k,
-                    scale=self.scale, norm_topk=self.norm_topk)
+                idx, w = self._route(ra.reshape(-1, shape[-1]), wr,
+                                     br[0] if biased else None)
             with jax.named_scope("moe/experts"):
                 out, per_expert, unheld = held_expert_sum(
                     tok, idx, w, wg, wu, wd, lo,
-                    None if valid is None else valid.reshape(-1))
+                    None if valid is None else valid.reshape(-1), act)
             return out.reshape(shape), per_expert, unheld
 
         out, per_expert, unheld = apply(
-            fn, x, self.router, self.router_bias, self.w_gate, self.w_up,
-            self.w_down, op_name="held_experts")
+            fn, x, x if router_input is None else router_input, self.router,
+            self.w_gate, self.w_up, self.w_down,
+            *((self.router_bias,) if biased else ()), op_name="held_experts")
         return out, {"moe_expert_tokens": per_expert,
                      "moe_unheld_tokens": unheld}

@@ -44,6 +44,11 @@ DEFAULT_SEP_STRIPE_TOKENS = 512
 _TELEMETRY = None      # lazily bound registry families
 
 
+#: ``SlotPagedKVCache``'s counters of its window groups
+_WINDOW_COUNTERS = ("window_blocks_released", "window_blocks_evicted",
+                    "prefix_hits_shortened_by_window")
+
+
 def _token_bucket(n, cap):
     """Pad a ragged tick's packed token batch to the next power of two
     (min 1, capped at the token budget). There is no floor: a
@@ -157,6 +162,16 @@ def _telemetry():
                 "paddle_kv_host_promotions_total",
                 "host-tier pages promoted back to device on an "
                 "admission hit (prefill work avoided)"),
+            "group_pages": r.gauge(
+                "paddle_kv_group_pages",
+                "pages of each KV page group of a model with window "
+                "layers (group=full|window<length>; kind=used|capacity)",
+                labels=("group", "kind")),
+            "window_events": r.counter(
+                "paddle_kv_window_events_total",
+                "window page groups: blocks released during a request, "
+                "cached window blocks evicted, prefix hits shortened for "
+                "want of a window tail (kind=...)", labels=("kind",)),
         }
     return _TELEMETRY
 
@@ -662,7 +677,8 @@ class ContinuousServingEngine:
                  spec_decode=None, spec_k=None, drafter=None,
                  draft_model=None, weight_dtype=None, draft_batch=None,
                  host_pool_mb=None, sep_prefill=None,
-                 sep_stripe_tokens=None, sep_threshold_tokens=None):
+                 sep_stripe_tokens=None, sep_threshold_tokens=None,
+                 window_num_pages=None):
         self.model = model
         # end-to-end int8 weights (PADDLE_WEIGHT_DTYPE=int8): every
         # nn.Linear swaps its weight for (int8, per-channel scale) and
@@ -773,6 +789,14 @@ class ContinuousServingEngine:
             if str(kv).lower() == "int8":
                 raise ValueError("sep prefill requires native KV pages "
                                  "(kv_dtype=int8 is unsupported)")
+        # layer groups: a model some of whose layers attend a sliding
+        # window (``model.kv_layer_windows``: None or a length a layer)
+        # gets one page group a distinct window beside the full layers'
+        # (``SlotPagedKVCache``); ``window_num_pages`` sizes it; the default
+        # holds every slot's window and one step's chunk
+        self.kv_windows = sorted({int(w) for w in getattr(
+            model, "kv_layer_windows", ()) if w})
+        self.window_groups = self._window_groups(window_num_pages)
         self.spec_drafted_tokens = 0
         self.spec_accepted_tokens = 0
         self.spec_rounds = 0           # verify spans with >= 1 draft
@@ -820,6 +844,41 @@ class ContinuousServingEngine:
         self.events: deque = deque(maxlen=4096)
         self._declare_programs()
 
+    def _window_groups(self, window_num_pages):
+        """``{window: pages}`` for the cache, or None for a model of full
+        layers alone; ``window_num_pages`` is ONE count, given to each
+        distinct window's group (no model here has two). What cannot yet
+        serve a windowed model refuses it here, loudly (docs/SERVING.md
+        lists them)."""
+        if not self.kv_windows:
+            if window_num_pages is not None:
+                raise ValueError("window_num_pages for a model that "
+                                 "declares no window layer")
+            return None
+        kv = self.kv_dtype
+        if kv is None:
+            kv = os.environ.get("PADDLE_KV_DTYPE", "auto")
+        refused = [name for name, on in (
+            ("speculative decoding (rollback)", self.enable_spec),
+            ("sep striping", self.sep_prefill_enabled),
+            ("the host KV tier", self.host_pool_mb > 0),
+            ("int8 KV pages", str(kv).lower() == "int8")) if on]
+        if refused:
+            raise NotImplementedError(
+                f"a model with window layers ({self.kv_windows}) is not "
+                f"served with: {', '.join(refused)}")
+        pages_per_seq = -(-self.max_len // self.page_size)
+        out = {}
+        for w in self.kv_windows:
+            n = window_num_pages
+            if n is None:
+                a_slot = min(pages_per_seq, -(-(
+                    w + min(self.chunk_tokens, self.token_budget))
+                    // self.page_size) + 2)
+                n = self.max_batch * a_slot + 1
+            out[w] = int(n)
+        return out
+
     def declared_token_buckets(self):
         """The ragged scheduler's full compiled-shape family: every tick's
         flat token batch is padded to one of these sizes, so the number
@@ -833,7 +892,7 @@ class ContinuousServingEngine:
         out.add(self.token_budget)
         return out
 
-    def declared_kernel_buckets(self, latent=False):
+    def declared_kernel_buckets(self, latent=False, window=None):
         """The q-block attention kernel's compiled-shape family: one
         program a (token bucket, job bucket). A tick's flat job list, one
         job a (q-block, KV page) pair
@@ -845,10 +904,13 @@ class ContinuousServingEngine:
         reach (``max_batch_size`` sequences of at most ``max_len`` tokens,
         pages shared or not), which :meth:`warmup_programs` compiles.
         ``latent``: the ladder of a latent (one-pool) layer's kernel,
-        whose lists are padded to powers of two from 64."""
+        whose lists are padded to powers of two from 64. ``window``: the
+        ladder of a sliding-window layer, whose (q-block, sequence) pairs
+        walk the window's pages at the most."""
         from ..ops.pallas.ragged_paged_attention import (
-            _qblock_rows, job_buckets)
-        pages_per_seq = -(-self.max_len // self.page_size)
+            _qblock_rows, job_buckets, window_pages)
+        pages_per_seq = window_pages(window, _qblock_rows(), self.page_size,
+                                     -(-self.max_len // self.page_size))
         return {b: job_buckets(b, _qblock_rows(), self.max_batch,
                                pages_per_seq, latent=latent)
                 for b in sorted(self.declared_token_buckets())}
@@ -956,7 +1018,8 @@ class ContinuousServingEngine:
         # both ladders: which kind of layer the model has shows only once
         # a forward has built its pools
         kernel = self.declared_kernel_buckets()
-        ladders = (kernel, self.declared_kernel_buckets(latent=True))
+        ladders = (kernel, self.declared_kernel_buckets(latent=True)) + tuple(
+            self.declared_kernel_buckets(window=w) for w in self.kv_windows)
         _co.declare_family(
             "serving.ragged_attention",
             buckets={"tokens": sorted(kernel),
@@ -1013,7 +1076,8 @@ class ContinuousServingEngine:
                     self.max_batch, page_size=self.page_size,
                     max_len=self.max_len, num_pages=self.num_pages,
                     enable_prefix_cache=False, kv_dtype=self.kv_dtype,
-                    allow_page_overcommit=self.sep_prefill_enabled)
+                    allow_page_overcommit=self.sep_prefill_enabled,
+                    window_groups=self.window_groups)
                 kernel = want("serving.ragged_attention")
                 if want("serving.ragged") or kernel:
                     t0 = time.perf_counter()
@@ -1161,19 +1225,23 @@ class ContinuousServingEngine:
         for layer, shape, dtype, sm_scale, value_dim in calls:
             pools = cache._pools[id(layer)]
             key = (shape, str(dtype), sm_scale, value_dim,
+                   getattr(layer, "kv_window", None),
                    tuple((p.shape, str(p.dtype)) for p in pools))
             if key in seen:
                 continue
             seen.add(key)
             q = jnp.zeros(shape, dtype)
-            ladder = self.declared_kernel_buckets(latent=len(pools) == 1)
+            window = getattr(layer, "kv_window", None)
+            ladder = self.declared_kernel_buckets(latent=len(pools) == 1,
+                                                  window=window)
             for jobs in ladder[tokens]:
                 t_run = time.perf_counter()
                 cache.ragged_attention(
                     layer, q, sm_scale, value_dim,
                     descriptors=warm_descriptors(
                         tokens, jobs, _qblock_rows(), self.page_size,
-                        pages_per_seq)).block_until_ready()
+                        pages_per_seq, window=window)
+                ).block_until_ready()
                 _co.observe("serving.ragged_attention",
                             self._kernel_signature(tokens, jobs),
                             seconds=time.perf_counter() - t_run)
@@ -1331,11 +1399,13 @@ class ContinuousServingEngine:
                                  kv_dtype=self.kv_dtype,
                                  host_pool=self._host_pool,
                                  allow_page_overcommit=(
-                                     self.sep_prefill_enabled))
+                                     self.sep_prefill_enabled),
+                                 window_groups=self.window_groups)
         # cache-scoped counter baselines reset with the cache (a rebuilt
         # cache restarts them at 0; pool-scoped baselines persist with
         # the engine-owned host pool)
-        self._kv_tier_seen.pop("dev_evict", None)
+        for key in ("dev_evict",) + _WINDOW_COUNTERS:
+            self._kv_tier_seen.pop(key, None)
         self._cache = cache           # flight-recorder / test introspection
         return cache
 
@@ -1344,14 +1414,8 @@ class ContinuousServingEngine:
         registry by the delta since the last mirror (counters must never
         regress even when the cache — and its counters — rebuild after a
         serve-loop error)."""
-        seen = self._kv_tier_seen
         hp = self._host_pool
-
-        def bump(key, cur, metric, **labels):
-            prev = seen.get(key, 0)
-            if cur > prev:
-                metric.inc(cur - prev, **labels)
-            seen[key] = cur
+        bump = self._bump
 
         bump("dev_evict", cache.prefix_evictions_device,
              tele["prefix_evictions"], tier="device")
@@ -1361,6 +1425,40 @@ class ContinuousServingEngine:
         bump("promote", hp.promotions, tele["host_promotions"])
         tele["host_pool_bytes"].set(hp.used_bytes, kind="used")
         tele["host_pool_bytes"].set(hp.max_bytes, kind="capacity")
+
+    def _bump(self, key, cur, metric, **labels):
+        """Inc ``metric`` by what counter ``key`` gained since it was last
+        mirrored."""
+        prev = self._kv_tier_seen.get(key, 0)
+        if cur > prev:
+            metric.inc(cur - prev, **labels)
+        self._kv_tier_seen[key] = cur
+
+    def _mirror_kv_groups(self, tele, cache):
+        """A windowed model's tick: a gauge a page group (pages used /
+        pages) and the window groups' three counters, as
+        :meth:`_mirror_kv_tier` mirrors the tier's."""
+        for label, used, pages in cache.group_usage():
+            tele["group_pages"].set(used, group=label, kind="used")
+            tele["group_pages"].set(pages, group=label, kind="capacity")
+        for key in _WINDOW_COUNTERS:
+            self._bump(key, getattr(cache, key), tele["window_events"],
+                       kind=key)
+
+    def kv_counters(self):
+        """The cache's counters that the engine's own do not carry:
+        prefix evictions and, for a windowed model, the window groups'
+        three and each group's pages (used, capacity). {} before the
+        first tick."""
+        cache = self._cache
+        if cache is None:
+            return {}
+        out = {"prefix_evictions_device": cache.prefix_evictions_device}
+        if self.window_groups:
+            out.update({key: getattr(cache, key) for key in _WINDOW_COUNTERS},
+                       group_pages={label: (used, pages) for label, used,
+                                    pages in cache.group_usage()})
+        return out
 
     def _sep_engaged(self, cache, prompt_tokens):
         """Route a prompt to sep-parallel prefill? Explicit threshold
@@ -1602,6 +1700,8 @@ class ContinuousServingEngine:
                     tele["pool_bytes"].set((cache.num_pages - 1) * page_nb,
                                            kind="capacity")
                     self._mirror_kv_tier(tele, cache)
+                    if self.window_groups:
+                        self._mirror_kv_groups(tele, cache)
                     self._sep_tick(cache, free, active, sep_q)
                     if not spans:
                         phase.discard()

@@ -25,6 +25,8 @@ from .mixtral import (MixtralConfig, MixtralModel, MixtralForCausalLM,
                       MixtralSparseMoeBlock, mixtral_8x7b, mixtral_tiny)
 from .deepseek_v3 import (DeepseekV3Config, DeepseekV3Model,
                           DeepseekV3ForCausalLM, deepseek_v3_tiny)
+from .smallthinker import (SmallThinkerConfig, SmallThinkerModel,
+                           SmallThinkerForCausalLM, smallthinker_tiny)
 
 __all__ = [
     "LlamaConfig", "LlamaModel", "LlamaForCausalLM",
@@ -34,6 +36,8 @@ __all__ = [
     "MixtralSparseMoeBlock", "mixtral_8x7b", "mixtral_tiny",
     "DeepseekV3Config", "DeepseekV3Model", "DeepseekV3ForCausalLM",
     "deepseek_v3_tiny",
+    "SmallThinkerConfig", "SmallThinkerModel", "SmallThinkerForCausalLM",
+    "smallthinker_tiny",
     "T5Config", "T5ForConditionalGeneration", "t5_tiny",
     "GPTConfig", "GPTModel", "GPTForCausalLM", "GPTForCausalLMPipe",
     "gpt3_1p3b", "gpt_tiny",

@@ -420,6 +420,35 @@ class PagedKVCache(KVCache):
         return apply(fn, q, op_name="paged_attention")
 
 
+class _PageGroup:
+    """The pages of the layers that share one ``window`` (None: layers of
+    full causal attention, which keep a sequence's every block): pools of
+    ``num_pages`` pages, a free list, refcounts, a prefix index (digest ->
+    page, in LRU order) and a block table a slot. A window group's table
+    has a slot's blocks at the same indices as the full group's; the
+    entries below ``first[slot]`` were released and are 0."""
+
+    def __init__(self, window, num_pages, max_batch, pages_per_seq):
+        from collections import deque, OrderedDict
+        self.window = None if window is None else int(window)
+        self.num_pages = int(num_pages)
+        self.free = deque(range(1, self.num_pages))
+        self.ref = np.zeros(self.num_pages, np.int32)
+        self.index = OrderedDict()
+        self.page_digest = {}
+        self.tables = np.zeros((max_batch, pages_per_seq), np.int32)
+        self.n_blocks = np.zeros(max_batch, np.int32)
+        self.first = np.zeros(max_batch, np.int32)
+
+    @property
+    def label(self):
+        return "full" if self.window is None else f"window{self.window}"
+
+    @property
+    def used(self):
+        return self.num_pages - 1 - len(self.free)
+
+
 class SlotPagedKVCache:
     """Per-slot paged KV cache over a SHARED refcounted page pool — the
     continuous-batching serving cache (reference: the vLLM-style block
@@ -443,11 +472,29 @@ class SlotPagedKVCache:
     there so it can never corrupt a page a request owns. Writes into a
     shared page (refcount > 1 or registered in the prefix index) trigger
     copy-on-write.
+
+    **Layer groups.** A model whose layers differ in what they may forget
+    says so a layer (``layer.kv_window``: None, or a sliding window's
+    length) and the cache is built with ``window_groups={window: pages}``:
+    the layers of one window share a :class:`_PageGroup` (pools of that
+    group's own page count, block table, free list, refcounts, prefix
+    index; the full layers' group is the first and is the whole cache of a
+    model that names no window). A window group gives a block back as
+    soon as no query at or past the slot's filled length can see any of
+    its tokens (after each step: :meth:`advance`); a block the prefix
+    index knows stays cached and evictable, any other returns to the free
+    list. A prefix hit of ``b`` blocks needs the chain ``[0, b)`` in the
+    full group AND, in each window group, every block that a query at
+    ``b * page_size`` still sees; else it is shortened to the longest
+    ``b`` for which both hold. Eviction is LRU within a group. With a
+    window group: no int8 pages, host tier, sep striping, page export /
+    import or rollback (each refuses).
     """
 
     def __init__(self, max_batch, page_size=16, max_len=2048,
                  num_pages=None, enable_prefix_cache=True, kv_dtype=None,
-                 host_pool=None, allow_page_overcommit=False):
+                 host_pool=None, allow_page_overcommit=False,
+                 window_groups=None):
         self.max_batch = int(max_batch)
         self.page_size = int(page_size)
         self.max_len = int(max_len)
@@ -478,19 +525,42 @@ class SlotPagedKVCache:
                 raise ValueError("num_pages must be >= 2")
         elif self.num_pages < self.pages_per_seq + 1:
             raise ValueError("num_pages must cover one full sequence")
-        from collections import deque, OrderedDict
-        self._free = deque(range(1, self.num_pages))
-        self._ref = np.zeros(self.num_pages, np.int32)
-        self._index = OrderedDict()       # block digest -> page (LRU order)
-        self._page_digest = {}            # page -> digest (registered)
+        # the full layers' group, then one group a window. The allocator's
+        # methods take a group; the names below are the full group's state
+        # for the paths that know one group alone (sep striping, export /
+        # import, the host tier: each refuses a cache with a window group)
+        # and for the callers outside that read them
+        full = _PageGroup(None, self.num_pages, self.max_batch,
+                          self.pages_per_seq)
+        self._groups = [full]
+        self._free = full.free
+        self._ref = full.ref
+        self._index = full.index          # block digest -> page (LRU order)
+        self._page_digest = full.page_digest    # page -> digest (registered)
+        self._tables = full.tables
+        self._n_blocks = full.n_blocks
+        if window_groups and (self.kv_quant or allow_page_overcommit):
+            raise NotImplementedError(
+                "a cache with a window group serves neither int8 pages nor "
+                "sep striping")
+        for window, pages in sorted((window_groups or {}).items()):
+            # what a slot holds at the most: the window, one step's new
+            # tokens up to the whole of it, the pages both ends straddle
+            if int(window) < 1 or int(pages) < min(
+                    self.pages_per_seq,
+                    2 * -(-int(window) // self.page_size) + 2) + 1:
+                raise ValueError(
+                    f"window group {window}: {pages} pages do not cover "
+                    "one slot's window and a step of new tokens")
+            self._groups.append(_PageGroup(window, pages, self.max_batch,
+                                           self.pages_per_seq))
+        self._pool_group = {}       # id(layer) -> its _PageGroup
+        self._group_idx = {}        # group -> a ragged step's index memo
         self._chain = [None] * self.max_batch   # per-slot block digests
         self._pools = {}            # id(layer) -> (k_pages, v_pages)
-        self._tables = np.zeros((self.max_batch, self.pages_per_seq),
-                                np.int32)
-        self._n_blocks = np.zeros(self.max_batch, np.int32)
         self.lens = np.zeros(self.max_batch, np.int32)   # filled ctx/slot
         self._mode = None            # ("ragged", spans) | ("sep_*", slot)
-        self._idx = None             # per-forward index memo
+        self._idx = None             # a sep step's index memo
         self._touched = None         # ... and ragged_touched_pages' own
         self._prefill_valid = None   # real tokens in a sep prefill chunk
         # prefix-cache statistics (mirrored into the telemetry registry
@@ -523,7 +593,16 @@ class SlotPagedKVCache:
         # engine passes its own long-lived pool so the warm tier
         # survives cache rebuilds.
         self.host_pool = host_pool if host_pool is not None else HostKVPool()
+        if len(self._groups) > 1 and self.host_pool.enabled:
+            raise NotImplementedError(
+                "the host KV tier does not serve a cache with a window "
+                "group")
         self.prefix_evictions_device = 0   # device-index LRU evictions
+        # window groups: blocks given back during a request, cached tails
+        # that eviction took, and prefix hits cut short for want of them
+        self.window_blocks_released = 0
+        self.window_blocks_evicted = 0
+        self.prefix_hits_shortened_by_window = 0
         self.host_demotions = 0            # evictions caught by the tier
         self.host_promotions = 0           # host hits moved back to device
         self.host_promote_rejects = 0      # dtype/geometry mismatch drops
@@ -540,36 +619,42 @@ class SlotPagedKVCache:
         self.sep_decode_steps = 0
 
     # -- page allocator ------------------------------------------------------
-    def _alloc_page(self):
-        if not self._free:
-            self._evict_lru()
-        if not self._free:
+    def _alloc_page(self, group=None):
+        g = group or self._groups[0]
+        if not g.free:
+            self._evict_lru(g)
+        if not g.free:
             raise RuntimeError(
-                f"KV page pool exhausted ({self.num_pages - 1} pages, all "
-                f"backing live sequences)")
-        page = self._free.popleft()
-        self._ref[page] = 1
+                f"KV page pool exhausted ({g.num_pages - 1} pages"
+                + ("" if g.window is None else f" of group {g.label}")
+                + ", all backing live sequences)")
+        page = g.free.popleft()
+        g.ref[page] = 1
         return int(page)
 
-    def _evict_lru(self):
-        """Reclaim the least-recently-used prefix-index entry whose page
-        has no live slot mapping (refcount 1 == the index's own ref).
-        With the host tier enabled the page's bytes are demoted there
+    def _evict_lru(self, group=None):
+        """Reclaim the least-recently-used prefix-index entry of a group
+        whose page has no live slot mapping (refcount 1 == the index's own
+        ref). With the host tier enabled the page's bytes are demoted there
         before the device page frees — the prefix survives device churn
         and a later :meth:`assign` promotes it back."""
+        g = group or self._groups[0]
         # the index is in LRU order: walk it from its old end, in place
         # (a copy of it a freed page was the cost of a full pool)
-        victim = next(((d, p) for d, p in self._index.items()
-                       if self._ref[p] == 1), None)
+        victim = next(((d, p) for d, p in g.index.items()
+                       if g.ref[p] == 1), None)
         if victim is None:
             return False
         digest, page = victim
-        self._demote(digest, page)
-        del self._index[digest]
-        del self._page_digest[page]
-        self._ref[page] = 0
-        self._free.append(page)
-        self.prefix_evictions_device += 1
+        if g.window is None:
+            self._demote(digest, page)
+            self.prefix_evictions_device += 1
+        else:
+            self.window_blocks_evicted += 1
+        del g.index[digest]
+        del g.page_digest[page]
+        g.ref[page] = 0
+        g.free.append(page)
         return True
 
     def _page_entry(self, page):
@@ -650,45 +735,71 @@ class SlotPagedKVCache:
         hp.promotions += 1
         return page
 
-    def _decref(self, page):
+    def _decref(self, page, group=None):
+        g = group or self._groups[0]
         page = int(page)
         if page == 0:
             return
-        if self._ref[page] <= 0:
+        if g.ref[page] <= 0:
             raise RuntimeError(f"page {page} refcount underflow")
-        self._ref[page] -= 1
-        if self._ref[page] == 0:
+        g.ref[page] -= 1
+        if g.ref[page] == 0:
             # registered pages always carry the index's ref, so zero
             # means the page is unreachable — back to the free list
-            self._free.append(page)
+            g.free.append(page)
 
     def _ensure_blocks(self, slot, tokens):
-        """Allocate fresh pages so ``slot`` can hold ``tokens`` context."""
+        """Allocate fresh pages so ``slot`` can hold ``tokens`` context,
+        in every group."""
         need = -(-int(tokens) // self.page_size)
-        for i in range(int(self._n_blocks[slot]), need):
-            self._tables[slot, i] = self._alloc_page()
-        if need > self._n_blocks[slot]:
-            self._n_blocks[slot] = need
+        for g in self._groups:
+            for i in range(int(g.n_blocks[slot]), need):
+                g.tables[slot, i] = self._alloc_page(g)
+            if need > g.n_blocks[slot]:
+                g.n_blocks[slot] = need
 
     def _make_writable(self, slot, blk):
         """Copy-on-write: writing into a block whose page is shared
         (mapped by another slot, or registered in the prefix index) must
         first copy the page so the sharer's content survives."""
-        page = int(self._tables[slot, blk])
-        if page == 0:
-            return
-        if self._ref[page] <= 1 and page not in self._page_digest:
-            return
-        new = self._alloc_page()
-        for key, pools in self._pools.items():
-            self._pools[key] = tuple(pool.at[:, new].set(pool[:, page])
-                                     for pool in pools)
-        for key, (ks, vs) in self._scales.items():
-            self._scales[key] = (ks.at[:, new].set(ks[:, page]),
-                                 vs.at[:, new].set(vs[:, page]))
-        self._decref(page)
-        self._tables[slot, blk] = new
-        self.cow_copies += 1
+        for g in self._groups:
+            page = int(g.tables[slot, blk])
+            if page == 0:
+                continue
+            if g.ref[page] <= 1 and page not in g.page_digest:
+                continue
+            new = self._alloc_page(g)
+            for key, pools in self._pools.items():
+                if self._pool_group[key] is g:
+                    self._pools[key] = tuple(
+                        pool.at[:, new].set(pool[:, page]) for pool in pools)
+            for key, (ks, vs) in self._scales.items():
+                self._scales[key] = (ks.at[:, new].set(ks[:, page]),
+                                     vs.at[:, new].set(vs[:, page]))
+            self._decref(page, g)
+            g.tables[slot, blk] = new
+            self.cow_copies += 1
+
+    def _group_of(self, layer):
+        """The group of a layer's pages, by the window it declares
+        (``layer.kv_window``; a layer that declares none is a full one)."""
+        window = getattr(layer, "kv_window", None)
+        for i, g in enumerate(self._groups):
+            if g.window == window:
+                return i
+        raise ValueError(
+            f"a layer declares kv_window {window} and the cache has the "
+            f"groups {[g.label for g in self._groups]}: build it with "
+            "window_groups={window: pages}")
+
+    def group_usage(self):
+        """``[(label, pages used, pages)]`` a group, the full one first."""
+        return [(g.label, g.used, g.num_pages - 1) for g in self._groups]
+
+    def _windowed(self, what):
+        if len(self._groups) > 1:
+            raise NotImplementedError(
+                f"{what} does not serve a cache with a window group")
 
     @property
     def free_page_count(self):
@@ -704,11 +815,12 @@ class SlotPagedKVCache:
         pools (and int8 scale arrays) — 0 until the first forward
         materializes the pools."""
         total = 0
-        for pools in self._pools.values():
-            total += sum(pool.nbytes for pool in pools)
+        for key, pools in self._pools.items():
+            total += (sum(pool.nbytes for pool in pools)
+                      // self._pool_group[key].num_pages)
         for ks, vs in self._scales.values():
-            total += ks.nbytes + vs.nbytes
-        return total // self.num_pages if total else 0
+            total += (ks.nbytes + vs.nbytes) // self.num_pages
+        return total
 
     def rollback(self, slot, n):
         """Truncate the last ``n`` context tokens of ``slot`` — the
@@ -726,6 +838,7 @@ class SlotPagedKVCache:
         n = int(n)
         if n <= 0:
             return 0
+        self._windowed("rollback (a released block cannot come back)")
         if n > int(self.lens[slot]):
             raise ValueError(f"rollback {n} > slot context "
                              f"{int(self.lens[slot])}")
@@ -769,6 +882,8 @@ class SlotPagedKVCache:
             self._ref[page] += 1
             self._tables[slot, i] = page
             matched += 1
+        if len(self._groups) > 1:
+            matched = self._match_windows(slot, chain, matched)
         self._n_blocks[slot] = matched
         cached = matched * self.page_size
         self.lens[slot] = cached
@@ -793,18 +908,84 @@ class SlotPagedKVCache:
         slot = int(slot)
         chain = self._chain[slot] or []
         registered = 0
-        for i, digest in enumerate(chain):
-            if i >= int(self._n_blocks[slot]):
-                break
-            page = int(self._tables[slot, i])
-            if digest in self._index or page == 0 \
-                    or page in self._page_digest:
-                continue
-            self._index[digest] = page
-            self._page_digest[page] = digest
-            self._ref[page] += 1          # the index's own reference
-            registered += 1
+        for g in self._groups:
+            for i in range(int(g.first[slot]),
+                           min(len(chain), int(g.n_blocks[slot]))):
+                registered += self._register(g, chain[i],
+                                             int(g.tables[slot, i]))
         return registered
+
+    def _register(self, g, digest, page):
+        """Enter a filled block in a group's prefix index (the index's own
+        reference); a digest or a page it already knows stays as it is."""
+        if digest in g.index or page == 0 or page in g.page_digest:
+            return 0
+        g.index[digest] = page
+        g.page_digest[page] = digest
+        g.ref[page] += 1
+        return 1
+
+    # -- window groups -------------------------------------------------------
+    def _window_first_live(self, g, filled):
+        """The first block of a window group that a query at a position
+        ``>= filled`` still sees: it sees keys ``j > i - window``, so from
+        ``filled - window + 1`` on."""
+        return max(int(filled) - g.window + 1, 0) // self.page_size
+
+    def _match_windows(self, slot, chain, matched):
+        """Cut a prefix hit of ``matched`` blocks (mapped in the full group
+        already) to the longest ``b`` whose window tails the window groups
+        hold, and map those: ``[first live block at b * page_size, b)``."""
+        groups = self._groups[1:]
+        runs = []      # a group: how many blocks in a row it has, ending at i
+        for g in groups:
+            run, out = 0, []
+            for i in range(matched):
+                run = run + 1 if chain[i] in g.index else 0
+                out.append(run)
+            runs.append(out)
+        b = matched
+        while b > 0 and not all(
+                r[b - 1] >= b - self._window_first_live(g, b * self.page_size)
+                for g, r in zip(groups, runs)):
+            b -= 1
+        if b < matched:
+            self.prefix_hits_shortened_by_window += 1
+            for i in range(b, matched):
+                self._decref(self._tables[slot, i])
+                self._tables[slot, i] = 0
+        for g in groups:
+            lo = self._window_first_live(g, b * self.page_size)
+            for i in range(lo, b):
+                page = g.index[chain[i]]
+                g.index.move_to_end(chain[i])          # LRU touch
+                g.ref[page] += 1
+                g.tables[slot, i] = page
+            g.first[slot], g.n_blocks[slot] = lo, b
+        return b
+
+    def _release_windows(self, slots):
+        """Give back, in each window group, the blocks of ``slots`` that no
+        later query can see (:meth:`_window_first_live` at the filled
+        length). A full prompt block enters the group's prefix index first
+        and so stays cached and evictable, as a finished request's pages
+        do; any other returns to the free list."""
+        released = 0
+        for g in self._groups[1:]:
+            for slot in slots:
+                keep = min(self._window_first_live(g, self.lens[slot]),
+                           int(g.n_blocks[slot]))
+                chain = self._chain[slot] or []
+                for blk in range(int(g.first[slot]), keep):
+                    page = int(g.tables[slot, blk])
+                    if blk < len(chain):
+                        self._register(g, chain[blk], page)
+                    self._decref(page, g)
+                    g.tables[slot, blk] = 0
+                    released += 1
+                g.first[slot] = max(int(g.first[slot]), keep)
+        self.window_blocks_released += released
+        return released
 
     def begin_ragged(self, spans):
         """Arm the next forward as ONE ragged mixed prefill+decode step
@@ -828,7 +1009,8 @@ class SlotPagedKVCache:
                                  -(-(start + n_new) // self.page_size)):
                     self._make_writable(slot, blk)
             self._mode = ("ragged", spans)
-            self._idx = self._touched = None
+            self._touched = None
+            self._group_idx = {}
 
     def free(self, slot):
         slot = int(slot)
@@ -836,10 +1018,11 @@ class SlotPagedKVCache:
         # table entries stay 0 and _decref(0) is a no-op, so one loop
         # covers both lifecycles
         self._sep[slot] = None
-        for i in range(int(self._n_blocks[slot])):
-            self._decref(self._tables[slot, i])
-        self._tables[slot, :] = 0
-        self._n_blocks[slot] = 0
+        for g in self._groups:
+            for i in range(int(g.first[slot]), int(g.n_blocks[slot])):
+                self._decref(g.tables[slot, i], g)
+            g.tables[slot, :] = 0
+            g.n_blocks[slot] = g.first[slot] = 0
         self.lens[slot] = 0
         self._chain[slot] = None
 
@@ -858,6 +1041,7 @@ class SlotPagedKVCache:
         in the host tier — a demoted block still hands off (read-only,
         no promotion), so the disagg path survives device churn. The
         blob reports how many blocks came from host as ``host_pages``."""
+        self._windowed("export_pages")
         entries, out_digests, host_pages = [], [], 0
         hp = self.host_pool
         for d in digests:
@@ -914,6 +1098,7 @@ class SlotPagedKVCache:
         prompt sharing the chain maps straight onto them. Digests
         already registered are skipped — first writer wins. Returns the
         number of pages imported."""
+        self._windowed("import_pages")
         if not blob or not self.enable_prefix_cache:
             return 0
         if int(blob["page_size"]) != self.page_size:
@@ -982,6 +1167,7 @@ class SlotPagedKVCache:
         Only the trailing partial chunk and the decode tail land in
         device pages. No prefix-index interaction: a striped span is not
         page-granular shareable."""
+        self._windowed("sep striping")
         slot = int(slot)
         self.free(slot)
         n = int(prompt_tokens)
@@ -1168,6 +1354,10 @@ class SlotPagedKVCache:
         elif mode == "ragged":
             for slot, _, n_new in arg:
                 self.lens[slot] += n_new
+            if len(self._groups) > 1:
+                with _spans.span("kv/release_window") as sp:
+                    sp.set(blocks=self._release_windows(
+                        [slot for slot, _, _ in arg]))
         else:                   # "sep_decode" slot
             self.lens[arg] += 1
 
@@ -1185,9 +1375,11 @@ class SlotPagedKVCache:
         key = id(layer)
         if key not in self._pools:
             li = len(self._pools)       # this layer's forward-order index
-            shape = ((kv_heads, self.num_pages, d, self.page_size)
+            group = self._groups[self._group_of(layer)]
+            self._pool_group[key] = group
+            shape = ((kv_heads, group.num_pages, d, self.page_size)
                      if latent else
-                     (kv_heads, self.num_pages, self.page_size, d))
+                     (kv_heads, group.num_pages, self.page_size, d))
             if latent and self.kv_quant:
                 raise NotImplementedError(
                     "int8 pages for a latent pool are not built")
@@ -1257,36 +1449,42 @@ class SlotPagedKVCache:
         """True between :meth:`begin_ragged` and the next ``begin_*``."""
         return self._mode is not None and self._mode[0] == "ragged"
 
-    def _ragged_index(self, s):
+    def _ragged_index(self, s, group=0):
         """The armed step's indices over a flat batch of ``s`` tokens,
-        built once a forward and shared by every layer: the scatter's
-        ``page_ids`` / ``slot_ids`` on the device, and the kernel's
-        descriptors as HOST arrays (block tables, then slot, q_start,
-        q_len and context length a span) — the q-block schedule is built
-        from them on the host, so a device copy would only be read back."""
-        if self._idx is None:
-            spans = self._mode[1]
-            page_ids = np.zeros(s, np.int64)     # default: scratch
-            slot_ids = np.zeros(s, np.int64)
-            for slot, qs, n_new in spans:
-                pos = np.arange(self.lens[slot], self.lens[slot] + n_new)
-                page_ids[qs:qs + n_new] = \
-                    self._tables[slot, pos // self.page_size]
-                slot_ids[qs:qs + n_new] = pos % self.page_size
-            self._idx = (
-                jnp.asarray(page_ids), jnp.asarray(slot_ids),
-                self._tables.copy(),
-                np.asarray([sl for sl, _, _ in spans], np.int32),
-                np.asarray([qs for _, qs, _ in spans], np.int32),
-                np.asarray([n for _, _, n in spans], np.int32),
-                np.asarray([int(self.lens[sl]) + n for sl, _, n in spans],
-                           np.int32))
-        return self._idx
+        built once a forward and group and shared by the group's layers:
+        the scatter's ``page_ids`` / ``slot_ids`` on the device, and the
+        kernel's descriptors as HOST arrays (block tables, then slot,
+        q_start, q_len and context length a span) — the q-block schedule is
+        built from them on the host, so a device copy would only be read
+        back."""
+        if group not in self._group_idx:
+            self._group_idx[group] = self._build_ragged_index(
+                s, self._groups[group])
+        return self._group_idx[group]
 
-    def ragged_scatter_ids(self, s):
+    def _build_ragged_index(self, s, g):
+        spans = self._mode[1]
+        page_ids = np.zeros(s, np.int64)     # default: scratch
+        slot_ids = np.zeros(s, np.int64)
+        for slot, qs, n_new in spans:
+            pos = np.arange(self.lens[slot], self.lens[slot] + n_new)
+            page_ids[qs:qs + n_new] = g.tables[slot, pos // self.page_size]
+            slot_ids[qs:qs + n_new] = pos % self.page_size
+        return (
+            jnp.asarray(page_ids), jnp.asarray(slot_ids),
+            g.tables.copy(),
+            np.asarray([sl for sl, _, _ in spans], np.int32),
+            np.asarray([qs for _, qs, _ in spans], np.int32),
+            np.asarray([n for _, _, n in spans], np.int32),
+            np.asarray([int(self.lens[sl]) + n for sl, _, n in spans],
+                       np.int32))
+
+    def ragged_scatter_ids(self, s, layer=None):
         """``(page_ids, slot_ids)`` [s]: where the armed step's tokens
-        land in the pools; bucket padding lands in the scratch page."""
-        return self._ragged_index(s)[:2]
+        land in the pools (of ``layer``'s group); bucket padding lands in
+        the scratch page."""
+        group = 0 if layer is None else self._group_of(layer)
+        return self._ragged_index(s, group)[:2]
 
     def ragged_touched_pages(self, s):
         """``(pages [n], row_page [s])`` for a latent pool's scatter
@@ -1322,8 +1520,9 @@ class SlotPagedKVCache:
         if self.attention_calls is not None:
             self.attention_calls.append(
                 (layer, qa.shape, qa.dtype, sm_scale, value_dim))
+        group = self._group_of(layer)
         tables, seq_slots, q_starts, q_lens, ctx_lens = (
-            descriptors or self._ragged_index(qa.shape[0])[2:])
+            descriptors or self._ragged_index(qa.shape[0], group)[2:])
         pools = self._pools[id(layer)]
         k_pages, v_pages = pools if len(pools) == 2 else (pools[0], None)
         ksc, vsc = self._layer_scales(layer)
@@ -1331,7 +1530,8 @@ class SlotPagedKVCache:
             qa, k_pages, v_pages, tables, seq_slots, q_starts, q_lens,
             ctx_lens, sm_scale=sm_scale, value_dim=value_dim,
             k_scales=ksc, v_scales=vsc,
-            interpret=jax.default_backend() != "tpu")
+            interpret=jax.default_backend() != "tpu",
+            window=self._groups[group].window)
 
     def attend_latent(self, layer, q, row, sm_scale, value_dim):
         """Eager attention of a latent layer: ``q`` [1, s, heads, d] (the
@@ -1490,7 +1690,7 @@ class SlotPagedKVCache:
         # from the pages — causal masking inside each span comes from
         # the kernel's per-token context bound.
         assert b == 1, "ragged step packs one flat token batch"
-        page_ids, slot_ids = self.ragged_scatter_ids(s)
+        page_ids, slot_ids = self.ragged_scatter_ids(s, layer)
         kt = jnp.moveaxis(ka[0], 1, 0)          # [kv, s, d]
         vt = jnp.moveaxis(va[0], 1, 0)
         self._scatter(layer, k_pages, v_pages, kt, vt, page_ids,

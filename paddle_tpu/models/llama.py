@@ -366,7 +366,7 @@ class RaggedLayerPrograms:
                 return spec(self._kv_dtype[key])
             return attn.num_kv_heads, attn.head_dim, self._kv_dtype[key]
 
-        page_ids, slot_ids = cache.ragged_scatter_ids(hidden.shape[1])
+        page_ids, slot_ids = cache.ragged_scatter_ids(hidden.shape[1], layer)
         pools = cache.layer_pools(layer, kv_spec)
         # a latent layer's one pool is written a touched page at a time
         touched = (cache.ragged_touched_pages(hidden.shape[1])

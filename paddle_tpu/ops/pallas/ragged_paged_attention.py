@@ -66,7 +66,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...profiler import spans as _spans
-from .paged_attention import NEG_INF, _device_call, _scale_rows
+from .flash_attention import _jit_unless_interpret
+from .paged_attention import NEG_INF, _scale_rows
 
 #: finite cross-span mask for the q-block kernel. The causal bound keeps
 #: NEG_INF (= -inf, the decode kernel's mask, on a row's own pages); keys
@@ -145,13 +146,25 @@ def job_bucket(total, latent=False):
     return min(b, MAX_JOBS)
 
 
+def window_pages(window, q_block, page_size, pages_per_seq):
+    """The most pages a (q-block, sequence) pair walks under a ``window``
+    (None: the whole table): its rows' bounds lie within ``q_block`` of
+    each other, so the keys any of them sees are ``window + q_block - 1``
+    neighbours, which touch one page more than they fill."""
+    if window is None:
+        return int(pages_per_seq)
+    return min(int(pages_per_seq),
+               (int(window) + int(q_block) - 2) // int(page_size) + 2)
+
+
 def job_buckets(num_tokens, q_block, max_seqs, pages_per_seq, latent=False):
     """Every :func:`job_bucket` a call over ``num_tokens`` tokens can land
     in, whatever its descriptors: a sequence's span is contiguous, so a
     call of ``b`` q-blocks and at most ``max_seqs`` sequences has at most
     ``b + max_seqs`` (block, sequence) pairs (and at most one a token), each
-    of at most ``pages_per_seq`` jobs, and at least one job a block. The
-    ladder stops at ``MAX_JOBS``: a call beyond it is refused."""
+    of at most ``pages_per_seq`` jobs (a windowed layer's caller gives
+    :func:`window_pages`), and at least one job a block. The ladder stops
+    at ``MAX_JOBS``: a call beyond it is refused."""
     blocks = -(-int(num_tokens) // q_block)
     most = min(int(num_tokens), blocks + int(max_seqs)) * int(pages_per_seq)
     top = job_bucket(min(most, MAX_JOBS), latent)
@@ -161,14 +174,20 @@ def job_buckets(num_tokens, q_block, max_seqs, pages_per_seq, latent=False):
     return out
 
 
-def warm_descriptors(num_tokens, jobs, q_block, page_size, pages_per_seq):
+def warm_descriptors(num_tokens, jobs, q_block, page_size, pages_per_seq,
+                     window=None):
     """Descriptors ``(block_tables, seq_slots, q_starts, q_lens,
     context_lens)`` of a call over ``num_tokens`` tokens whose flat list has
     exactly ``jobs`` jobs (clipped to what that many tokens can hold), for
     warming the compiled program of a job bucket through the public op:
     single-token spans of sequences of their own, the first token of every
-    q-block among them, over tables that point at page 0."""
+    q-block among them, over tables that point at page 0. Under a
+    ``window`` (a multiple of the page) a single row walks at most
+    ``window // page_size + 1`` pages, and its context is the shortest
+    that needs its pages, so that none of them falls behind the window."""
     t, p = int(num_tokens), int(pages_per_seq)
+    if window is not None:
+        p = min(p, int(window) // int(page_size) + 1)
     pages = np.zeros(t, np.int64)
     pages[::q_block] = 1                    # no q-block without a job
     room = p - pages
@@ -176,13 +195,15 @@ def warm_descriptors(num_tokens, jobs, q_block, page_size, pages_per_seq):
     filled = np.minimum(np.cumsum(room), left)
     pages += np.diff(filled, prepend=0)
     rows = np.flatnonzero(pages).astype(np.int32)
+    ctx = pages[rows] * page_size
+    if window is not None:
+        ctx -= page_size - 1
     return (np.zeros((t, p), np.int32), rows, rows,
-            np.ones(len(rows), np.int32),
-            (pages[rows] * page_size).astype(np.int32))
+            np.ones(len(rows), np.int32), ctx.astype(np.int32))
 
 
 def qblock_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
-                    block_tables, q_block, page_size):
+                    block_tables, q_block, page_size, window=None):
     """Host-side schedule of both q-block kernels, vectorized: the flat
     packed batch is tiled into ``q_block``-row blocks over the cumulative
     span offsets, and the "jobs" are one (q-block, physical page, owner
@@ -194,6 +215,12 @@ def qblock_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
     a sequence's pages ascend, so each row meets its own pages in
     ascending order, as the decode kernel does.
 
+    ``window`` (a sliding-window layer: a row at position ``i`` sees keys
+    ``j > i - window``): a (q-block, sequence) pair's pages start at the
+    page of the first key its FIRST row in the block can see, and no job
+    is emitted for a page wholly behind it. None: the list of a full
+    layer, as ever.
+
     Sentinels: rows outside every span (bucket and block padding) get slot
     -1 / ctx 0 and own no job; a block without jobs gets one that matches
     nothing (slot -2, page 0), so that its output is written. -1 and -2
@@ -202,6 +229,15 @@ def qblock_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
     Returns ``(row_slot [B*q_block], row_ctx [B*q_block], jobs [4, n])``
     int32 numpy; ``jobs`` rows are (q-block, physical page, owner slot,
     kv offset), and ``n`` is the list's own length: no padding."""
+    return _qblock_jobs(num_tokens, seq_slots, q_starts, q_lens, context_lens,
+                        block_tables, q_block, page_size, window)[:3]
+
+
+def _qblock_jobs(num_tokens, seq_slots, q_starts, q_lens, context_lens,
+                 block_tables, q_block, page_size, window=None):
+    """:func:`qblock_job_list` and, fourth, how many jobs the same call
+    would have walked with no lower bound (the list's own length where
+    ``window`` is None)."""
     ss = np.asarray(seq_slots, np.int64).reshape(-1)
     qs = np.asarray(q_starts, np.int64).reshape(-1)
     ql = np.asarray(q_lens, np.int64).reshape(-1)
@@ -233,38 +269,55 @@ def qblock_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
                           (pair_block + 1) * qb) - 1
     cmax = (cl - ql)[pair_span] + (last_row - qs[pair_span]) + 1
     n_pages = np.clip(-(-cmax // page_size), 1, pages_per_seq)
+    if window is None:
+        first_page = np.zeros(len(pair_span), np.int64)
+    else:
+        # ... and start at the first key the span's FIRST row in the block
+        # sees: a row whose bound is ``c`` sees positions ``c - window ..``
+        first_row = np.maximum(qs[pair_span], pair_block * qb)
+        cmin = (cl - ql)[pair_span] + (first_row - qs[pair_span]) + 1
+        first_page = np.minimum(np.maximum(cmin - int(window), 0)
+                                // page_size, n_pages - 1)
     pair_slot = ss[pair_span]
     # a slot met twice in one block (two spans of one sequence) is walked
-    # once, as far as its longest bound
+    # once, from its nearest lower bound as far as its longest bound
     key = pair_block * (int(tbl.shape[0]) + 1) + pair_slot
     uniq, inv = np.unique(key, return_inverse=True)
     if len(uniq) != len(key):
         pages = np.zeros(len(uniq), np.int64)
         np.maximum.at(pages, inv, n_pages)
+        lows = np.full(len(uniq), pages_per_seq, np.int64)
+        np.minimum.at(lows, inv, first_page)
         first = np.zeros(len(uniq), np.int64)
         first[inv[::-1]] = np.arange(len(key))[::-1]
         keep = np.argsort(first)            # order of first appearance
-        pair_block, pair_slot, n_pages = (pair_block[first[keep]],
-                                          pair_slot[first[keep]],
-                                          pages[keep])
+        pair_block, pair_slot, n_pages, first_page = (
+            pair_block[first[keep]], pair_slot[first[keep]], pages[keep],
+            lows[keep])
     empty = np.setdiff1d(np.arange(nblocks), pair_block)
     pair_block = np.concatenate([pair_block, empty])
     pair_slot = np.concatenate([pair_slot, np.full(len(empty), -2)])
     n_pages = np.concatenate([n_pages, np.ones(len(empty), np.int64)])
+    first_page = np.concatenate([first_page, np.zeros(len(empty), np.int64)])
     order = np.argsort(pair_block, kind="stable")
-    pair_block, pair_slot, n_pages = (pair_block[order], pair_slot[order],
-                                      n_pages[order])
+    pair_block, pair_slot, n_pages, first_page = (
+        pair_block[order], pair_slot[order], n_pages[order],
+        first_page[order])
 
+    unwindowed = int(n_pages.sum())
+    n_pages = n_pages - first_page
     total = int(n_pages.sum())
     jobs = np.empty((4, total), np.int32)
     page_idx = np.arange(total) - np.repeat(np.cumsum(n_pages) - n_pages,
                                             n_pages)
+    if window is not None:
+        page_idx = page_idx + np.repeat(first_page, n_pages)
     slot = np.repeat(pair_slot, n_pages)
     jobs[0] = np.repeat(pair_block, n_pages)
     jobs[1] = np.where(slot >= 0, tbl[np.maximum(slot, 0), page_idx], 0)
     jobs[2] = slot
     jobs[3] = page_idx * page_size
-    return row_slot, row_ctx, jobs
+    return row_slot, row_ctx, jobs, unwindowed
 
 
 def _padded_jobs(jobs, length, count=False):
@@ -298,12 +351,19 @@ def latent_job_list(num_tokens, seq_slots, q_starts, q_lens, context_lens,
         jobs, job_bucket(jobs.shape[1], latent=True))
 
 
-def _qblock_masked_scores(s, kv_start, jslot, row_slot, row_ctx):
+def _qblock_masked_scores(s, kv_start, jslot, row_slot, row_ctx,
+                          window=None):
     """Causal bound with NEG_INF (the decode kernel's mask, on a row's
     own pages), then the whole row to finite BIG_NEG wherever
-    the row's sequence does not own this job's page."""
+    the row's sequence does not own this job's page. ``window`` (static):
+    the keys behind ``row_ctx - window`` as well, with the finite mask: a
+    later row of a q-block sees nothing in the pages that only the block's
+    first row still reaches, and a first page of -inf alone would leave
+    NaN in its running maximum (see ``BIG_NEG``)."""
     pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(pos < row_ctx, s, NEG_INF)
+    if window is not None:
+        s = jnp.where(pos >= row_ctx - window, s, BIG_NEG)
     return jnp.where(row_slot == jslot, s, BIG_NEG)
 
 
@@ -338,7 +398,7 @@ def _job_walk(jobs_ref, num_jobs, m_ref, l_ref, acc_ref, step, finalize,
 
 
 def _qblock_kernel(jobs_ref, rows_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
-                   quant, unroll):
+                   quant, unroll, window=None):
     """One grid step: a q-block's rows (``q_block`` tokens x all query
     heads) against one KV page, EVERY KV head of it: the page block is
     ``[kv_heads, 1, page_size, d]`` and each head runs the decode
@@ -366,7 +426,8 @@ def _qblock_kernel(jobs_ref, rows_ref, q_ref, k_ref, v_ref, *rest, sm_scale,
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            s = _qblock_masked_scores(s, kv_start, jslot, row_slot, row_ctx)
+            s = _qblock_masked_scores(s, kv_start, jslot, row_slot, row_ctx,
+                                      window)
             m_prev = m_ref[h][:, :1]                   # [Qg, 1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             w = jnp.exp(s - m_new)
@@ -396,7 +457,8 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
                                           q_starts, q_lens, context_lens,
                                           *, sm_scale, interpret,
                                           k_scales=None, v_scales=None,
-                                          q_block=None, value_dim=None):
+                                          q_block=None, value_dim=None,
+                                          window=None):
     """The q-block kernel's entry: grid ``(jobs,)`` over the flat packed
     batch — one grid step covers ``q_block`` tokens (all their heads)
     against one KV page, and the grid is the list of such (q-block, page)
@@ -412,22 +474,32 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
     apart. The ``attn/qblock`` span says how far the list fills the grid:
     ``jobs`` (the grid steps of one KV head's walk), ``real_jobs`` (those
     with an owner), ``steps`` (grid steps the call runs, over all grid
-    axes) and ``blocks``."""
+    axes) and ``blocks``; a windowed layer's call also ``window`` and
+    ``jobs_without_window`` (what the same call would have walked with no
+    lower bound)."""
     tokens = q.shape[0]
     qb = q_block or _qblock_rows()
     latent = v_pages is None
     page_size = k_pages.shape[3 if latent else 2]   # latent: tokens = columns
-    build = latent_job_list if latent else qblock_job_list
+    if latent and window is not None:
+        raise NotImplementedError("the latent kernel takes no window")
     args = {"latent": 1} if latent else {}
     with _spans.span("attn/qblock", **args) as sp:
         with _spans.span("attn/qblock_schedule", **args):
-            row_slot, row_ctx, jobs = build(
-                tokens, seq_slots, q_starts, q_lens, context_lens,
-                block_tables, qb, page_size)
+            if latent:
+                row_slot, row_ctx, jobs = latent_job_list(
+                    tokens, seq_slots, q_starts, q_lens, context_lens,
+                    block_tables, qb, page_size)
+            else:
+                row_slot, row_ctx, jobs, unwindowed = _qblock_jobs(
+                    tokens, seq_slots, q_starts, q_lens, context_lens,
+                    block_tables, qb, page_size, window)
         n = jobs.shape[1]                   # the grid, either kernel's
         if sp is not _spans.NULL:           # counted only where recorded
             sp.set(jobs=n, blocks=row_slot.shape[0] // qb,
                    real_jobs=int((jobs[2] >= 0).sum()), steps=n)
+            if window is not None:
+                sp.set(window=int(window), jobs_without_window=unwindowed)
         if latent:
             return _latent_call(jobs, row_slot, row_ctx, q, k_pages,
                                 sm_scale, interpret, value_dim, qb)
@@ -435,7 +507,8 @@ def _ragged_paged_attention_pallas_qblock(q, k_pages, v_pages,
             _padded_jobs(jobs, job_bucket(n) + 1, count=True),
             np.stack([row_slot, row_ctx]).reshape(2, -1, qb), q, k_pages,
             v_pages, k_scales, v_scales, sm_scale=sm_scale,
-            interpret=interpret)
+            interpret=interpret,
+            window=None if window is None else int(window))
 
 
 def _spread_rows(a, rows_a_token):
@@ -448,15 +521,16 @@ def _spread_rows(a, rows_a_token):
     return jnp.broadcast_to(a[..., None], a.shape + (128,))
 
 
-@_device_call
+@_jit_unless_interpret(static_argnames=("sm_scale", "interpret", "window"))
 def _qblock_device(jobs, rows, q, k_pages, v_pages, k_scales, v_scales, *,
-                   sm_scale, interpret):
+                   sm_scale, interpret, window=None):
     """Device half of the q-block tier: the schedule arrives as two arrays
     (``jobs`` [4, J + 1], scalar-prefetched: the list and at ``[3, J]`` its
     own length; ``rows`` [2, B, q_block]: slot and context bound, one value
     a token). The grid is ``(jobs[3, J],)``, a bound read on the device, so
     one compiled program serves every tick of a (tokens,
-    :func:`job_bucket`) shape and walks no padding."""
+    :func:`job_bucket`) shape and walks no padding. ``window`` is static:
+    a sliding-window layer's program masks the keys behind it too."""
     tokens, heads, d = q.shape
     kv_heads, _, page_size, _ = k_pages.shape
     group = heads // kv_heads
@@ -469,7 +543,8 @@ def _qblock_device(jobs, rows, q, k_pages, v_pages, k_scales, v_scales, *,
 
     quant = k_scales is not None
     kernel = functools.partial(_qblock_kernel, sm_scale=sm_scale,
-                               quant=quant, unroll=not interpret)
+                               quant=quant, unroll=not interpret,
+                               window=window)
     page_spec = pl.BlockSpec((kv_heads, 1, page_size, d),
                              lambda j, jobs: (0, jobs[1, j], 0, 0))
     scale_spec = pl.BlockSpec((kv_heads, 1, 1, page_size),
@@ -609,7 +684,7 @@ def _latent_call(jobs, row_slot, row_ctx, q, kv_pages, sm_scale, interpret,
 
 def _ragged_paged_attention_xla(q, k_pages, v_pages, block_tables,
                                 tok_slot, tok_ctx, *, sm_scale,
-                                k_scales=None, v_scales=None):
+                                k_scales=None, v_scales=None, window=None):
     """Vectorized jittable XLA tier: gather each token's sequence pages
     as dense KV (dequantized when int8 row scales are given), then
     masked softmax-attention. O(tokens * S_max) HBM: what runs where the
@@ -630,8 +705,11 @@ def _ragged_paged_attention_xla(q, k_pages, v_pages, block_tables,
     qb = (q * sm_scale).reshape(tokens, kv_heads, group, d)
     s = jnp.einsum("tkgd,tksd->tkgs", qb.astype(jnp.float32),
                    ks.astype(jnp.float32))
-    valid = (jnp.arange(ks.shape[2])[None, :]
-             < jnp.asarray(tok_ctx, jnp.int32)[:, None])
+    key_pos = jnp.arange(ks.shape[2])[None, :]
+    ctx = jnp.asarray(tok_ctx, jnp.int32)[:, None]
+    valid = key_pos < ctx
+    if window is not None:
+        valid &= key_pos >= ctx - window
     s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
     w = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("tkgs,tksd->tkgd", w, vs.astype(jnp.float32))
@@ -641,7 +719,7 @@ def _ragged_paged_attention_xla(q, k_pages, v_pages, block_tables,
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_slots,
                            q_starts, q_lens, context_lens, *,
                            sm_scale=None, k_scales=None, v_scales=None,
-                           value_dim=None, interpret=False):
+                           value_dim=None, interpret=False, window=None):
     """Mixed prefill+decode attention over a shared paged KV cache.
 
     A LATENT pool is ``k_pages`` [1, num_pages, d, page_size] (a page's
@@ -662,6 +740,12 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_slots,
     context_lens    [nseq] int32 — total context incl. this span
     k_scales/v_scales [kv_heads, num_pages, page_size] f32 — per-row
                     dequant scales for int8 pages (None = native pages)
+    window          None, or a sliding-window layer's length: the query
+                    at position ``i`` sees keys ``i - window < j <= i``
+                    (``window`` keys with its own; the Mistral
+                    convention). Pages wholly behind every row's window
+                    are neither walked nor read, so their block-table
+                    entries may be 0 (``SlotPagedKVCache`` releases them)
     -> [tokens, heads, head_dim]; rows outside every span are garbage.
 
     Concrete descriptors and block tables run the q-block kernel, whose
@@ -674,11 +758,12 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_slots,
     traced = any(isinstance(v, jax.core.Tracer) for v in (
         seq_slots, q_starts, q_lens, context_lens, block_tables))
     if v_pages is None:
-        if traced or value_dim is None or k_scales is not None:
+        if traced or value_dim is None or k_scales is not None \
+                or window is not None:
             raise NotImplementedError(
                 "a latent pool is read by the q-block kernel alone: "
                 "concrete descriptors (not jit tracers), a value_dim, "
-                "native pages")
+                "native pages, no window")
         return _ragged_paged_attention_pallas_qblock(
             q, k_pages, None, block_tables, seq_slots, q_starts, q_lens,
             context_lens, sm_scale=sm_scale, interpret=interpret,
@@ -687,19 +772,21 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, seq_slots,
         return _ragged_paged_attention_pallas_qblock(
             q, k_pages, v_pages, block_tables, seq_slots, q_starts, q_lens,
             context_lens, sm_scale=sm_scale, interpret=interpret,
-            k_scales=k_scales, v_scales=v_scales)
+            k_scales=k_scales, v_scales=v_scales, window=window)
     tok_slot, tok_ctx = _token_descriptors(tokens, seq_slots, q_starts,
                                            q_lens, context_lens)
     return _ragged_paged_attention_xla(
         q, k_pages, v_pages, block_tables, tok_slot, tok_ctx,
-        sm_scale=sm_scale, k_scales=k_scales, v_scales=v_scales)
+        sm_scale=sm_scale, k_scales=k_scales, v_scales=v_scales,
+        window=window)
 
 
 def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,
                                      seq_slots, q_starts, q_lens,
-                                     context_lens):
+                                     context_lens, window=None):
     """Dense numpy-style oracle: per sequence, gather its context from
-    the pages and run plain causal softmax attention for its span. Rows
+    the pages and run plain causal softmax attention for its span (over
+    the last ``window`` keys a query where a window is given). Rows
     outside every span are zero."""
     tokens, heads, d = q.shape
     kv_heads, _, page_size, _ = k_pages.shape
@@ -718,11 +805,12 @@ def ragged_paged_attention_reference(q, k_pages, v_pages, block_tables,
                               for p in range(n_pages)], axis=1)[:, :ctx]
         for j in range(ql):
             vis = ctx - ql + j + 1                 # causal inside the span
+            low = 0 if window is None else max(vis - int(window), 0)
             qb = q[qs + j].reshape(kv_heads, group, d).astype(jnp.float32)
             s = jnp.einsum("kgd,ksd->kgs", qb,
-                           ks[:, :vis].astype(jnp.float32)) / math.sqrt(d)
+                           ks[:, low:vis].astype(jnp.float32)) / math.sqrt(d)
             w = jax.nn.softmax(s, axis=-1)
             o = jnp.einsum("kgs,ksd->kgd", w,
-                           vs[:, :vis].astype(jnp.float32))
+                           vs[:, low:vis].astype(jnp.float32))
             out[qs + j] = np.asarray(o.reshape(heads, d))
     return jnp.asarray(out).astype(q.dtype)

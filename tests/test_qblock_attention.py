@@ -447,11 +447,11 @@ def test_engine_qblock_vs_token_bit_identical(model, monkeypatch):
     got_qb = run()
 
     def xla_entry(q, kp, vp, tbl, ss, qs, ql, cl, *, sm_scale, interpret,
-                  k_scales=None, v_scales=None):
+                  k_scales=None, v_scales=None, window=None):
         ts, tc = _token_descriptors(q.shape[0], ss, qs, ql, cl)
         return _ragged_paged_attention_xla(
             q, kp, vp, tbl, ts, tc, sm_scale=sm_scale, k_scales=k_scales,
-            v_scales=v_scales)
+            v_scales=v_scales, window=window)
 
     monkeypatch.setattr(rpa, "_ragged_paged_attention_pallas_qblock",
                         xla_entry)
@@ -604,7 +604,7 @@ def test_warmup_compiles_the_declared_kernel_family(model):
         return out
 
     lengths = []
-    build = rpa.qblock_job_list
+    build = rpa._qblock_jobs
 
     def lists(*a, **kw):
         out = build(*a, **kw)
@@ -612,7 +612,7 @@ def test_warmup_compiles_the_declared_kernel_family(model):
         return out
 
     rpa._ragged_paged_attention_pallas_qblock = spy
-    rpa.qblock_job_list = lists
+    rpa._qblock_jobs = lists
     try:
         took = eng.warmup_programs()
         warmed = set(zip(seen, lengths))
@@ -624,7 +624,7 @@ def test_warmup_compiles_the_declared_kernel_family(model):
         assert seen and set(zip(seen, lengths)) <= warmed
     finally:
         rpa._ragged_paged_attention_pallas_qblock = entry
-        rpa.qblock_job_list = build
+        rpa._qblock_jobs = build
 
 
 # ---------------------------------------------------------------------------

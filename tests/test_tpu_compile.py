@@ -279,6 +279,56 @@ def test_qblock_device_half_at_the_cells_widths_and_largest_job_bucket(
                 if re.search(r"= \w+" + shape + r"\S* (copy|transpose)\(", ln)]
 
 
+# -- the device half at serve_mixed_window_closed's widths -------------------
+# (benchmark/configs/smallthinker-21b-serve-12l.json: 28 query / 4 KV heads
+# of 128, bf16 pools of 128-token pages in two groups, 512-token ticks)
+WINDOW_CELL = dict(heads=28, kv_heads=4, page=128, full_pages=1537,
+                   window_pages=705, window=4096, tokens=512)
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_qblock_device_half_at_the_window_cells_widths(compile_on_chip,
+                                                       window):
+    """bf16 pools through ``_qblock_device``: a ``(4, 1, 128, 128)`` bf16
+    page block, 56 bf16 query rows a q-block (8 tokens x a group of 7: no
+    multiple of bf16's 16-row tile, and the block is the array's whole
+    axis), at each group's pool and largest job bucket; the window is
+    static to the kernel (a lower bound in the mask) and the call keeps the
+    name the two q-block rooflines find. No copy of a pool."""
+    rpa = importlib.import_module(
+        "paddle_tpu.ops.pallas.ragged_paged_attention")
+    c = WINDOW_CELL
+    per_seq = rpa.window_pages(window, rpa.DEFAULT_QBLOCK, c["page"],
+                               16384 // c["page"])
+    jobs = rpa.job_buckets(c["tokens"], rpa.DEFAULT_QBLOCK, 24, per_seq)[-1]
+    assert jobs == (8192 if window else 32768)
+    pages = c["window_pages"] if window else c["full_pages"]
+    pool = ((c["kv_heads"], pages, c["page"], HEAD_DIM), jnp.bfloat16)
+    specs = [((4, jobs + 1), jnp.int32),
+             ((2, c["tokens"] // rpa.DEFAULT_QBLOCK, rpa.DEFAULT_QBLOCK),
+              jnp.int32),
+             ((c["tokens"], c["heads"], HEAD_DIM), jnp.bfloat16), pool, pool]
+    kw = {} if window is None else {"window": window}
+
+    def call(jobs, rows, q, kp, vp):
+        return rpa._qblock_device(jobs, rows, q, kp, vp, None, None,
+                                  sm_scale=SM_SCALE, interpret=False, **kw)
+
+    text = compile_on_chip(call, *specs)
+    calls = _custom_calls(text)
+    pattern = _roofline_patterns("qblock_roofline").KERNEL
+    assert len(calls) == 1 and re.search(pattern, calls[0]), calls
+    # ``qblock_roofline.windowed`` (a dotted file name, no module path)
+    # tells the call by the same pattern
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "layer_metrics",
+            "qblock_roofline.windowed.py")) as f:
+        assert f'KERNEL = r"{pattern}"' in f.read()
+    shape = r"\[%d,%d,%d,%d\]" % pool[0]
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"= \w+" + shape + r"\S* (copy|transpose)\(", ln)]
+
+
 def test_flash_device_event_names_are_the_ones_their_roofline_matches(
         compile_on_chip):
     """``flash_roofline`` tells the forward kernel and the backward's two
