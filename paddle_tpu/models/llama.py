@@ -12,6 +12,7 @@ implements by hand in ``fleet/layers/mpu/mp_layers.py`` (SURVEY.md §2.3).
 from __future__ import annotations
 
 import copy
+import functools
 import inspect
 import math
 import threading
@@ -436,10 +437,55 @@ class LlamaModel(Layer):
         return hidden
 
 
+def _causal_lm_loss_terms(lg, lb, ign):
+    """The loss, and what its gradient is made of."""
+    lg = lg.astype(jnp.float32)
+    logp = lg - jax.nn.logsumexp(lg, axis=-1, keepdims=True)
+    valid = lb != ign
+    lb_safe = jnp.where(valid, lb, 0)
+    tok = jnp.take_along_axis(logp, lb_safe[..., None], axis=-1)[..., 0]
+    tok = jnp.where(valid, tok, 0.0)
+    n = jnp.maximum(valid.sum(), 1)
+    return -tok.sum() / n, logp, valid, lb_safe, n
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def causal_lm_loss(lg, lb, ign):
+    """Mean over the labels that are not ``ign`` of ``-log_softmax(lg)`` at
+    the label, in float32 whatever the logits' dtype. This body is what an
+    undifferentiated call runs; under differentiation the two rules below."""
+    return _causal_lm_loss_terms(lg, lb, ign)[0]
+
+
+def _causal_lm_loss_fwd(lg, lb, ign):
+    """The same loss and, in the same pass over the logits, their gradient
+    ``(softmax - onehot) * valid / n``: rounded ONCE to the logits' dtype
+    (the cotangent the source defines for them anyway) and fenced, so it is
+    the one array kept for the backward pass. Without the fence XLA keeps
+    the float32 logits instead and clones ``exp(logit - lse) - onehot``
+    into the operand of each of the head's two gradient products, where it
+    is recomputed on every pass of the product's tiling."""
+    loss, logp, valid, lb_safe, n = _causal_lm_loss_terms(lg, lb, ign)
+    onehot = lb_safe[..., None] == jnp.arange(lg.shape[-1], dtype=lb.dtype)
+    scale = (valid / n.astype(jnp.float32))[..., None]
+    dlogits = ((jnp.exp(logp) - onehot) * scale).astype(lg.dtype)
+    return loss, jax.lax.optimization_barrier(dlogits)
+
+
+def _causal_lm_loss_bwd(ign, dlogits, cot):
+    return (cot * dlogits.astype(jnp.float32)).astype(dlogits.dtype), None
+
+
+causal_lm_loss.defvjp(_causal_lm_loss_fwd, _causal_lm_loss_bwd)
+
+
 class LlamaPretrainingCriterion(Layer):
     """Causal-LM loss; mean over non-ignored tokens (ignore_index=-100).
     Computed in fp32 regardless of model dtype (reference: vocab-parallel
-    softmax-CE kernel accumulates in fp32)."""
+    softmax-CE kernel accumulates in fp32). Under differentiation the
+    forward pass also computes the logits' gradient and keeps that, in the
+    logits' dtype, as its only residual (``causal_lm_loss``): the float32
+    logits are not kept."""
 
     def __init__(self, ignore_index=-100):
         super().__init__()
@@ -447,17 +493,8 @@ class LlamaPretrainingCriterion(Layer):
 
     def forward(self, logits, labels):
         ign = self.ignore_index
-
-        def fn(lg, lb):
-            lg = lg.astype(jnp.float32)
-            logp = lg - jax.nn.logsumexp(lg, axis=-1, keepdims=True)
-            valid = lb != ign
-            lb_safe = jnp.where(valid, lb, 0)
-            tok = jnp.take_along_axis(logp, lb_safe[..., None], axis=-1)[..., 0]
-            tok = jnp.where(valid, tok, 0.0)
-            return -tok.sum() / jnp.maximum(valid.sum(), 1)
-
-        return apply(fn, logits, labels, op_name="causal_lm_loss")
+        return apply(lambda lg, lb: causal_lm_loss(lg, lb, ign),
+                     logits, labels, op_name="causal_lm_loss")
 
 
 class LlamaForCausalLM(GenerationMixin, Layer):

@@ -5,10 +5,14 @@
 TPU-native: each "fused" op is expressed as plain jax.numpy — XLA fuses the
 elementwise chains into the surrounding matmuls (SURVEY.md §7.0: the CUDA
 fusion tier maps to XLA fusion + Pallas for the rest), so there is nothing to
-hand-fuse here except keeping the ops in one traced region.
+hand-fuse here except keeping the ops in one traced region. One exception,
+under differentiation: a chain with a transcendental that feeds SEVERAL
+products is cloned into each product's operand and recomputed on every pass
+of its tiling, so ``fused_swiglu``'s rules fence their results (below).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..autograd.tape import apply
@@ -79,20 +83,42 @@ def fused_rotary_position_embedding(q, k=None, v=None, sin=None, cos=None,
 
 
 # -- fused_swiglu: the worked example for the custom-op extension API
-# (utils.register_op — the TPU-native PD_BUILD_OP). fwd returns
-# (out, residuals); the hand-written VJP recomputes nothing but the cheap
-# sigmoid products (reference: fused_bias_act swiglu backward kernel).
+# (utils.register_op — the TPU-native PD_BUILD_OP; reference: fused_bias_act
+# swiglu backward kernel). Three functions:
+#   * ``_swiglu_primal``: what an undifferentiated call compiles (serving);
+#   * ``_swiglu_fwd`` / ``_swiglu_vjp``: the rules under differentiation.
+# Under differentiation every result is a chain with a transcendental that
+# feeds matrix products (``down_proj`` forward and weight gradient; the
+# ``gate_proj`` / ``up_proj`` input and weight gradients). XLA's TPU
+# pipeline keeps such a chain in float32 and clones it into EACH consumer
+# product's operand, where it is recomputed on every pass of the product's
+# tiling. So each rule computes its chain once (float32 arithmetic), rounds
+# once to the inputs' dtype and fences the array with
+# ``optimization_barrier``: a product then reads a ready-made operand.
+# Residuals are the two inputs alone; the backward pass recomputes the
+# sigmoid in its single pass over them.
+
+def _swiglu_primal(a, g):
+    s = 1.0 / (1.0 + jnp.exp(-a))
+    return jnp.asarray(a * s * g, a.dtype)
+
 
 def _swiglu_fwd(a, g):
-    s = 1.0 / (1.0 + jnp.exp(-a))
-    return jnp.asarray(a * s * g, a.dtype), (a, s, g)
+    wide = jnp.promote_types(a.dtype, jnp.float32)
+    af, gf = a.astype(wide), g.astype(wide)
+    s = 1.0 / (1.0 + jnp.exp(-af))
+    out = (af * s * gf).astype(a.dtype)
+    return jax.lax.optimization_barrier(out), (a, g)
 
 
 def _swiglu_vjp(res, cot):
-    a, s, g = res
-    d_silu = s * (1.0 + a * (1.0 - s))        # d/da [a*sigmoid(a)]
-    return (jnp.asarray(cot * g * d_silu, a.dtype),
-            jnp.asarray(cot * a * s, g.dtype))
+    a, g = res
+    wide = jnp.promote_types(a.dtype, jnp.float32)
+    af, gf, cf = a.astype(wide), g.astype(wide), cot.astype(wide)
+    s = 1.0 / (1.0 + jnp.exp(-af))
+    d_silu = s * (1.0 + af * (1.0 - s))       # d/da [a*sigmoid(a)]
+    return jax.lax.optimization_barrier(
+        ((cf * gf * d_silu).astype(a.dtype), (cf * af * s).astype(g.dtype)))
 
 
 _fused_swiglu_op = None
@@ -103,7 +129,8 @@ def _swiglu_registered():
     if _fused_swiglu_op is None:
         from ..utils.custom_op import register_op
         _fused_swiglu_op = register_op(_swiglu_fwd, name="fused_swiglu",
-                                       vjp=_swiglu_vjp, override=True)
+                                       vjp=_swiglu_vjp,
+                                       primal=_swiglu_primal, override=True)
     return _fused_swiglu_op
 
 

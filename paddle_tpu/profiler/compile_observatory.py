@@ -48,7 +48,8 @@ import threading
 __all__ = [
     "CompileObservatory", "get_observatory", "observe", "declare_family",
     "register_warmup", "declared_families", "warmup_entries", "run_warmup",
-    "undeclared_families", "snapshot", "cost_section", "tensor_arg",
+    "undeclared_families", "record_program", "snapshot", "cost_section",
+    "tensor_arg",
     "static_arg", "format_signature", "enable", "disable", "reset",
     "is_enabled",
 ]
@@ -205,6 +206,7 @@ class CompileObservatory:
         self._families = {}      # name -> _Family
         self._declared = {}      # name -> {"buckets": ..., "static": ...}
         self._warmups = {}       # name -> callable
+        self._programs = {}      # name -> hlo_fusions.summary of its text
         self._tele = None
         self._provider = False
 
@@ -296,6 +298,20 @@ class CompileObservatory:
         return {"family": family, "miss": not known, "cause": cause,
                 "seconds": float(seconds or 0.0)}
 
+    def record_program(self, family, text):
+        """Keep, with a program family, what its COMPILED text says of
+        its matrix products (``hlo_fusions.summary`` of
+        ``compiled.as_text()``): how many fusions hold a product, how many
+        of them recompute a transcendental chain on an operand side, and
+        the compiler's estimated cycles of each group. Called by whoever
+        holds the compiled program (a sandbox compile for a described
+        chip, a test); shown under ``programs`` in :meth:`snapshot`."""
+        from . import hlo_fusions
+        seen = hlo_fusions.summary(text)
+        with self._lock:
+            self._programs[str(family)] = seen
+        return seen
+
     def _telemetry(self):
         if self._tele is None:
             from .telemetry import get_registry
@@ -381,6 +397,7 @@ class CompileObservatory:
                 "families": families,
                 "declared_unobserved": declared_only,
                 "undeclared": undeclared,
+                "programs": dict(self._programs),
                 "totals": {
                     "hits": sum(f.hits for f in self._families.values()),
                     "misses": sum(f.misses
@@ -411,6 +428,7 @@ class CompileObservatory:
             self._families.clear()
             self._declared.clear()
             self._warmups.clear()
+            self._programs.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -455,6 +473,10 @@ def run_warmup(families=None):
 
 def undeclared_families():
     return _OBSERVATORY.undeclared_families()
+
+
+def record_program(family, text):
+    return _OBSERVATORY.record_program(family, text)
 
 
 def snapshot():

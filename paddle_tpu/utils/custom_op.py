@@ -38,8 +38,9 @@ def _as_array(x):
     return x._data if isinstance(x, Tensor) else x
 
 
-def register_op(fwd=None, *, name=None, vjp=None, nondiff_argnums=(),
-                host_callback=False, out_shape=None, override=False):
+def register_op(fwd=None, *, name=None, vjp=None, primal=None,
+                nondiff_argnums=(), host_callback=False, out_shape=None,
+                override=False):
     """Register a custom op (decorator or functional form).
 
     ``fwd(*arrays, **static_kwargs)`` is a pure function of jax arrays.
@@ -50,6 +51,12 @@ def register_op(fwd=None, *, name=None, vjp=None, nondiff_argnums=(),
     ``vjp(residuals, *out_cotangents) -> tuple`` must return one cotangent
     per differentiable positional input (``jax.custom_vjp`` convention;
     reference: the ``SetBackwardFn`` half of PD_BUILD_OP).
+
+    ``primal`` (with ``vjp``): the function an UNDIFFERENTIATED call runs,
+    ``primal(*arrays, **static_kwargs) -> out``; default ``fwd(...)[0]``.
+    ``jax.custom_vjp`` runs ``fwd`` / ``vjp`` only under differentiation,
+    so an op whose rules materialise or fence arrays for the backward pass
+    keeps a plain expression for inference programs.
 
     ``nondiff_argnums``: positional args treated as static (hashable)
     configuration, not tensors.
@@ -62,11 +69,15 @@ def register_op(fwd=None, *, name=None, vjp=None, nondiff_argnums=(),
     """
     if fwd is None:
         return functools.partial(register_op, name=name, vjp=vjp,
+                                 primal=primal,
                                  nondiff_argnums=nondiff_argnums,
                                  host_callback=host_callback,
                                  out_shape=out_shape, override=override)
 
     op_name = name or fwd.__name__
+    if primal is not None and (vjp is None or host_callback):
+        raise ValueError(f"custom op '{op_name}': primal= goes with vjp= "
+                         "on a device op")
     if op_name in REGISTRY and not override:
         raise ValueError(f"custom op '{op_name}' is already registered "
                          "(pass override=True to replace)")
@@ -91,8 +102,10 @@ def register_op(fwd=None, *, name=None, vjp=None, nondiff_argnums=(),
         @functools.lru_cache(maxsize=64)
         def _bound(kw_items):
             kw = dict(kw_items)
-            wrapped = jax.custom_vjp(lambda *a: base(*a, **kw)[0],
-                                     nondiff_argnums=tuple(nondiff_argnums))
+            wrapped = jax.custom_vjp(
+                (lambda *a: base(*a, **kw)[0]) if primal is None
+                else (lambda *a: primal(*a, **kw)),
+                nondiff_argnums=tuple(nondiff_argnums))
 
             def _fwd(*a):
                 return base(*a, **kw)

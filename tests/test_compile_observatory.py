@@ -545,3 +545,77 @@ def test_bench_compare_compile_directions():
     assert bc.direction_of("compile_post_warmup_misses") == "lower"
     assert bc.direction_of("serving_warmup_compile_s") == "lower"
     assert bc.direction_of("compile_observatory_overhead_pct") == "lower"
+
+
+# a hand-made optimized module: one product with an exponential in a NESTED
+# producer of its operand, one with a divide as its output epilogue, one
+# plain product, and an elementwise fusion that holds no product
+_HLO = """HloModule jit_step, entry_computation_layout={()->f32[8,8]}
+
+%fused_computation.9 (param_0.1: f32[8,8]) -> f32[8,8] {
+  %param_0.1 = f32[8,8]{1,0} parameter(0)
+  ROOT %exp.1 = f32[8,8]{1,0} exponential(%param_0.1)
+}
+
+%fused_computation.1 (param_0: f32[8,8], param_1: bf16[8,8]) -> (bf16[8,8], bf16[8,8]) {
+  %param_0 = f32[8,8]{1,0} parameter(0)
+  %param_1 = bf16[8,8]{1,0} parameter(1)
+  %fusion.9.clone = f32[8,8]{1,0} fusion(%param_0), kind=kLoop, calls=%fused_computation.9
+  %convolution.1 = f32[8,8]{1,0} convolution(%fusion.9.clone, %param_1), dim_labels=bf_io->bf
+  %convert.1 = bf16[8,8]{1,0} convert(%convolution.1)
+  ROOT %tuple.1 = (bf16[8,8]{1,0}, bf16[8,8]{1,0}) tuple(%convert.1, %convert.1)
+}
+
+%fused_computation.2 (param_0.2: bf16[8,8], param_1.2: bf16[8,8]) -> bf16[8,8] {
+  %param_0.2 = bf16[8,8]{1,0} parameter(0)
+  %param_1.2 = bf16[8,8]{1,0} parameter(1)
+  %convolution.2 = f32[8,8]{1,0} convolution(%param_0.2, %param_1.2), dim_labels=bf_io->bf
+  %div.2 = f32[8,8]{1,0} divide(%convolution.2, %convolution.2)
+  ROOT %convert.2 = bf16[8,8]{1,0} convert(%div.2)
+}
+
+%fused_computation.3 (param_0.3: bf16[8,8]) -> f32[8,8] {
+  %param_0.3 = bf16[8,8]{1,0} parameter(0)
+  ROOT %convolution.3 = f32[8,8]{1,0} convolution(%param_0.3, %param_0.3), dim_labels=bf_io->bf
+}
+
+%fused_computation.4 (param_0.4: f32[8,8]) -> f32[8,8] {
+  %param_0.4 = f32[8,8]{1,0} parameter(0)
+  ROOT %log.4 = f32[8,8]{1,0} log(%param_0.4)
+}
+
+ENTRY %main.1 (a: f32[8,8], b: bf16[8,8]) -> f32[8,8] {
+  %a = f32[8,8]{1,0} parameter(0)
+  %b = bf16[8,8]{1,0} parameter(1)
+  %fusion.1 = (bf16[8,8]{1,0}, bf16[8,8]{1,0}) fusion(%a, %b), kind=kOutput, calls=%fused_computation.1, backend_config={"window_config":{"estimated_cycles":"700"}}
+  %gte.1 = bf16[8,8]{1,0} get-tuple-element(%fusion.1), index=0
+  %fusion.2 = bf16[8,8]{1,0} fusion(%gte.1, %b), kind=kOutput, calls=%fused_computation.2, backend_config={"window_config":{"estimated_cycles":"200"}}
+  %fusion.3 = f32[8,8]{1,0} fusion(%fusion.2), kind=kOutput, calls=%fused_computation.3, backend_config={"window_config":{"estimated_cycles":"100"}}
+  ROOT %fusion.4 = f32[8,8]{1,0} fusion(%fusion.3), kind=kLoop, calls=%fused_computation.4, backend_config={"window_config":{"estimated_cycles":"50"}}
+}
+"""
+
+
+def test_what_a_compiled_program_fused_into_its_products():
+    """``hlo_fusions``: which side of each product a transcendental chain
+    sits on, read from the compiled text; the observatory keeps the
+    counts with a program family."""
+    from paddle_tpu.profiler import hlo_fusions
+
+    recs = {r["name"]: r for r in hlo_fusions.product_fusions(_HLO)}
+    assert sorted(recs) == ["fusion.1", "fusion.2", "fusion.3"]
+    assert recs["fusion.1"]["operand_side"] == ["exponential"]
+    assert recs["fusion.1"]["estimated_cycles"] == 700
+    assert recs["fusion.1"]["result"].startswith("(bf16[8,8]")
+    assert recs["fusion.1"]["inputs"] == ["f32[8,8]{1,0}", "bf16[8,8]{1,0}"]
+    assert recs["fusion.2"]["operand_side"] == []
+    assert recs["fusion.2"]["anywhere"] == ["divide"]       # an epilogue
+    assert recs["fusion.3"]["anywhere"] == []
+    seen = co.record_program("train.unit", _HLO)
+    assert seen == {"product_fusions": 3, "operand_side_transcendental": 1,
+                    "product_cycles": 1000, "operand_side_cycles": 700,
+                    "program_cycles": 1050}
+    assert co.snapshot()["programs"] == {"train.unit": seen}
+    json.dumps(co.snapshot())
+    co.reset()
+    assert co.snapshot()["programs"] == {}

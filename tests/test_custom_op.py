@@ -153,6 +153,91 @@ def test_fused_swiglu_ported_through_api():
     assert "fused_swiglu" in REGISTRY
 
 
+def test_primal_is_what_an_undifferentiated_call_runs():
+    """``primal=``: the op's own expression for calls that are not being
+    differentiated; ``fwd`` / ``vjp`` run under differentiation alone."""
+    calls = []
+
+    def fwd(x):
+        calls.append("fwd")
+        return 2.0 * x, ()
+
+    def primal(x):
+        calls.append("primal")
+        return x + x
+
+    op = register_op(fwd, name="t_primal", vjp=lambda res, ct: (2.0 * ct,),
+                     primal=primal, override=True)
+    x = _leaf([1.0, 2.0])
+    with paddle.no_grad():
+        np.testing.assert_allclose(op(x).numpy(), [2.0, 4.0])
+    assert calls == ["primal"]
+    del calls[:]
+    op(x).sum().backward()
+    np.testing.assert_allclose(x.grad.numpy(), [2.0, 2.0])
+    assert "fwd" in calls
+    with pytest.raises(ValueError, match="primal="):
+        register_op(lambda x: x, name="t_primal_alone", primal=primal)
+
+
+def _plain_swiglu(a, g):
+    a, g = a.astype(jnp.float32), g.astype(jnp.float32)
+    return a * jax.nn.sigmoid(a) * g
+
+
+@pytest.mark.parametrize("split", [False, True], ids=["two_inputs", "split"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_fused_swiglu_rules_against_autodiff_of_the_plain_formula(dtype,
+                                                                   split):
+    """Forward and both gradients of the rules ``fused_swiglu`` runs under
+    differentiation, against ``jax.vjp`` of the plain formula in float32,
+    within the dtype's rounding; the undifferentiated call too; and the
+    eager tape gives what ``jax.value_and_grad`` through
+    ``FunctionalModule`` gives."""
+    from paddle_tpu.framework.functional import FunctionalModule
+    from paddle_tpu.ops import fused
+
+    rng = np.random.RandomState(3)
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    a, g, ct = (jnp.asarray(rng.randn(6, 16), dtype) for _ in range(3))
+    want, pull = jax.vjp(_plain_swiglu, a, g)
+    want_da, want_dg = pull(ct.astype(jnp.float32))
+    raw = fused._swiglu_registered().raw
+    got, pull = jax.vjp(raw, a, g)
+    da, dg = pull(ct)
+    assert got.dtype == da.dtype == dg.dtype == jnp.dtype(dtype)
+    for have, ref in ((got, want), (da, want_da), (dg, want_dg),
+                      (raw(a, g), want)):
+        np.testing.assert_allclose(np.asarray(have, np.float32),
+                                   np.asarray(ref), rtol=tol, atol=tol)
+
+    class Gate(paddle.nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.w = self.create_parameter([6, 32 if split else 16],
+                                           dtype=dtype)
+
+        def forward(self, x):
+            h = self.w * x
+            if split:
+                return fused.fused_swiglu(h).astype("float32").sum()
+            return fused.fused_swiglu(h, x).astype("float32").sum()
+
+    layer = Gate()
+    x = paddle.to_tensor(np.asarray(
+        rng.randn(6, 32 if split else 16), np.float32)).astype(dtype)
+    loss = layer(x)
+    loss.backward()
+    fm = FunctionalModule(layer, training=True)
+    ref_loss, ref_grads = jax.value_and_grad(
+        lambda ps: fm(ps, [], jax.random.key(0), x._data)[0])(
+            fm.param_arrays())
+    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(layer.w.grad.numpy(), np.float32),
+        np.asarray(ref_grads[0], np.float32), rtol=1e-6, atol=1e-6)
+
+
 CPP_SRC = r"""
 extern "C" void double_plus_one(const float* in, float* out, long n) {
     for (long i = 0; i < n; ++i) out[i] = 2.0f * in[i] + 1.0f;

@@ -497,3 +497,107 @@ def test_sdpa_flash_under_a_mesh_compiles(topo, monkeypatch):
     finally:
         mesh_mod.reset_mesh()
     assert _has_kernel(text)
+
+
+# -- the training step's products read ready-made operands (PR 32) ---------
+
+CELL_SEQ, CELL_HIDDEN, CELL_FFN, CELL_VOCAB = 4096, 4096, 14336, 32768
+
+
+def _program_text(text):
+    """A compiled program's text without what names its source: metadata
+    (op names, stack frames), the module's own name and its frame tables."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    return "\n".join(line for line in text.splitlines()
+                     if not line.startswith(("HloModule", "FileNames",
+                                             "FunctionNames", "FileLocations",
+                                             "StackFrames"))
+                     and not re.match(r"^\d+ ", line)).strip()
+
+
+def test_train_step_products_read_ready_made_operands(one_chip, monkeypatch):
+    """The benchmark's own step at ``train_dense_1chip``'s widths, cut to
+    one layer, for one v5e chip: no product fusion carries an
+    ``exponential`` / ``divide`` / ``log`` in a producer of its operands
+    (at the seed all of the MLP's and the head's gradient products did, and
+    recomputed SwiGLU or the softmax on every pass of their tiling), and the
+    head's weight gradient reads no float32 logits-sized array."""
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmark import harness
+    from benchmark.drivers import train
+    from paddle_tpu.framework.functional import FunctionalModule
+    from paddle_tpu.profiler import compile_observatory, hlo_fusions
+
+    config = harness.load_json(os.path.join(
+        harness.HERE, "configs", "mistral-7b-train-4l.json"))
+    assert (config["hidden_size"], config["intermediate_size"],
+            config["vocab_size"]) == (CELL_HIDDEN, CELL_FFN, CELL_VOCAB)
+    config["num_hidden_layers"] = 1
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = train.build_model(config, config["trainer"]["dtype"])
+    model.train()
+    fm = FunctionalModule(model, training=True)
+    step = train.make_train_step(fm, config["trainer"]["optimizer"])
+    state = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+             for a in fm.param_arrays()]
+    for _, p in model.named_parameters():
+        p._data = None                    # shapes are enough from here on
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, CELL_SEQ), jnp.int32, sharding=one_chip)
+    # the suite's conftest asks for "highest" products; the cell runs jax's
+    # default, and the program differs (float32 operands, other fusions)
+    with jax.default_matmul_precision(None):
+        text = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            state, state, state, key, ids, ids).compile().as_text()
+
+    products = hlo_fusions.product_fusions(text)
+    hot = [(r["name"], r["operand_side"]) for r in products
+           if r["operand_side"]]
+    assert len(products) >= 10 and not hot
+    # the SwiGLU backward and AdamW are there, once an element: as epilogues
+    assert any(r["anywhere"] for r in products)
+    logits = re.compile(rf"f32\[(1,)?{CELL_SEQ},{CELL_VOCAB}\]")
+    head_grads = [r for r in products
+                  if f"bf16[{CELL_SEQ},{CELL_VOCAB}]" in r["result"]]
+    assert head_grads, [r["result"][:60] for r in products]
+    for r in head_grads:
+        assert not [t for t in r["inputs"] + r["operand_types"]
+                    if logits.match(t)], r
+    seen = compile_observatory.get_observatory().record_program(
+        "train.test_step", text)
+    assert seen["operand_side_transcendental"] == 0
+    assert seen["product_fusions"] == len(products)
+    assert compile_observatory.snapshot()["programs"][
+        "train.test_step"] == seen
+
+
+def test_undifferentiated_swiglu_compiles_to_the_seeds_program(one_chip):
+    """``LlamaMLP``'s forward at the chat cell's 256-token bucket, not
+    differentiated: the program is the one the op compiled to when its
+    primal WAS its forward rule (the seed's registration, rebuilt here)."""
+    from paddle_tpu.framework.core import Tensor
+    from paddle_tpu.ops import fused
+    from paddle_tpu.utils import register_op
+
+    def seed_fwd(a, g):
+        s = 1.0 / (1.0 + jnp.exp(-a))
+        return jnp.asarray(a * s * g, a.dtype), (a, s, g)
+
+    seed_op = register_op(seed_fwd, name="t_seed_swiglu",
+                          vjp=lambda res, ct: (ct, ct), override=True)
+
+    def mlp(act):
+        def fn(x, wg, wu, wd):
+            return (act(Tensor(x @ wg), Tensor(x @ wu))._data) @ wd
+        args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+                for s in ((TOKEN_BUDGET, CELL_HIDDEN),
+                          (CELL_HIDDEN, CELL_FFN), (CELL_HIDDEN, CELL_FFN),
+                          (CELL_FFN, CELL_HIDDEN))]
+        with jax.default_matmul_precision(None):
+            return _program_text(
+                jax.jit(fn).lower(*args).compile().as_text())
+
+    now, seed = mlp(fused.fused_swiglu), mlp(seed_op)
+    assert "exponential" in now and now == seed
