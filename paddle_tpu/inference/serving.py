@@ -700,6 +700,14 @@ class ContinuousServingEngine:
         self.page_size = int(page_size)
         self.max_len = int(max_len)
         self.pad_token_id = int(pad_token_id)
+        # layers that keep a state a slot instead of pages
+        # (``model.kv_state_layers``: linear attention): the cache holds
+        # their arrays beside the page pool, and the prefix cache is off
+        # (a hit would need a snapshot of the state; asking for it is
+        # refused by the cache)
+        self.state_layers = int(getattr(model, "kv_state_layers", 0) or 0)
+        if enable_prefix_cache is None and self.state_layers:
+            enable_prefix_cache = False
         if enable_prefix_cache is None:
             enable_prefix_cache = os.environ.get(
                 "PADDLE_SERVING_PREFIX_CACHE", "1") != "0"
@@ -797,6 +805,8 @@ class ContinuousServingEngine:
         self.kv_windows = sorted({int(w) for w in getattr(
             model, "kv_layer_windows", ()) if w})
         self.window_groups = self._window_groups(window_num_pages)
+        if self.state_layers:
+            self._refuse_with("layers that keep a state a slot")
         self.spec_drafted_tokens = 0
         self.spec_accepted_tokens = 0
         self.spec_rounds = 0           # verify spans with >= 1 draft
@@ -855,18 +865,7 @@ class ContinuousServingEngine:
                 raise ValueError("window_num_pages for a model that "
                                  "declares no window layer")
             return None
-        kv = self.kv_dtype
-        if kv is None:
-            kv = os.environ.get("PADDLE_KV_DTYPE", "auto")
-        refused = [name for name, on in (
-            ("speculative decoding (rollback)", self.enable_spec),
-            ("sep striping", self.sep_prefill_enabled),
-            ("the host KV tier", self.host_pool_mb > 0),
-            ("int8 KV pages", str(kv).lower() == "int8")) if on]
-        if refused:
-            raise NotImplementedError(
-                f"a model with window layers ({self.kv_windows}) is not "
-                f"served with: {', '.join(refused)}")
+        self._refuse_with(f"window layers ({self.kv_windows})")
         pages_per_seq = -(-self.max_len // self.page_size)
         out = {}
         for w in self.kv_windows:
@@ -878,6 +877,22 @@ class ContinuousServingEngine:
                 n = self.max_batch * a_slot + 1
             out[w] = int(n)
         return out
+
+    def _refuse_with(self, what):
+        """What cannot yet serve a model with ``what`` refuses it here,
+        loudly (docs/SERVING.md lists them)."""
+        kv = self.kv_dtype
+        if kv is None:
+            kv = os.environ.get("PADDLE_KV_DTYPE", "auto")
+        refused = [name for name, on in (
+            ("speculative decoding (rollback)", self.enable_spec),
+            ("sep striping", self.sep_prefill_enabled),
+            ("the host KV tier", self.host_pool_mb > 0),
+            ("int8 KV pages", str(kv).lower() == "int8")) if on]
+        if refused:
+            raise NotImplementedError(
+                f"a model with {what} is not served with: "
+                f"{', '.join(refused)}")
 
     def declared_token_buckets(self):
         """The ragged scheduler's full compiled-shape family: every tick's
@@ -1077,7 +1092,8 @@ class ContinuousServingEngine:
                     max_len=self.max_len, num_pages=self.num_pages,
                     enable_prefix_cache=False, kv_dtype=self.kv_dtype,
                     allow_page_overcommit=self.sep_prefill_enabled,
-                    window_groups=self.window_groups)
+                    window_groups=self.window_groups,
+                    state_layers=bool(self.state_layers))
                 kernel = want("serving.ragged_attention")
                 if want("serving.ragged") or kernel:
                     t0 = time.perf_counter()
@@ -1101,6 +1117,8 @@ class ContinuousServingEngine:
                             self._warm_attention(cache, b, calls)
                         t_kernel += time.perf_counter() - t_run
                         cache.free(0)
+                        if self.state_layers:
+                            self._warm_state_spans(cache, b)
                     if want("serving.ragged"):
                         out["serving.ragged"] = \
                             time.perf_counter() - t0 - t_kernel
@@ -1209,6 +1227,24 @@ class ContinuousServingEngine:
             if was_training:
                 self.model.train()
         return out
+
+    def _warm_state_spans(self, cache, tokens):
+        """A model with a state a slot runs a tick's longer spans through
+        another kernel than its one-token rows, over a job list padded to
+        one of two lengths: one forward of ONE span of ``tokens`` tokens
+        and one of as many two-token spans as a tick can hold reach both
+        (the one-token warm-up forward met neither)."""
+        if tokens < 2:
+            return
+        many = min(self.max_batch, tokens // 2)
+        for spans in [[(0, 0, tokens)]] + (
+                [[(i, 2 * i, 2) for i in range(many)]] if many > 1 else []):
+            cache.begin_ragged(spans)
+            self.model.forward(
+                Tensor(np.full(tokens, self.pad_token_id, np.int64)[None]),
+                cache=cache, position_ids=np.zeros(tokens, np.int32))
+            for slot, _, _ in spans:
+                cache.free(slot)
 
     def _warm_attention(self, cache, tokens, calls):
         """Compile the attention kernel of a ``tokens``-token tick for
@@ -1400,7 +1436,8 @@ class ContinuousServingEngine:
                                  host_pool=self._host_pool,
                                  allow_page_overcommit=(
                                      self.sep_prefill_enabled),
-                                 window_groups=self.window_groups)
+                                 window_groups=self.window_groups,
+                                 state_layers=bool(self.state_layers))
         # cache-scoped counter baselines reset with the cache (a rebuilt
         # cache restarts them at 0; pool-scoped baselines persist with
         # the engine-owned host pool)
@@ -1454,6 +1491,9 @@ class ContinuousServingEngine:
         if cache is None:
             return {}
         out = {"prefix_evictions_device": cache.prefix_evictions_device}
+        if self.state_layers:
+            out.update(cache.state_counters,
+                       state_resets=cache.state_resets)
         if self.window_groups:
             out.update({key: getattr(cache, key) for key in _WINDOW_COUNTERS},
                        group_pages={label: (used, pages) for label, used,
@@ -1728,6 +1768,7 @@ class ContinuousServingEngine:
                     phase.end()
                     cache.begin_ragged(ragged)      # its own span
                     compiled0 = cache.compiled_layer_calls
+                    state0 = dict(cache.state_counters)
                     phase = _spans.span("serve/forward").begin()
                     logits = self.model.forward(Tensor(flat[None]),
                                                 cache=cache,
@@ -1887,6 +1928,11 @@ class ContinuousServingEngine:
                                                 - admitted0[1]),
                                  spans=[[n, start + n]
                                         for _, _, start, n, _ in spans],
+                                 **({"state_slots_live": int(
+                                     np.count_nonzero(cache.lens))}
+                                    if self.state_layers else {}),
+                                 **{k: v - state0.get(k, 0) for k, v in
+                                    cache.state_counters.items()},
                                  **{k: v.tolist()
                                     for k, v in found.items()})
                 except Exception as e:      # fail everything in flight

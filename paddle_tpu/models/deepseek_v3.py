@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 
 from ..framework.core import Tensor
@@ -71,6 +72,11 @@ class DeepseekV3Config:
         if scoring_func != "sigmoid" or topk_method != "noaux_tc":
             raise ValueError("only the sigmoid / noaux_tc router is built "
                              f"(got {scoring_func!r} / {topk_method!r})")
+        if q_lora_rank is None or kwargs.get("attn_output_gate"):
+            # ``DeepseekV3Attention`` builds both (another family's
+            # configuration asks it for them); this family has neither
+            raise ValueError("the DeepSeek-V3 family has a query rank and "
+                             "no output gate")
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.intermediate_size = intermediate_size
@@ -209,7 +215,12 @@ class DeepseekV3MoE(Layer):
 
 
 class DeepseekV3Attention(Layer):
-    """Multi-head latent attention, both forms (module docstring)."""
+    """Multi-head latent attention, both forms (module docstring). Two
+    things that another family's configuration may ask for: no query rank
+    (``config.q_lora_rank`` None: one full-rank ``q_proj``) and a head-wise
+    output gate (``config.attn_output_gate`` "head_wise": each head's
+    output times ``sigmoid(x W_g)_h`` before ``o_proj``; ``x`` is the
+    mixer's input)."""
 
     def __init__(self, config):
         super().__init__()
@@ -221,16 +232,26 @@ class DeepseekV3Attention(Layer):
         self.v_dim, self.kv_rank = config.v_head_dim, config.kv_lora_rank
         self.qk_dim = self.nope + self.rope
         init = Normal(0.0, config.initializer_range)
-        self.q_a_proj = Linear(h, config.q_lora_rank, weight_attr=init,
-                               bias_attr=False)
-        self.q_a_layernorm = RMSNorm(config.q_lora_rank, config.rms_norm_eps)
-        self.q_b_proj = Linear(config.q_lora_rank, nh * self.qk_dim,
-                               weight_attr=init, bias_attr=False)
+        if config.q_lora_rank is None:
+            self.q_proj = Linear(h, nh * self.qk_dim, weight_attr=init,
+                                 bias_attr=False)
+        else:
+            self.q_a_proj = Linear(h, config.q_lora_rank, weight_attr=init,
+                                   bias_attr=False)
+            self.q_a_layernorm = RMSNorm(config.q_lora_rank,
+                                         config.rms_norm_eps)
+            self.q_b_proj = Linear(config.q_lora_rank, nh * self.qk_dim,
+                                   weight_attr=init, bias_attr=False)
         self.kv_a_proj_with_mqa = Linear(h, self.kv_rank + self.rope,
                                          weight_attr=init, bias_attr=False)
         self.kv_a_layernorm = RMSNorm(self.kv_rank, config.rms_norm_eps)
         self.kv_b_proj = Linear(self.kv_rank, nh * (self.nope + self.v_dim),
                                 weight_attr=init, bias_attr=False)
+        gate = getattr(config, "attn_output_gate", None)
+        if gate not in (None, "head_wise"):
+            raise ValueError(f"output gate {gate!r} is not built")
+        if gate:
+            self.g_proj = Linear(h, nh, weight_attr=init, bias_attr=False)
         self.o_proj = Linear(nh * self.v_dim, h, weight_attr=init,
                              bias_attr=False)
         self._cos, self._sin = yarn_rope_tables(
@@ -265,7 +286,10 @@ class DeepseekV3Attention(Layer):
         c_kv [b, s, rank] (normed), k_rope [b, s, rope])``, rope applied at
         ``position_ids`` ([s] or [b, s]; default ``cache.pos + arange``)."""
         b, s, _ = hidden.shape
-        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(hidden)))
+        if hasattr(self, "q_proj"):
+            q = self.q_proj(hidden)
+        else:
+            q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(hidden)))
         kv = self.kv_a_proj_with_mqa(hidden)
         if position_ids is None:
             start = cache.pos if cache is not None else 0
@@ -309,15 +333,24 @@ class DeepseekV3Attention(Layer):
         return apply(fn, q_nope, q_rope, c_kv, k_rope, w_k,
                      op_name="mla_absorb")
 
-    def absorbed_project(self, attn_out):
+    def _gated(self, heads_out, gate_input):
+        """``heads_out`` [b, s, heads, v] times the head-wise gate of
+        ``gate_input`` (the mixer's input), where the layer has one."""
+        if not hasattr(self, "g_proj"):
+            return heads_out
+        return apply(lambda o, g: o * jax.nn.sigmoid(
+            g.astype(jnp.float32))[..., None].astype(o.dtype),
+            heads_out, self.g_proj(gate_input), op_name="mla_gate")
+
+    def absorbed_project(self, attn_out, gate_input=None):
         """``[b, s, heads, rank]`` (attention over the latent rows) ->
-        ``W_v`` a head, then ``o_proj``."""
+        ``W_v`` a head, the output gate where the layer has one
+        (``gate_input``: the mixer's input), then ``o_proj``."""
         _, w_v = self._kv_b()
         b, s = attn_out.shape[:2]
-        out = apply(lambda o, wv: jnp.einsum(
-            "bshr,hrv->bshv", o, wv).reshape(b, s, -1),
-            attn_out, w_v, op_name="mla_unabsorb")
-        return self.o_proj(out)
+        out = apply(lambda o, wv: jnp.einsum("bshr,hrv->bshv", o, wv),
+                    attn_out, w_v, op_name="mla_unabsorb")
+        return self.o_proj(self._gated(out, gate_input).reshape([b, s, -1]))
 
     # -- expanded form ----------------------------------------------------
     def expanded(self, hidden, attn_mask=None, position_ids=None, cache=None):
@@ -351,7 +384,8 @@ class DeepseekV3Attention(Layer):
             out = F.scaled_dot_product_attention(
                 q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
                 training=self.training)
-        return self.o_proj(out[..., :vd].reshape([b, s, nh * vd]))
+        return self.o_proj(self._gated(out[..., :vd], hidden).reshape(
+            [b, s, nh * vd]))
 
 
 class DeepseekV3DecoderLayer(Layer):

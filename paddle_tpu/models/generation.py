@@ -9,6 +9,7 @@ decode kernel — follow-up on the inference milestone.
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import numpy as np
@@ -46,6 +47,13 @@ def dequantize_kv_rows(q, scale, dtype=jnp.float32):
     ``scale / 2 = max|row| / 254``)."""
     return (jnp.asarray(q).astype(jnp.float32)
             * jnp.asarray(scale)[..., None]).astype(dtype)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _zero_slot(arrays, slot):
+    """Row ``slot`` of each array zeroed (``SlotPagedKVCache.reset_state``)."""
+    return [jax.lax.dynamic_update_index_in_dim(
+        a, jnp.zeros(a.shape[1:], a.dtype), slot, 0) for a in arrays]
 
 
 def _scatter_rows(pool, rows, page_ids, slot_ids):
@@ -489,12 +497,28 @@ class SlotPagedKVCache:
     ``b`` for which both hold. Eviction is LRU within a group. With a
     window group: no int8 pages, host tier, sep striping, page export /
     import or rollback (each refuses).
+
+    **A state a slot.** A layer that keeps no keys and values but a
+    recurrent state of fixed size (linear attention) says what it keeps
+    with ``layer.state_spec() -> {name: (shape a slot, dtype)}`` where an
+    attention layer says ``kv_pool_spec`` or ``kv_window``, and the cache
+    is built with ``state_layers=True``: the arrays of such a layer
+    (:meth:`layer_state`) are indexed by slot, with one more row, the
+    scratch slot ``max_batch``, for a bucket's padding rows; no page table
+    maps them. Admission zeroes a slot's rows on the device
+    (:meth:`reset_state`, inside :meth:`assign`), every step overwrites
+    them, :meth:`free` needs nothing. A ragged step hands such a layer its
+    spans (:meth:`ragged_spans`) and a memo that lasts the step
+    (``step_memo``), where the layers of a model share the plan they make
+    of the spans. A state cannot be cut back or found again by a digest:
+    with ``state_layers`` the prefix cache must be off, and rollback, page
+    export / import, the host tier, int8 pages and sep striping refuse.
     """
 
     def __init__(self, max_batch, page_size=16, max_len=2048,
                  num_pages=None, enable_prefix_cache=True, kv_dtype=None,
                  host_pool=None, allow_page_overcommit=False,
-                 window_groups=None):
+                 window_groups=None, state_layers=False):
         self.max_batch = int(max_batch)
         self.page_size = int(page_size)
         self.max_len = int(max_len)
@@ -554,6 +578,22 @@ class SlotPagedKVCache:
                     "one slot's window and a step of new tokens")
             self._groups.append(_PageGroup(window, pages, self.max_batch,
                                            self.pages_per_seq))
+        # layers that keep a state a slot (class docstring)
+        self.state_layers = bool(state_layers)
+        if self.state_layers:
+            refused = [name for name, on in (
+                ("the prefix cache (a hit would need a snapshot of the "
+                 "state)", self.enable_prefix_cache),
+                ("int8 pages", self.kv_quant),
+                ("sep striping", allow_page_overcommit)) if on]
+            if refused:
+                raise NotImplementedError(
+                    "a cache with a state a slot does not serve: "
+                    + ", ".join(refused))
+        self._states = {}           # id(layer) -> {name: [slots + 1, ...]}
+        self.step_memo = {}         # what a step's layers share; per step
+        self.state_resets = 0       # slots zeroed at admission
+        self.state_counters = {}    # name -> what the layers counted
         self._pool_group = {}       # id(layer) -> its _PageGroup
         self._group_idx = {}        # group -> a ragged step's index memo
         self._chain = [None] * self.max_batch   # per-slot block digests
@@ -593,10 +633,11 @@ class SlotPagedKVCache:
         # engine passes its own long-lived pool so the warm tier
         # survives cache rebuilds.
         self.host_pool = host_pool if host_pool is not None else HostKVPool()
-        if len(self._groups) > 1 and self.host_pool.enabled:
+        if (len(self._groups) > 1 or self.state_layers) \
+                and self.host_pool.enabled:
             raise NotImplementedError(
                 "the host KV tier does not serve a cache with a window "
-                "group")
+                "group or a state a slot")
         self.prefix_evictions_device = 0   # device-index LRU evictions
         # window groups: blocks given back during a request, cached tails
         # that eviction took, and prefix hits cut short for want of them
@@ -800,6 +841,52 @@ class SlotPagedKVCache:
         if len(self._groups) > 1:
             raise NotImplementedError(
                 f"{what} does not serve a cache with a window group")
+        if self.state_layers:
+            raise NotImplementedError(
+                f"{what} does not serve a cache with a state a slot (it "
+                "would need a snapshot of the state)")
+
+    # -- a state a slot ------------------------------------------------------
+    @property
+    def scratch_slot(self):
+        """The row of a state array that a bucket's padding reads and
+        writes."""
+        return self.max_batch
+
+    def layer_state(self, layer, spec):
+        """``{name: array [max_batch + 1, *shape]}`` of a layer that keeps a
+        state a slot, zeros when made, on its first forward; ``spec()`` ->
+        ``{name: (shape a slot, dtype)}`` is asked then."""
+        key = id(layer)
+        if key not in self._states:
+            if not self.state_layers:
+                raise ValueError(
+                    "a layer keeps a state a slot and the cache was built "
+                    "without state_layers=True")
+            self._states[key] = {
+                name: jnp.zeros((self.max_batch + 1,) + tuple(shape), dtype)
+                for name, (shape, dtype) in spec().items()}
+        return self._states[key]
+
+    def reset_state(self, slot):
+        """Zero ``slot``'s rows of every state array, on the device (one
+        program for all of them, the arrays donated)."""
+        if not self._states:
+            return
+        with _spans.span("state/admit", slot=int(slot),
+                         layers=len(self._states)):
+            arrays = [a for st in self._states.values() for a in st.values()]
+            arrays = iter(_zero_slot(arrays, jnp.int32(slot)))
+            for st in self._states.values():
+                for name in st:
+                    st[name] = next(arrays)
+            self.state_resets += 1
+
+    def ragged_spans(self):
+        """The armed step's spans as ``(slot, q_start, n_new, context tokens
+        before the step)``."""
+        return [(slot, qs, n, int(self.lens[slot]))
+                for slot, qs, n in self._mode[1]]
 
     @property
     def free_page_count(self):
@@ -863,6 +950,7 @@ class SlotPagedKVCache:
         token)."""
         slot = int(slot)
         self.free(slot)                       # defensive: slot starts clean
+        self.reset_state(slot)
         prompt = np.asarray(prompt).reshape(-1)
         chain = (block_hash_chain(prompt, self.page_size)
                  if self.enable_prefix_cache else [])
@@ -1011,6 +1099,7 @@ class SlotPagedKVCache:
             self._mode = ("ragged", spans)
             self._touched = None
             self._group_idx = {}
+            self.step_memo = {}
 
     def free(self, slot):
         slot = int(slot)

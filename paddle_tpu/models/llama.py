@@ -265,6 +265,13 @@ class RaggedLayerPrograms:
        returns ``(hidden, counters)``: device values the tick reads with
        its one sync (``cache.add_step_counters``).
 
+    A layer that keeps a STATE a slot and no pages (``layer.state_spec``:
+    linear attention) has the same three pieces under other names
+    (:meth:`_build_state`, :meth:`_run_state`): ``pre_state`` (norm,
+    projections and whatever the layer updates of its state inside the
+    program: those arrays are donated), the layer's own eager kernel entry
+    ``mix_state``, ``post_state``.
+
     Weights, rope tables, positions, page and slot ids and pools are all
     ARGUMENTS: the programs are traced once a KIND of layer (``layer.kind``
     where a model has several, e.g. dense and expert layers) over a twin of
@@ -290,7 +297,9 @@ class RaggedLayerPrograms:
         for layer in self._layers:
             kind = self.kind_of(layer)
             if kind not in self._kinds:
-                self._kinds[kind] = self._build(_structural_twin(layer))
+                build = (self._build_state if hasattr(layer, "state_spec")
+                         else self._build)
+                self._kinds[kind] = build(_structural_twin(layer))
         self._kv_dtype = {}          # (kind, hidden dtype) -> rows' dtype
 
     @staticmethod
@@ -331,6 +340,44 @@ class RaggedLayerPrograms:
 
         return qkv, jax.jit(pre_fn, donate_argnums=(8,)), jax.jit(post_fn)
 
+    def _build_state(self, twin):
+        """The two programs of a layer that keeps a state a slot:
+        ``pre_state(hidden, kept, plan) -> (what the kernel entry takes,
+        what ``post_state`` takes beside it, the kept arrays updated)`` with
+        ``kept`` donated, and ``post_state(hidden, mixed, carried, valid) ->
+        (hidden, counters)``."""
+        pre_mod = FunctionalModule(twin, method=twin.pre_state,
+                                   training=False)
+        post_mod = FunctionalModule(twin, method=twin.post_state,
+                                    training=False)
+
+        def pre_fn(p, b, hidden, kept, plan):
+            with self._tracing:
+                return pre_mod(p, b, jax.random.key(0), hidden, kept,
+                               plan)[0]
+
+        def post_fn(p, b, hidden, mixed, carried, valid):
+            with self._tracing:
+                return post_mod(p, b, jax.random.key(0), hidden, mixed,
+                                carried, valid)[0]
+
+        return None, jax.jit(pre_fn, donate_argnums=(3,)), jax.jit(post_fn)
+
+    def _run_state(self, layer, hidden, cache, p, b):
+        _, pre, post = self._kinds[self.kind_of(layer)]
+        plan = layer.step_plan(cache, hidden.shape[1])
+        state = cache.layer_state(layer, layer.state_spec)
+        kept = {k: state[k] for k in layer.state_kept_in_program}
+        mix_in, carried, kept = pre(p, b, hidden, kept, plan["program"])
+        state.update(kept)
+        cache.compiled_layer_calls += 1
+        out, found = post(p, b, hidden,
+                          layer.mix_state(cache, plan, *mix_in), carried,
+                          plan["program"]["valid"])
+        if found:
+            cache.add_step_counters(found)
+        return out
+
     def usable(self):
         """False where some layer carries state the programs would bake
         in as the traced layer's constants: int8 weight streams
@@ -353,6 +400,8 @@ class RaggedLayerPrograms:
         p = [t._data for t in params]
         b = [t._data for t in buffers]
         kind = self.kind_of(layer)
+        if hasattr(layer, "state_spec"):
+            return self._run_state(layer, hidden, cache, p, b)
         qkv, pre, post = self._kinds[kind]
         attn = layer.self_attn
         cos, sin = attn._cos, attn._sin

@@ -601,3 +601,50 @@ def test_undifferentiated_swiglu_compiles_to_the_seeds_program(one_chip):
 
     now, seed = mlp(fused.fused_swiglu), mlp(seed_op)
     assert "exponential" in now and now == seed
+
+
+# -- the two KDA kernels at serve_reason_state_closed's widths ---------------
+KDA_TOKENS, KDA_HEADS, KDA_DIM, KDA_SLOTS = 512, 32, 128, 96
+KDA_STATE = ((KDA_SLOTS + 1, KDA_HEADS, KDA_DIM, KDA_DIM), jnp.float32)
+
+
+def _kda_rows():
+    rows = ((KDA_TOKENS, KDA_HEADS, KDA_DIM), jnp.bfloat16)
+    return [rows] * 3 + [(rows[0], jnp.float32),
+                         ((KDA_TOKENS, KDA_HEADS), jnp.float32), KDA_STATE]
+
+
+def _state_sized_copies(text):
+    shape = r"f32\[%d,%d,%d,%d\]" % KDA_STATE[0]
+    return [ln.strip()[:120] for ln in text.splitlines()
+            if re.search(r"= " + shape + r"\S* (copy|transpose)\(", ln)]
+
+
+@pytest.mark.parametrize("kernel", ["step", "chunk"])
+def test_kda_kernels_compile_in_place_under_their_rooflines_names(
+        one_chip, kernel):
+    """``kda_step_roofline`` / ``kda_chunk_roofline`` / ``linear_attn_
+    device_pct`` find the kernels by the name ``pallas_call(name=)`` gives
+    the Mosaic call; each matches its own kernel alone; the donated state
+    (203 MB a layer) is updated in place: no copy of it in the program."""
+    from paddle_tpu.ops.pallas import kda
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    rows = [spec(*r) for r in _kda_rows()]
+    if kernel == "step":
+        args = rows + [spec((KDA_SLOTS,), jnp.int32)] * 2 + [False]
+        fn, static, other = kda._kda_step_device, (8,), "kda_chunk_roofline"
+    else:
+        jobs = kda.chunk_jobs_bound(KDA_TOKENS, 8)
+        args = rows + [spec(*_kda_rows()[0]),
+                       spec((jobs * kda.SUB,), jnp.int32),
+                       spec((KDA_TOKENS,), jnp.int32),
+                       spec((2, jobs), jnp.int32), False]
+        fn, static, other = kda._kda_chunk_device, (10,), "kda_step_roofline"
+    text = jax.jit(fn, static_argnums=static, donate_argnums=(5,)).lower(
+        *args).compile().as_text()
+    calls = _custom_calls(text)
+    mine = re.compile(_roofline_patterns(f"kda_{kernel}_roofline").KERNEL)
+    both = re.compile(_roofline_patterns("linear_attn_device_pct").KERNEL)
+    assert len(calls) == 1 and mine.search(calls[0]) and both.search(calls[0])
+    assert not re.compile(_roofline_patterns(other).KERNEL).search(calls[0])
+    assert not _state_sized_copies(text)
