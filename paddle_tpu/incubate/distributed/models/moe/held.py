@@ -5,11 +5,29 @@ experts (``held = range(lo, lo + n)`` of ``num_experts``). The layer here
 is one chip's part of that: the router keeps its full width and picks its
 ``top_k`` over ALL experts; the tokens routed to a held expert are sorted by
 expert and run through ONE grouped matrix product a projection
-(``jax.lax.ragged_dot``: on a TPU a native grouped matmul that visits only
-the row tiles a group really has); what an absent expert would add is left
-out. There is no capacity and no dropped token: the sorted buffer holds
-every (token, choice) pair. Nothing here stands in for the other chips or
-their exchange: a caller that runs every share adds the parts up
+(``jax.lax.ragged_dot``: on a TPU a native grouped matmul, the Mosaic call
+``ragged-dot...``); what an absent expert would add is left out.
+
+What a visited tile is. The grouped matmul walks a list of (group, row
+tile) visits, only those a group really has rows in, and a visit computes a
+whole ``tm x tk x tn`` tile (rows x contraction x output columns) for every
+block of the expert's ``[K, N]`` slab, whatever share of the ``tm`` rows
+are the group's. Left alone the TPU compiler takes ``tm`` = 512 (the
+largest power of two that divides the sorted buffer) and weight blocks of
+512 x 256 or 512 x 512. A held expert of a serving tick has 3-48 rows, so
+the MXU computed 512 for them; and a block that splits the contraction is
+fetched anew for every row tile, while one that holds it whole stays for
+all the row tiles of its group. :func:`grouped_tiling` picks a tile of at
+most 128 rows and the widest block of whole contractions that fits VMEM,
+and :func:`held_expert_sum` hands it to the compiler (the
+``ragged_dot_tiling`` attribute): the same kernel and the same numbers, bit
+for bit, in other tiles, at 0.3-0.8 of the compiler's tile's time from 2
+to 1,000 rows a group in buffers of 128 rows or more, and at the same
+time in smaller ones (PERF.md, PR 34).
+
+There is no capacity and no dropped token: the sorted buffer holds every
+(token, choice) pair. Nothing here stands in for the other chips or their
+exchange: a caller that runs every share adds the parts up
 (``tests/test_deepseek_v3.py`` does, against the uncut layer).
 
 Two routers, chosen by the layer's ``router`` argument:
@@ -32,6 +50,9 @@ layer's normalised input, before attention.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -40,8 +61,11 @@ from .....framework.core import Tensor
 from .....nn.initializer import Normal
 from .....nn.layer import Layer
 
+from jax.custom_derivatives import SymbolicZero
+from jax.experimental.xla_metadata import set_xla_metadata
+
 __all__ = ["group_limited_topk", "sigmoid_group_route", "topk_softmax_route",
-           "held_expert_sum", "HeldExperts"]
+           "grouped_tiling", "held_expert_sum", "HeldExperts"]
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -93,6 +117,83 @@ def topk_softmax_route(x, w_router, *, top_k):
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+# The constants below are a TPU v5e's (the chip this repo measures on:
+# 16 MiB of scoped VMEM a kernel, 197 TFLOP/s over 819 GB/s), read from
+# ``tools/grouped_product_bench.py``'s tables (PERF.md, PR 34).
+
+#: bytes of VMEM a grouped product's blocks may fill, by the TPU compiler's
+#: own count (two buffers each of the row tile, the weight block and the
+#: output tile): it scopes 16 MiB to a kernel and needs up to ~1 MiB of
+#: its own besides
+TILE_VMEM_BUDGET = 12 * 2 ** 20
+#: the row tile's range: the kernel takes 8 rows (a sublane tile) at the
+#: least, whatever the type; a tile past 128 computes more than the weight
+#: block's fetch hides (v5e turns memory-bound under ~240 rows a 2-byte
+#: weight) and leaves the block less room
+TILE_ROWS_MIN, TILE_ROWS_MAX = 8, 128
+
+
+def tile_vmem_bytes(tm, k, tn, itemsize, out_itemsize):
+    """What a ``tm x k x tn`` tile of a grouped product keeps in VMEM, as
+    the TPU compiler counts it (its refusals say: PERF.md, PR 34)."""
+    return 2 * (tm * k * itemsize + k * tn * itemsize
+                + tm * tn * out_itemsize)
+
+
+def grouped_tiling(rows, k, n, itemsize, out_itemsize=None):
+    """The tile ``(tm, tk, tn)`` of a grouped product ``[rows, k] x
+    [groups, k, n]``, from its shapes, or None: the compiler's own choice.
+
+    ``tm``: the largest power of two up to ``TILE_ROWS_MAX`` that divides
+    ``rows`` (the kernel's condition). ``tk`` = ``k``: the kernel keeps a
+    block of whole contractions for all the row tiles of its group, and
+    streams one of a split contraction again for every row tile. ``tn``:
+    the widest block of columns, a multiple of 128 that divides ``n``, that
+    keeps :func:`tile_vmem_bytes` inside ``TILE_VMEM_BUDGET``. Nothing
+    here depends on the load: this tile was the best tried or within 8 % of
+    it from 2 to 1,000 rows a group. None where 8 does not divide ``rows``,
+    128 does not divide ``k``, or no such block fits."""
+    out_itemsize = out_itemsize or itemsize
+    tm = TILE_ROWS_MAX
+    while tm > TILE_ROWS_MIN and rows % tm:
+        tm //= 2
+    fits = [tn for tn in range(128, n + 1, 128) if n % tn == 0
+            and tile_vmem_bytes(tm, k, tn, itemsize, out_itemsize)
+            <= TILE_VMEM_BUDGET]
+    if rows % tm or k % 128 or not fits:
+        return None
+    return tm, k, max(fits)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(3,))
+def _grouped_product(rows, w, sizes, out_dtype):
+    """``jax.lax.ragged_dot`` under :func:`grouped_tiling`'s tile. The hint
+    is the forward product's alone: JAX keeps an operation's metadata for
+    its transposes, whose contraction and columns are other dimensions (a
+    TPU compile of the gradient under the forward's tile fails for VMEM),
+    so the derivative is written out in products that carry none and
+    compile as they did."""
+    tile = grouped_tiling(rows.shape[0], w.shape[1], w.shape[2],
+                          rows.dtype.itemsize,
+                          jnp.dtype(out_dtype or rows.dtype).itemsize)
+    hint = (set_xla_metadata(ragged_dot_tiling="%d,%d,%d" % tile)
+            if tile else contextlib.nullcontext())
+    with hint:
+        return jax.lax.ragged_dot(rows, w, sizes,
+                                  preferred_element_type=out_dtype)
+
+
+@functools.partial(_grouped_product.defjvp, symbolic_zeros=True)
+def _grouped_product_jvp(out_dtype, primals, tangents):
+    rows, w, sizes = primals
+    d_rows, d_w, _ = tangents
+    terms = [jax.lax.ragged_dot(a, b, sizes, preferred_element_type=out_dtype)
+             for a, b in ((d_rows, w), (rows, d_w))
+             if SymbolicZero not in (type(a), type(b))]
+    return _grouped_product(rows, w, sizes, out_dtype), sum(terms[1:],
+                                                            terms[0])
+
+
 def held_expert_sum(x, idx, weights, w_gate, w_up, w_down, lo, valid=None,
                     activation=jax.nn.silu):
     """The held experts' part of a routed layer of gated units
@@ -113,12 +214,11 @@ def held_expert_sum(x, idx, weights, w_gate, w_up, w_down, lo, valid=None,
     order = jnp.argsort(key, stable=True)                     # [S*k]
     sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
     rows = x[order // k]                                      # [S*k, h]
-    gate = jax.lax.ragged_dot(rows, w_gate, sizes)
-    up = jax.lax.ragged_dot(rows, w_up, sizes)
+    gate = _grouped_product(rows, w_gate, sizes, None)
+    up = _grouped_product(rows, w_up, sizes, None)
     act = (activation(gate.astype(jnp.float32))
            * up.astype(jnp.float32)).astype(x.dtype)
-    y = jax.lax.ragged_dot(act, w_down, sizes,
-                           preferred_element_type=jnp.float32)
+    y = _grouped_product(act, w_down, sizes, jnp.float32)
     # each pair's row of ``y`` by the inverse permutation: a gather, where
     # the forward form would be a scatter-add; pairs of absent experts
     # (sorted past the last group, whose rows no group computes) weigh 0

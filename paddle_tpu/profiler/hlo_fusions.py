@@ -12,13 +12,18 @@ which such operations sit in a producer of the product's operands, with
 the compiler's own ``estimated_cycles`` for the fusion;
 :func:`summary` counts them, and
 ``compile_observatory.record_program`` keeps the count with a program
-family. Stdlib only, like the observatory.
+family. :func:`grouped_products` reads the same text for the grouped
+matrix products (``jax.lax.ragged_dot``, Mosaic calls ``ragged-dot...``
+on the TPU) and the tile each was compiled with: the witness that
+``moe/held.py::grouped_tiling`` engaged. Stdlib only, like the
+observatory.
 """
 from __future__ import annotations
 
 import re
 
-__all__ = ["parse_computations", "product_fusions", "summary"]
+__all__ = ["parse_computations", "product_fusions", "grouped_products",
+           "summary"]
 
 #: opcodes that make an operand-side chain expensive to recompute
 TRANSCENDENTALS = frozenset(("exponential", "divide", "log"))
@@ -28,6 +33,8 @@ _INSTR = re.compile(r"^\s+(?:ROOT\s+)?%([\w.\-]+)\s+=\s+(.*)$")
 _NAME = re.compile(r"%([\w.\-]+)")
 _CALLS = re.compile(r"\bcalls=%([\w.\-]+)")
 _CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
+_TILING = re.compile(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"')
+_LAYOUT = re.compile(r"\{[^{}]*\}$")
 
 
 def _closing(s, start):
@@ -151,10 +158,44 @@ def product_fusions(text):
     return out
 
 
+def grouped_products(text):
+    """One record for each grouped matrix product (a custom call named
+    ``ragged-dot...``; the call that builds its tile lists,
+    ``ragged-dot-metadata``, is not one): ``name`` (the device event's
+    name), ``lhs`` / ``rhs`` / ``result`` (the rows, the stacked weights
+    and what is written, as ``dtype[dims]``) and ``tiling``: the
+    ``[rows, contraction, columns]`` of its ``ragged_dot_tiling``
+    attribute, the compiler's own choice or the one handed to it; None
+    where the call carries none."""
+    types, calls = {}, []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, rest = m.groups()
+        types[name] = rest.split(" ", 1)[0]
+        if (name.startswith("ragged-dot") and "metadata" not in name
+                and " custom-call(" in rest):
+            _, operands, _, _, result = _parse_instruction(rest)
+            tiling = _TILING.search(rest)
+            calls.append((name, operands[-2:], result,
+                          [int(v) for v in tiling.groups()] if tiling
+                          else None))
+
+    def shape(of):
+        return _LAYOUT.sub("", of) if of else None
+
+    return [{"name": name, "lhs": shape(types.get(ops[0])),
+             "rhs": shape(types.get(ops[1])), "result": shape(result),
+             "tiling": tiling}
+            for name, ops, result, tiling in calls if len(ops) == 2]
+
+
 def summary(text):
     """Counts over :func:`product_fusions`: fusions that hold a product,
     those with a transcendental on an operand side, and the estimated
-    cycles of each group and of every operation of the program."""
+    cycles of each group and of every operation of the program; and the
+    program's :func:`grouped_products`."""
     recs = product_fusions(text)
     hot = [r for r in recs if r["operand_side"]]
     return {
@@ -164,5 +205,6 @@ def summary(text):
         "operand_side_cycles": sum(r["estimated_cycles"] or 0
                                    for r in hot),
         "program_cycles": sum(int(c) for c in _CYCLES.findall(text)),
+        "grouped_products": grouped_products(text),
     }
 

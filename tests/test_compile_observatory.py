@@ -614,8 +614,46 @@ def test_what_a_compiled_program_fused_into_its_products():
     seen = co.record_program("train.unit", _HLO)
     assert seen == {"product_fusions": 3, "operand_side_transcendental": 1,
                     "product_cycles": 1000, "operand_side_cycles": 700,
-                    "program_cycles": 1050}
+                    "program_cycles": 1050, "grouped_products": []}
     assert co.snapshot()["programs"] == {"train.unit": seen}
     json.dumps(co.snapshot())
     co.reset()
     assert co.snapshot()["programs"] == {}
+
+
+_GROUPED_HLO = """HloModule jit_fn, is_scheduled=true
+
+ENTRY %main.9 (x.1: bf16[4096,2560], w.1: bf16[128,2560,768], s.1: s32[128]) -> bf16[4096,768] {
+  %x.1 = bf16[4096,2560]{1,0:T(8,128)(2,1)} parameter(0)
+  %w.1 = bf16[128,2560,768]{2,1,0:T(8,128)(2,1)} parameter(1)
+  %s.1 = s32[128]{0:T(128)} parameter(2)
+  %ragged-dot-metadata = (s32[129]{0:T(256)S(1)}, s32[159]{0:T(256)S(1)}, s32[159]{0:T(256)S(1)}, s32[1]{0:T(128)}) custom-call(%s.1), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[128]{0}}, metadata={op_name="ragged-dot-metadata"}, backend_config={"custom_call_config":{"body":"QUJD"}}
+  %get-tuple-element = s32[1]{0:T(128)} get-tuple-element(%ragged-dot-metadata), index=3
+  %get-tuple-element.1 = s32[129]{0:T(256)S(1)} get-tuple-element(%ragged-dot-metadata), index=0
+  %get-tuple-element.2 = s32[159]{0:T(256)S(1)} get-tuple-element(%ragged-dot-metadata), index=1
+  %get-tuple-element.3 = s32[159]{0:T(256)S(1)} get-tuple-element(%ragged-dot-metadata), index=2
+  ROOT %ragged-dot-none = bf16[4096,768]{1,0:T(8,128)(2,1)} custom-call(%get-tuple-element, %get-tuple-element.1, %get-tuple-element.2, %get-tuple-element.3, %get-tuple-element, /*index=5*/%x.1, %w.1), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[1]{0}, s32[129]{0}, s32[159]{0}, s32[159]{0}, s32[1]{0}, bf16[4096,2560]{1,0}, bf16[128,2560,768]{2,1,0}}, frontend_attributes={mosaic_fusion_entry_point="true"ATTR}, metadata={op_name="ragged-dot-none"}, backend_config={"custom_call_config":{"body":"QUJD"}}
+}
+"""
+
+
+@pytest.mark.parametrize("attr,tiling", [
+    (',ragged_dot_tiling="128,2560,768"', [128, 2560, 768]), ("", None)])
+def test_what_tile_a_compiled_grouped_product_got(attr, tiling):
+    """``hlo_fusions.grouped_products``: each ``ragged-dot`` call's name,
+    operand shapes and ``ragged_dot_tiling`` (None where the instruction
+    carries none), not the call that builds its tile lists; ``summary``
+    and the observatory carry it."""
+    from paddle_tpu.profiler import hlo_fusions
+
+    text = _GROUPED_HLO.replace("ATTR", attr)
+    want = [{"name": "ragged-dot-none", "lhs": "bf16[4096,2560]",
+             "rhs": "bf16[128,2560,768]", "result": "bf16[4096,768]",
+             "tiling": tiling}]
+    assert hlo_fusions.grouped_products(text) == want
+    assert hlo_fusions.grouped_products(_HLO) == []
+    seen = co.record_program("serving.ragged", text)
+    assert seen["grouped_products"] == want and seen["product_fusions"] == 0
+    assert co.snapshot()["programs"]["serving.ragged"] == seen
+    json.dumps(co.snapshot())
+    co.reset()

@@ -16,14 +16,17 @@ comparison with the model in bf16 fails it, which one test asserts.
 """
 import importlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.framework.core import Tensor
+from paddle_tpu.incubate.distributed.models.moe import held as held_mod
 from paddle_tpu.incubate.distributed.models.moe.held import (
-    HeldExperts, group_limited_topk, sigmoid_group_route)
+    HeldExperts, group_limited_topk, grouped_tiling, held_expert_sum,
+    sigmoid_group_route, tile_vmem_bytes)
 from paddle_tpu.inference import ContinuousServingEngine
 from paddle_tpu.models.deepseek_v3 import (DeepseekV3ForCausalLM,
                                            deepseek_v3_tiny)
@@ -347,3 +350,154 @@ def test_latent_kernel_and_its_flat_job_list():
         assert mine == theirs or mine | {(tables[0, 0], 0, 0)} == theirs
     assert (row_slot[:23] >= 0).all() and (row_slot[23:] == -1).all()
     assert row_ctx[3:23].tolist() == list(range(22, 42))
+
+
+# -- the held experts' grouped products: the tile comes from the shapes -----
+
+
+@pytest.mark.parametrize("rows,k,n,out,want", [
+    # the three expert cells at their 512-token bucket: gate / up, then down
+    (4096, 2560, 768, 2, (128, 2560, 768)),          # Ling
+    (4096, 768, 2560, 4, (128, 768, 2560)),
+    (4096, 7168, 2048, 2, (128, 7168, 256)),         # GigaChat
+    (4096, 2048, 7168, 4, (128, 2048, 1024)),
+    (3072, 2560, 768, 2, (128, 2560, 768)),          # SmallThinker
+    (3072, 768, 2560, 4, (128, 768, 2560)),
+    (192, 2560, 768, 2, (64, 2560, 768)),            # its decode tick
+    (128, 7168, 2048, 2, (128, 7168, 256)),          # GigaChat's
+    (8, 7168, 2048, 2, (8, 7168, 256)),              # a one-token bucket
+    (6, 2560, 768, 2, None),          # ... whose rows no tile divides
+    (65536, 7168, 2048, 2, (128, 7168, 256)),  # 16 x the cell's tokens a tick
+    (4096, 7100, 2048, 2, None),      # a K that 128 does not divide
+    (4096, 7168, 2000, 2, None),      # an N that no block divides
+    (4096, 32768, 2048, 2, None),     # a K whose narrowest block does not fit
+])
+def test_grouped_tiling_is_arithmetic_on_shapes(rows, k, n, out, want):
+    got = grouped_tiling(rows, k, n, 2, out)
+    assert got == want
+    if got:
+        tm, tk, tn = got
+        assert rows % tm == 0 and tk == k and n % tn == 0 and tn % 128 == 0
+        assert held_mod.TILE_ROWS_MIN <= tm <= held_mod.TILE_ROWS_MAX
+
+
+def test_grouped_tiling_never_passes_its_vmem_budget():
+    """Over a grid of widths, row counts and item sizes: inside the budget,
+    and no wider block of columns would have been."""
+    for k in (128, 768, 2048, 2560, 7168, 18432):
+        for n in (128, 768, 2048, 7168):
+            for itemsize, out in ((2, 2), (2, 4), (4, 4), (1, 4)):
+                for rows in (8, 48, 192, 4096):
+                    got = grouped_tiling(rows, k, n, itemsize, out)
+                    if got is None:
+                        assert tile_vmem_bytes(
+                            min(rows, 128), k, 128, itemsize,
+                            out) > held_mod.TILE_VMEM_BUDGET, (rows, k, n)
+                        continue
+                    tm, tk, tn = got
+                    assert tile_vmem_bytes(tm, tk, tn, itemsize, out) \
+                        <= held_mod.TILE_VMEM_BUDGET, (got, k, n)
+                    wider = [w for w in range(tn + 128, n + 1, 128)
+                             if n % w == 0]
+                    assert not wider or tile_vmem_bytes(
+                        tm, tk, wider[0], itemsize,
+                        out) > held_mod.TILE_VMEM_BUDGET
+    # the result's item size defaults to the operands'
+    assert grouped_tiling(1024, 7168, 2048, 2) == (128, 7168, 256)
+
+
+def _held_case(dtype=jnp.float32, s=48, h=256, m=128, experts=16, held=4,
+               k=4):
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(0, 1, (s, h)), dtype)
+    idx = jnp.asarray(np.stack([rng.choice(experts, k, replace=False)
+                                for _ in range(s)]), jnp.int32)
+    w = jnp.asarray(rng.uniform(0.1, 1, (s, k)), jnp.float32)
+    wg, wu = (jnp.asarray(rng.normal(0, 0.05, (held, h, m)), dtype)
+              for _ in range(2))
+    wd = jnp.asarray(rng.normal(0, 0.05, (held, m, h)), dtype)
+    return x, idx, w, wg, wu, wd
+
+
+def _no_tile(monkeypatch):
+    monkeypatch.setattr(held_mod, "grouped_tiling", lambda *a, **kw: None)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_held_expert_sum_is_the_same_bits_with_and_without_the_hint(
+        dtype, monkeypatch):
+    """The tile is a hint to the TPU compiler and changes no operand, no
+    accumulation and no output type: on the CPU, where it is ignored, the
+    hinted and the unhinted sum are equal bit for bit, and the hint is
+    there (the lowered text carries the rule's triple)."""
+    args = _held_case(jnp.dtype(dtype))
+    fn = jax.jit(lambda *a: held_expert_sum(*a, 4))
+    tile = grouped_tiling(48 * 4, 256, 128, jnp.dtype(dtype).itemsize)
+    assert tile == (64, 256, 128)
+    assert 'ragged_dot_tiling = "64,256,128"' in fn.lower(*args).as_text()
+    hinted = fn(*args)
+    _no_tile(monkeypatch)
+    plain = jax.jit(lambda *a: held_expert_sum(*a, 4))
+    assert "ragged_dot_tiling" not in plain.lower(*args).as_text()
+    for a, b in zip(hinted, plain(*args)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def _hinted_products(text):
+    return [ln for ln in text.splitlines()
+            if "dot_general" in ln and "ragged_dot_tiling" in ln]
+
+
+@pytest.mark.parametrize("wrt", [(0, 3, 4, 5), (0,), (4,)])
+def test_transposed_grouped_products_carry_no_hint(wrt, monkeypatch):
+    """JAX keeps an operation's metadata for its transposes, and the
+    forward's tile is wrong for them (another contraction, other columns):
+    ``_grouped_product`` writes its derivative in products of their own, so
+    the gradient's lowered text holds the triple on the three forward
+    products and on nothing else, and the gradients are the bits JAX's own
+    derivative of ``ragged_dot`` gives."""
+    args = _held_case(jnp.bfloat16)
+
+    def loss(*a):
+        out, _, _ = held_expert_sum(*a, 4)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    grad = jax.jit(jax.grad(loss, argnums=wrt))
+    assert len(_hinted_products(grad.lower(*args).as_text())) == 3
+    ours = grad(*args)
+    monkeypatch.setattr(
+        held_mod, "_grouped_product",
+        lambda rows, w, sizes, out_dtype: jax.lax.ragged_dot(
+            rows, w, sizes, preferred_element_type=out_dtype))
+    theirs = jax.jit(jax.grad(lambda *a: loss(*a), argnums=wrt))
+    assert not _hinted_products(theirs.lower(*args).as_text())
+    for a, b in zip(ours, theirs(*args)):
+        assert a.dtype == b.dtype and float(jnp.abs(a).sum()) > 0
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    # forward mode too
+    x, dx = args[0], jnp.ones_like(args[0])
+    jax.jvp(lambda x: loss(x, *args[1:]), (x,), (dx,))
+
+
+def test_gradient_through_held_experts_is_unchanged_by_the_hint(monkeypatch):
+    """Through the layer and the tape: parameter and input gradients equal
+    bit for bit with and without the forward's tile."""
+    def grads():
+        paddle.seed(11)
+        layer = HeldExperts(64, 32, 16, 4, n_group=4, topk_group=2,
+                            scale=2.5, held=(4, 8))
+        x = paddle.to_tensor(np.random.default_rng(2).normal(
+            0, 1, (24, 64)).astype("float32"), stop_gradient=False)
+        out, _ = layer(x)
+        (out * out).sum().backward()
+        return [np.asarray(t.grad._data) for t in
+                (x, layer.router, layer.w_gate, layer.w_up, layer.w_down)]
+
+    hinted = grads()
+    _no_tile(monkeypatch)
+    for a, b in zip(hinted, grads()):
+        np.testing.assert_array_equal(a, b)
+    assert all(np.abs(g).sum() > 0 for g in hinted[2:])

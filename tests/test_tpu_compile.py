@@ -456,6 +456,156 @@ def test_expert_sum_device_events_are_the_ones_moe_device_pct_matches(
     assert len(products) == 3, _custom_calls(text)
 
 
+#: the three expert cells' widths (hidden, expert, held experts, choices a
+#: token) with their configuration's file, whose engine's token budget
+#: gives the buckets a tick is padded to
+EXPERT_CELLS = {
+    "gigachat": (7168, 2048, 16, 8, "gigachat3.1-702b-serve-ep16-5l.json"),
+    "smallthinker": (2560, 768, 64, 6, "smallthinker-21b-serve-12l.json"),
+    "ling": (2560, 768, 128, 8, "ling-3.0-flash-serve-ep4-7l.json"),
+}
+
+
+def _token_buckets(config_file):
+    """``declared_token_buckets()`` of the engine the cell builds."""
+    import types
+    from paddle_tpu.inference import ContinuousServingEngine
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs", config_file)) as f:
+        text = f.read()
+    budget = int(re.search(r'"token_budget":\s*(\d+)', text).group(1))
+    return sorted(ContinuousServingEngine.declared_token_buckets(
+        types.SimpleNamespace(token_budget=budget)))
+
+
+def _compile_expert_sum(compile_on_chip, tokens, h, m, held, top_k):
+    from paddle_tpu.incubate.distributed.models.moe.held import (
+        held_expert_sum)
+
+    def fn(x, idx, w, wg, wu, wd):
+        return held_expert_sum(x, idx, w, wg, wu, wd, 0)
+
+    with jax.default_matmul_precision("default"):
+        return compile_on_chip(
+            fn, ((tokens, h), jnp.bfloat16), ((tokens, top_k), jnp.int32),
+            ((tokens, top_k), jnp.float32), ((held, h, m), jnp.bfloat16),
+            ((held, h, m), jnp.bfloat16), ((held, m, h), jnp.bfloat16))
+
+
+#: ``declared_token_buckets()`` at the three engines' budget of 512
+TOKEN_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
+
+
+@pytest.mark.parametrize("tokens", TOKEN_BUCKETS)
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_expert_sum_compiles_with_the_rules_tile_at_every_token_bucket(
+        compile_on_chip, cell, tokens):
+    """``held_expert_sum`` at the cell's widths and each token bucket of
+    its engine: the TPU compiler takes the tile ``grouped_tiling`` hands it
+    (no VMEM refusal, no row tile that does not divide the buffer), all
+    three grouped products carry it, and at these loads a row tile is 128
+    rows at the most."""
+    from paddle_tpu.incubate.distributed.models.moe.held import (
+        grouped_tiling)
+    from paddle_tpu.profiler import hlo_fusions
+
+    h, m, held, top_k, config_file = EXPERT_CELLS[cell]
+    assert _token_buckets(config_file) == list(TOKEN_BUCKETS)
+    rows = tokens * top_k
+    products = hlo_fusions.grouped_products(_compile_expert_sum(
+        compile_on_chip, tokens, h, m, held, top_k))
+    if rows % 8:               # XLA's dense masked form, no grouped matmul
+        assert not products and (cell, tokens) in (("smallthinker", 1),
+                                                   ("smallthinker", 2))
+        return
+    assert len(products) == 3, products
+    want = {f"bf16[{held},{k},{n}]": grouped_tiling(rows, k, n, 2, out)
+            for k, n, out in ((h, m, 2), (m, h, 4))}
+    for p in products:
+        tile = want[p["rhs"]]
+        assert tile and tile[0] <= 128, tile
+        assert p["tiling"] == list(tile), p
+
+
+def _plain_grouped_product(rows, w, sizes, out_dtype):
+    return jax.lax.ragged_dot(rows, w, sizes,
+                              preferred_element_type=out_dtype)
+
+
+def test_expert_sum_tile_is_blind_to_the_load_and_absent_where_none_fits(
+        compile_on_chip, monkeypatch):
+    """All 16 of 16 experts held at GigaChat's widths under a 512-token
+    bucket (256 rows a group, the deployment's load) compile under the
+    tile a 16-row share gets: the rule reads shapes, and measured best at
+    both. Where it gives none the program is byte for byte the one
+    ``ragged_dot`` alone compiles to."""
+    from paddle_tpu.incubate.distributed.models.moe import held
+    from paddle_tpu.profiler import hlo_fusions
+
+    def program(rule=held.grouped_tiling):
+        monkeypatch.setattr(held, "grouped_tiling", rule)
+        return _program_text(_compile_expert_sum(
+            compile_on_chip, 512, 7168, 2048, 16, 8))
+
+    ours = program()
+    assert sorted(p["tiling"] for p in hlo_fusions.grouped_products(ours)) \
+        == [[128, 2048, 1024], [128, 7168, 256], [128, 7168, 256]]
+    none = program(lambda *a: None)
+    assert [p["tiling"][0] for p in hlo_fusions.grouped_products(none)] \
+        == [512, 512, 512]
+    monkeypatch.setattr(held, "_grouped_product", _plain_grouped_product)
+    assert none == program() != ours
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_expert_sum_gradient_compiles_and_its_transposes_get_no_tile(
+        compile_on_chip, monkeypatch, cell):
+    """``jax.grad`` through ``held_expert_sum`` at the cell's widths and
+    512-token bucket: the TPU compiler takes it (under the forward's tile a
+    transposed product, whose contraction and columns are other
+    dimensions, is refused for VMEM), the three forward products carry the
+    rule's tile, and the six transposed ones are byte for byte what
+    ``ragged_dot``'s own derivative compiles to, the compiler's tile
+    included."""
+    from paddle_tpu.incubate.distributed.models.moe import held
+    from paddle_tpu.profiler import hlo_fusions
+
+    h, m, n_held, top_k, _ = EXPERT_CELLS[cell]
+
+    def loss(x, idx, w, wg, wu, wd):
+        out, _, _ = held.held_expert_sum(x, idx, w, wg, wu, wd, 0)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    def compiled():
+        # a new function a compile: jit caches by the function it is given
+        with jax.default_matmul_precision("default"):
+            return hlo_fusions.grouped_products(compile_on_chip(
+                jax.grad(lambda *a: loss(*a), argnums=(0, 3, 4, 5)),
+                ((512, h), jnp.bfloat16), ((512, top_k), jnp.int32),
+                ((512, top_k), jnp.float32), ((n_held, h, m), jnp.bfloat16),
+                ((n_held, h, m), jnp.bfloat16),
+                ((n_held, m, h), jnp.bfloat16)))
+
+    def shape(p):
+        return p["lhs"], p["rhs"], p["result"]
+
+    ours = compiled()
+    rows = 512 * top_k
+    rule = {held.grouped_tiling(rows, k, n, 2, out)
+            for k, n, out in ((h, m, 2), (m, h, 4))}
+    forward = [p for p in ours if tuple(p["tiling"]) in rule]
+    assert len(ours) == 9 and len(forward) == 3, ours
+    assert {p["rhs"] for p in forward} == {f"bf16[{n_held},{h},{m}]",
+                                           f"bf16[{n_held},{m},{h}]"}
+    monkeypatch.setattr(held, "_grouped_product", _plain_grouped_product)
+    theirs = compiled()
+    assert not [p for p in theirs if tuple(p["tiling"]) in rule]
+    assert sorted((shape(p), p["tiling"]) for p in ours
+                  if p not in forward) == sorted(
+        (shape(p), p["tiling"]) for p in theirs
+        if shape(p) not in [shape(f) for f in forward])
+
+
 def test_int8_matmul_compiles(compile_on_chip):
     """Weight-only int8 GEMM at the 8B MLP up-projection: a 256-token
     tick against [hidden 4096, intermediate 14336]."""
