@@ -13,8 +13,23 @@ its recording window) or any ``jax.profiler`` session. A hot loop asks
 sites below it read the latched flag, so with no profiler a site costs one
 branch. A site reached while jax traces a jitted function records nothing:
 its wall time would be the tracing's, not the program's.
+
+While the latch is on, a ``gc.callbacks`` hook records the collector as a
+``host/gc`` span on the thread that triggered it (args ``generation``,
+``collected``, ``uncollectable``; no parent in the tracer, so the spans it
+interrupts keep their self times): every collection of generation 1 or 2,
+and one of generation 0 that took at least :data:`GC_MIN_S`; the shorter
+ones are counted on the tracer (``gc_short``, ``gc_short_s``). The
+collector stops every Python thread, so such a span is what each thread
+was doing. ``latch`` installs the hook on the tick where tracing turns on
+and removes it on the tick where it turns off; with no profiler
+``gc.callbacks`` is untouched.
 """
 from __future__ import annotations
+
+import gc
+import threading
+import time
 
 import jax
 
@@ -24,6 +39,9 @@ __all__ = ["PREFIX", "NULL", "span", "open_span", "latch", "tracing_active"]
 
 #: every annotation of the program in a device trace starts with this
 PREFIX = "paddle_tpu:"
+#: the collector's span, and the shortest generation-0 pass it records
+GC_SPAN = "host/gc"
+GC_MIN_S = 1e-3
 
 try:
     from jax._src.core import trace_state_clean as _trace_state_clean
@@ -56,10 +74,57 @@ def tracing_active():
 
 def latch():
     """Ask ``tracing_active()`` and latch the answer for the span sites
-    reached until the next ``latch``; returns it."""
+    reached until the next ``latch``; returns it. Where the answer
+    changes, the collector's hook goes in or out with it."""
     global _latched
-    _latched = tracing_active()
-    return _latched
+    on = tracing_active()
+    if on != _latched:
+        _watch_gc(on)
+    _latched = on
+    return on
+
+
+#: thread ident -> (the ``host/gc`` annotation, its perf_counter start)
+_gc_open = {}
+
+
+def _on_gc(phase, info):
+    """``gc.callbacks`` hook: a ``host/gc`` span around each collection,
+    on the thread that runs it; in the tracer it has no parent, so the
+    spans it interrupted keep their self times."""
+    if phase == "start":
+        if tracing_active():
+            ann = jax.profiler.TraceAnnotation(
+                PREFIX + GC_SPAN, generation=info["generation"])
+            ann.__enter__()
+            _gc_open[threading.get_ident()] = (ann, time.perf_counter())
+        return
+    opened = _gc_open.pop(threading.get_ident(), None)
+    if opened is None:
+        return
+    ann, t0 = opened
+    seconds = time.perf_counter() - t0
+    if info["generation"] == 0 and seconds < GC_MIN_S:
+        ann.set_metadata(discarded=1)
+        ann.__exit__(None, None, None)
+        get_tracer().count_short_gc(seconds)
+        return
+    args = {"generation": info["generation"],
+            "collected": info["collected"],
+            "uncollectable": info["uncollectable"]}
+    ann.set_metadata(**args)
+    ann.__exit__(None, None, None)
+    get_tracer().add_interruption(GC_SPAN, seconds, args)
+
+
+def _watch_gc(on):
+    if on:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+    else:
+        if _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+        _gc_open.clear()
 
 
 class _NullSpan:

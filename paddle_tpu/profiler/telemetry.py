@@ -23,6 +23,7 @@ op telemetry is explicitly enabled (``TelemetryCallback`` / Profiler).
 from __future__ import annotations
 
 import bisect
+import itertools
 import json
 import os
 import threading
@@ -413,7 +414,10 @@ def metrics_text() -> str:
 class Span:
     """One completed (or open) span. ``ts``/``dur`` are seconds on the
     tracer's monotonic clock (``ts_us``/``dur_us`` for chrome traces);
-    ``wall_time`` is the true wall-clock begin."""
+    ``wall_time`` is ``time.time_ns()`` read when the span opened, in
+    seconds: the clock a ``jax.profiler`` session stamps its
+    ``profile_start_time`` with, so ``wall_time * 1e9 -
+    profile_start_time`` is the span's start on the device trace's axis."""
 
     __slots__ = ("name", "ts", "dur", "tid", "span_id", "parent_id",
                  "wall_time", "args")
@@ -453,19 +457,25 @@ class SpanTracer:
     parent linkage. Enable/disable is refcounted (the Profiler enables
     it for each recording window); when disabled, begin/end are no-ops.
     Completed spans land in a bounded deque and are pulled with
-    :meth:`drain`."""
+    :meth:`drain`; ``dropped`` counts the spans the full deque pushed out
+    since the last ``drain``, and ``gc_short`` / ``gc_short_s`` the
+    generation-0 collections too short to be a ``host/gc`` span
+    (``profiler/spans.py``) and their seconds."""
 
     def __init__(self, max_spans=200_000):
-        self._lock = threading.Lock()
+        # re-entrant: the collector's hook (``profiler/spans.py``) records
+        # on the thread that runs a collection, which may hold this lock
+        self._lock = threading.RLock()
         self._done: deque = deque(maxlen=max_spans)
         self._tls = threading.local()
         self._enabled = 0
-        self._next_id = 0
+        self._ids = itertools.count(1)  # next() is atomic: no lock
         self._tids: dict = {}          # thread ident -> small stable tid
-        # monotonic origin + matching wall clock, so ts is comparable
-        # across threads and wall_time is recoverable for any span
+        # monotonic origin, so ts is comparable across threads
         self._t0 = time.perf_counter()
-        self._wall0 = time.time()
+        self.dropped = 0
+        self.gc_short = 0
+        self.gc_short_s = 0.0
 
     # -- lifecycle -----------------------------------------------------------
     def enable(self):
@@ -500,14 +510,24 @@ class SpanTracer:
             st = self._tls.stack = []
         return st
 
-    def _new_span(self, name, ts, args):
+    def _new_span(self, name, ts, args, ago=0.0, nest=True):
+        """A span that began ``ago`` seconds before now; ``nest=False``:
+        with no parent."""
         stack = self._stack()
-        parent = stack[-1].span_id if stack else None
+        parent = stack[-1].span_id if stack and nest else None
+        return Span(name, ts, self._tid(), next(self._ids), parent,
+                    time.time_ns() / 1e9 - ago, args)
+
+    def _record(self, sp):
+        """Queue a completed span; the caller holds ``_lock``."""
+        if len(self._done) == self._done.maxlen:
+            self.dropped += 1
+        self._done.append(sp)
+
+    def count_short_gc(self, seconds):
         with self._lock:
-            self._next_id += 1
-            sid = self._next_id
-        return Span(name, ts, self._tid(), sid, parent,
-                    self._wall0 + ts, args)
+            self.gc_short += 1
+            self.gc_short_s += seconds
 
     # -- recording -----------------------------------------------------------
     def begin(self, name, **args):
@@ -543,7 +563,7 @@ class SpanTracer:
             sp.dur = max(now - sp.ts, 0.0)
             if record:
                 with self._lock:
-                    self._done.append(sp)
+                    self._record(sp)
             if sp is target:
                 return sp
         return None
@@ -572,19 +592,36 @@ class SpanTracer:
             return None
         now = (end_ts if end_ts is not None
                else time.perf_counter() - self._t0)
-        sp = self._new_span(name, max(now - duration, 0.0), args or None)
+        ts = max(now - duration, 0.0)
+        sp = self._new_span(name, ts, args or None,
+                            ago=time.perf_counter() - self._t0 - ts)
         sp.dur = duration
         with self._lock:
-            self._done.append(sp)
+            self._record(sp)
+        return sp
+
+    def add_interruption(self, name, duration, args=None):
+        """Record an already-finished span that interrupted this thread
+        (the collector): whether enabled or not, as ``open``, and with no
+        parent, so that the spans it interrupted keep their self times."""
+        ts = max(time.perf_counter() - self._t0 - duration, 0.0)
+        sp = self._new_span(name, ts, args, ago=duration, nest=False)
+        sp.dur = duration
+        with self._lock:
+            self._record(sp)
         return sp
 
     # -- consumption ---------------------------------------------------------
     def drain(self):
-        """Pull (and clear) every completed span."""
+        """Pull (and clear) every completed span, and zero the counts of
+        what the deque dropped and of the short collections."""
         with self._lock:
-            out = list(self._done)
-            self._done.clear()
-        return out
+            # swapped, not cleared: a span the collector's hook records in
+            # here lands in one deque or the other
+            out, self._done = self._done, deque(maxlen=self._done.maxlen)
+            self.dropped = self.gc_short = 0
+            self.gc_short_s = 0.0
+        return list(out)
 
     def completed(self):
         """Every completed span, left in place (``drain`` clears)."""
