@@ -28,6 +28,14 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 METRICS = MANIFEST["end_to_end"] + MANIFEST["per_layer"]
+#: the configurations of the Llama family that this file's checks of source
+#: and widths are about, and their cells; every other family's file
+#: (``test_latent_moe.py``, ``test_window_moe.py``, ``test_linear_latent.py``)
+#: holds the same checks against its own published numbers
+MISTRAL_CONFIGS = [c for c in MANIFEST["configs"] if c["source"].startswith(
+    "https://huggingface.co/mistralai/")]
+MISTRAL_CELLS = [w["name"] for w in MANIFEST["workloads"]
+                 if w["config"] in {c["name"] for c in MISTRAL_CONFIGS}]
 MISTRAL = dict(hidden_size=4096, intermediate_size=14336, head_dim=128,
                num_attention_heads=32, num_key_value_heads=8,
                vocab_size=32768, num_hidden_layers=4)
@@ -38,7 +46,63 @@ TINY = dict(hidden_size=64, intermediate_size=176, num_hidden_layers=2,
 
 # -- the manifest and what it names -----------------------------------------
 
-@pytest.mark.parametrize("cell", CELLS)
+#: The names of each list's entries as accepted, in order. ``BENCHMARK.json``
+#: is read by place: an accepted entry keeps its place and its fields. A PR
+#: appends new entries at the end of a list, and may append a cell's name at
+#: the end of an accepted entry's ``workloads``; nothing else is edited. Only
+#: a ``benchmark`` PR that retires an entry edits these lists.
+ACCEPTED = {
+    "configs": [
+        "mistral-7b-train-4l", "mistral-7b-serve-16l",
+        "gigachat3.1-702b-serve-ep16-5l", "smallthinker-21b-serve-12l",
+        "ling-3.0-flash-serve-ep4-7l"],
+    "workloads": [
+        "train_dense_1chip", "serve_chat_closed", "serve_docs_latent_closed",
+        "serve_mixed_window_closed", "serve_reason_state_closed"],
+    "end_to_end": ["train_tok_s", "serve_tok_s", "itl_mean_ms", "setup_s"],
+    "per_layer": [
+        "train_mfu_pct", "device_idle_pct.train", "flash_roofline",
+        "sched_tick_ms", "sched_padded_pct", "ttft_p95_ms.closed",
+        "itl_p95_ms.closed", "kv_pages_peak_pct", "serve_mfu_pct",
+        "qblock_roofline", "device_idle_pct.serve", "pre_device_s",
+        "device_setup_s", "tick_schedule_ms", "tick_emit_ms",
+        "tick_kv_host_ms", "tick_dispatch_ms", "tick_attn_host_ms",
+        "tick_sync_ms", "tick_span_cover_pct", "serve_mfu_pct.latent_moe",
+        "latent_attn_roofline", "moe_device_pct", "moe_load_max_over_mean",
+        "moe_unheld_pct", "prefix_hit_pct", "qblock_job_fill_pct",
+        "flash_tile_fill_pct", "serve_mfu_pct.window_moe",
+        "qblock_roofline.windowed", "qblock_window_skip_pct",
+        "kv_window_held_pct", "moe_device_pct.window",
+        "moe_load_max_over_mean.window", "prefix_hit_pct.window",
+        "serve_mfu_pct.linear_latent", "kda_step_roofline",
+        "kda_chunk_roofline", "linear_attn_device_pct", "kda_chunk_fill_pct",
+        "state_host_ms", "latent_attn_roofline.linear",
+        "moe_device_pct.linear", "moe_load_max_over_mean.linear",
+        "moe_unheld_pct.linear", "tick_idle_boundary_ms",
+        "tick_idle_launch_ms", "tick_idle_attn_host_ms", "tick_idle_gc_ms",
+        "qblock_page_fill_pct"],
+}
+#: the manifest's names as committed, and as hand-made edits would leave
+#: them: an append keeps the rule, every other edit breaks it
+EDITS = {
+    "as_committed": lambda names: names,
+    "appended": lambda names: names + ["new_entry"],
+    "inserted": lambda names: names[:1] + ["new_entry"] + names[1:],
+    "removed": lambda names: names[:1] + names[2:],
+    "reordered": lambda names: names[1:2] + names[:1] + names[2:],
+    "renamed": lambda names: [names[0] + ".v2"] + names[1:],
+}
+
+
+@pytest.mark.parametrize("edit", list(EDITS))
+@pytest.mark.parametrize("key", list(ACCEPTED))
+def test_accepted_entries_keep_their_places(key, edit):
+    names = EDITS[edit]([e["name"] for e in MANIFEST[key]])
+    kept = names[:len(ACCEPTED[key])] == ACCEPTED[key]
+    assert kept is (edit in ("as_committed", "appended")), names
+
+
+@pytest.mark.parametrize("cell", MISTRAL_CELLS)
 def test_cell_is_found_by_name(cell):
     entry, config, traffic = harness.find_cell(MANIFEST, cell)
     assert config["source"].startswith("https://huggingface.co/mistralai/")
@@ -55,8 +119,7 @@ def test_cell_is_found_by_name(cell):
     assert entry["chips"] in (1, 4) and len(entry["why"]) <= 200
 
 
-@pytest.mark.parametrize("config", MANIFEST["configs"],
-                         ids=lambda c: c["name"])
+@pytest.mark.parametrize("config", MISTRAL_CONFIGS, ids=lambda c: c["name"])
 def test_config_keeps_published_widths(config):
     """No width is cut: only depth and the positions kept."""
     body = harness.load_json(os.path.join(REPO, config["file"]))

@@ -25,9 +25,10 @@ def cell_run():
 def test_manifest_entry():
     m = next(m for m in harness.load_manifest()["per_layer"]
              if m["name"] == NAME)
+    assert CELL in m["workloads"]
     assert m == {"name": NAME, "unit": "%", "better": "higher",
                  "source": "program_counter", "layer": "kernels",
-                 "moves": "train_tok_s", "workloads": [CELL]}
+                 "moves": "train_tok_s", "workloads": m["workloads"]}
 
 
 def test_needed_pairs_over_pairs_inside_computed_tiles_at_the_cells_shapes():

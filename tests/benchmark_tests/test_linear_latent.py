@@ -63,15 +63,14 @@ PUBLISHED = {
 REDUCED = {"num_hidden_layers": 7, "first_k_dense_replace": 1,
            "num_experts": 512, "vocab_size": 39296,
            "max_position_embeddings": 34048, "num_nextn_predict_layers": 0}
-#: the accepted readers that read this driver's records as they are have an
-#: entry of their own for this cell (``<name>.linear``): two test files pin
-#: the accepted entries' ``workloads`` to one cell each
-PINNED_ELSEWHERE = ("latent_attn_roofline", "moe_device_pct",
-                    "moe_load_max_over_mean", "moe_unheld_pct")
+#: the accepted readers that read this driver's records as they are, listed
+#: for this cell through copies of their own (``<name>.linear``)
+COPIED_READERS = ("latent_attn_roofline", "moe_device_pct",
+                  "moe_load_max_over_mean", "moe_unheld_pct")
 NEW_READERS = ("serve_mfu_pct.linear_latent", "kda_step_roofline",
                "kda_chunk_roofline", "linear_attn_device_pct",
                "kda_chunk_fill_pct", "state_host_ms") + tuple(
-                   name + ".linear" for name in PINNED_ELSEWHERE)
+                   name + ".linear" for name in COPIED_READERS)
 JOINED = ("sched_tick_ms", "sched_padded_pct", "kv_pages_peak_pct",
           "device_idle_pct.serve", "pre_device_s", "device_setup_s",
           "ttft_p95_ms.closed")
@@ -111,15 +110,10 @@ def test_the_cell_is_found_by_name_with_its_files(found):
     # 96 clients behind 32 k documents will not spread less
     assert not listed & ({"itl_mean_ms", "serve_mfu_pct", "qblock_roofline",
                           "tick_attn_host_ms", "prefix_hit_pct"}
-                         | set(PINNED_ELSEWHERE))
+                         | set(COPIED_READERS))
     for name in NEW_READERS:
         m = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
-    # new entries stand at the end of their lists
-    assert MANIFEST["workloads"][-1]["name"] == CELL
-    assert MANIFEST["configs"][-1]["name"] == CONFIG
-    assert [m["name"] for m in MANIFEST["per_layer"]][-len(NEW_READERS):] \
-        == list(NEW_READERS)
+        assert CELL in m["workloads"] and m["moves"] == "serve_tok_s"
 
 
 def test_the_configuration_keeps_every_published_number(found):

@@ -111,3 +111,12 @@ def test_the_kernels_entry_records_what_the_reader_reads(tracer):
             steps, 8, 6)
     assert harness.load_reader(NAME)(run) == pytest.approx(
         100.0 * 12 / (8 * 7))
+
+
+def test_manifest_entry():
+    m = next(m for m in harness.load_manifest()["per_layer"]
+             if m["name"] == NAME)
+    assert "serve_chat_closed" in m["workloads"]
+    assert m == {"name": NAME, "unit": "%", "better": "higher",
+                 "source": "program_counter", "layer": "kernels",
+                 "moves": "serve_tok_s", "workloads": m["workloads"]}

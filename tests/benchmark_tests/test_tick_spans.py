@@ -156,7 +156,7 @@ def test_manifest_entry(name):
     entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
     assert entry["layer"] == LAYER_OF[name]
     assert entry["moves"] == "serve_tok_s"
-    assert entry["workloads"] == ["serve_chat_closed"]
+    assert "serve_chat_closed" in entry["workloads"]
     assert entry["source"] == "program_span"
     pct = name == "tick_span_cover_pct"
     assert entry["unit"] == ("%" if pct else "ms")

@@ -34,16 +34,14 @@ PUBLISHED = {
     "rope_scaling": None, "rope_theta": 1500000,
     "sliding_window_layout": LAYOUT, "sliding_window_size": 4096,
     "tie_word_embeddings": False, "vocab_size": 151936}
-#: ``test_latent_moe.py`` picks its cell's metrics by ``workloads == [its
-#: cell]``, so the three accepted readers that read this driver's records
-#: as they are have an entry of their own for this cell (``<name>.window``,
-#: a reader file that loads the accepted one) and their lists stay as they
-#: were
-PINNED_ELSEWHERE = ("moe_device_pct", "moe_load_max_over_mean",
-                    "prefix_hit_pct")
+#: the accepted readers that read this driver's records as they are, listed
+#: for this cell through copies of their own (``<name>.window``, a reader
+#: file that loads the accepted one)
+COPIED_READERS = ("moe_device_pct", "moe_load_max_over_mean",
+                  "prefix_hit_pct")
 NEW_READERS = ("serve_mfu_pct.window_moe", "qblock_roofline.windowed",
                "qblock_window_skip_pct", "kv_window_held_pct") + tuple(
-                   name + ".window" for name in PINNED_ELSEWHERE)
+                   name + ".window" for name in COPIED_READERS)
 JOINED = ("sched_tick_ms", "sched_padded_pct", "kv_pages_peak_pct",
           "device_idle_pct.serve", "pre_device_s", "device_setup_s",
           "ttft_p95_ms.closed")
@@ -83,10 +81,10 @@ def test_the_cell_is_found_by_name_with_its_files(found):
     # not list it, as ISSUE 31 says of a spread over half the bound
     assert not listed & ({"qblock_roofline", "moe_unheld_pct", "itl_mean_ms",
                           "tick_attn_host_ms", "serve_mfu_pct"}
-                         | set(PINNED_ELSEWHERE))
+                         | set(COPIED_READERS))
     for name in NEW_READERS:
         m = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tok_s"
+        assert CELL in m["workloads"] and m["moves"] == "serve_tok_s"
 
 
 def test_the_configuration_keeps_every_published_number(found):
